@@ -85,22 +85,18 @@ def _provenance() -> dict:
             capture_output=True, text=True, timeout=10).stdout.strip()
     except (OSError, subprocess.SubprocessError):
         sha = ""
-    out = {"git_sha": sha or "unknown",
-           "timestamp": datetime.datetime.now(
-               datetime.timezone.utc).isoformat(timespec="seconds")}
-    try:
-        import jax
-        import jaxlib
+    import jax
+    import jaxlib
 
-        devs = jax.devices()
-        out.update(jax_version=jax.__version__,
-                   jaxlib_version=jaxlib.__version__,
-                   device_kind=devs[0].device_kind if devs else "none",
-                   device_count=len(devs),
-                   platform=devs[0].platform if devs else "none")
-    except Exception:  # provenance must never take down a bench run
-        pass
-    return out
+    devs = jax.devices()
+    return {"git_sha": sha or "unknown",
+            "timestamp": datetime.datetime.now(
+                datetime.timezone.utc).isoformat(timespec="seconds"),
+            "jax_version": jax.__version__,
+            "jaxlib_version": jaxlib.__version__,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs),
+            "platform": devs[0].platform}
 
 
 def _jsonable(obj):

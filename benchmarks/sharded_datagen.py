@@ -95,8 +95,17 @@ def _spawn(ndev: int, quick: bool, extra_args: list[str]) -> dict:
 
 
 def run(quick: bool = False):
+    import jax
+
     from benchmarks.common import CSV
 
+    # one process per chip: a parent that holds an accelerator would keep
+    # it while the CPU-forced children measured something else entirely
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "sharded_datagen sweeps virtual CPU devices in subprocesses; "
+            f"its parent must run on the CPU, not {jax.default_backend()!r} "
+            "(set JAX_PLATFORMS=cpu)")
     rows = {}
     for ndev in DEVICE_COUNTS:
         rows[ndev] = _spawn(ndev, quick, [])

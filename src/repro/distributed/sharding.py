@@ -263,3 +263,16 @@ class ChainSharding:
         """Shard every array leaf of an operator/preconditioner pytree
         (batched leaves all carry the leading chain axis)."""
         return jax.tree_util.tree_map(self.put, tree)
+
+    @staticmethod
+    def partitioner():
+        """Context for the solver's sharded dispatches: the GSPMD
+        partitioner. In JAX 0.9 the default one (Shardy) cannot compile an
+        fp64 QR or SVD whose batch axis is sharded on a TPU ("A tuple
+        parameter that is being flattened shouldn't have frontend
+        attributes"), and the stacked eigen/LS cleanup is built on both.
+        The setting is part of the jit cache key, so other programs keep
+        Shardy."""
+        from jax._src import config
+
+        return config.use_shardy_partitioner(False)

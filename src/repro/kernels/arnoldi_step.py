@@ -38,12 +38,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import ZERO, row_block
+
 
 def _dot2(a, b):
-    """Reduce the trailing (bx, ny) tile axes: (r, bx, ny)·(bx, ny) → (r,)."""
-    return jax.lax.dot_general(
-        a.reshape(a.shape[0], -1), b.reshape(-1),
-        (((1,), (0,)), ((), ())), preferred_element_type=None)
+    """Reduce the trailing (bx, ny) tile axes: (r, bx, ny)·(bx, ny) →
+    (r, 1, 1). Broadcast-multiply + reduce keeps every value 3-D with
+    tiled trailing axes, the form Mosaic lowers."""
+    p = jnp.sum(a * b[None], axis=2, keepdims=True)
+    return jnp.sum(p, axis=1, keepdims=True)
+
+
+def _combine(h, a):
+    """Σ_r h[r]·a[r]: (r, 1, 1) coefficients × (r, bx, ny) rows → (bx, ny)."""
+    return jnp.sum(h.astype(a.dtype) * a, axis=0)
 
 
 def _kernel(c5_ref, idg_ref, idg_up_ref, idg_dn_ref, vin_ref, vin_up_ref,
@@ -81,17 +89,13 @@ def _kernel(c5_ref, idg_ref, idg_up_ref, idg_dn_ref, vin_ref, vin_up_ref,
 
     @pl.when(phase == 1)
     def _p1():
-        cr = crows_ref[...]                  # (k1, bx, ny)
-        w1 = wout_ref[...] - jnp.tensordot(cacc_s[...].astype(cr.dtype), cr,
-                                           axes=([0], [0]))
+        w1 = wout_ref[...] - _combine(cacc_s[...], crows_ref[...])
         wout_ref[...] = w1
         h1_s[...] += (mask_ref[...] * _dot2(v_ref[...], w1)).astype(h1_s.dtype)
 
     @pl.when(phase == 2)
     def _p2():
-        v = v_ref[...]                       # (m1, bx, ny)
-        wout_ref[...] = wout_ref[...] - jnp.tensordot(
-            h1_s[...].astype(v.dtype), v, axes=([0], [0]))
+        wout_ref[...] = wout_ref[...] - _combine(h1_s[...], v_ref[...])
 
     @pl.when(phase == 3)
     def _p3():
@@ -100,9 +104,7 @@ def _kernel(c5_ref, idg_ref, idg_up_ref, idg_dn_ref, vin_ref, vin_up_ref,
 
     @pl.when(phase == 4)
     def _p4():
-        v = v_ref[...]
-        wout_ref[...] = wout_ref[...] - jnp.tensordot(
-            h2_s[...].astype(v.dtype), v, axes=([0], [0]))
+        wout_ref[...] = wout_ref[...] - _combine(h2_s[...], v_ref[...])
 
         @pl.when(t == nt - 1)
         def _emit():
@@ -115,7 +117,7 @@ def _kernel(c5_ref, idg_ref, idg_up_ref, idg_dn_ref, vin_ref, vin_up_ref,
 def arnoldi_step_pallas(coeffs: jax.Array, inv_diag: jax.Array,
                         c_rows: jax.Array, v_basis: jax.Array,
                         vin: jax.Array, mask: jax.Array, *,
-                        interpret: bool = True, block_rows: int = 64,
+                        interpret: bool, block_rows: int = 64,
                         acc_dtype=None):
     """One fused Arnoldi inner iteration.
 
@@ -138,9 +140,7 @@ def arnoldi_step_pallas(coeffs: jax.Array, inv_diag: jax.Array,
     dt = vin.dtype
     if k == 0:
         c_rows = jnp.zeros((1, nx * ny), dt)
-    bx = min(block_rows, nx)
-    while nx % bx:
-        bx -= 1  # largest divisor ≤ block_rows (grids here are powers of two)
+    bx = row_block(nx, block_rows)
     nt = nx // bx
     acc = jnp.dtype(acc_dtype) if acc_dtype is not None else dt
 
@@ -148,37 +148,41 @@ def arnoldi_step_pallas(coeffs: jax.Array, inv_diag: jax.Array,
         functools.partial(_kernel, nx_tiles=nt),
         grid=(5, nt),
         in_specs=[
-            pl.BlockSpec((5, bx, ny), lambda p, t: (0, t, 0)),
-            pl.BlockSpec((bx, ny), lambda p, t: (t, 0)),
+            pl.BlockSpec((5, bx, ny), lambda p, t: (ZERO, t, ZERO)),
+            pl.BlockSpec((bx, ny), lambda p, t: (t, ZERO)),
             # clamped neighbor tiles supply the halo rows (phase 0 only)
-            pl.BlockSpec((bx, ny), lambda p, t: (jnp.maximum(t - 1, 0), 0)),
-            pl.BlockSpec((bx, ny), lambda p, t: (jnp.minimum(t + 1, nt - 1), 0)),
-            pl.BlockSpec((bx, ny), lambda p, t: (t, 0)),
-            pl.BlockSpec((bx, ny), lambda p, t: (jnp.maximum(t - 1, 0), 0)),
-            pl.BlockSpec((bx, ny), lambda p, t: (jnp.minimum(t + 1, nt - 1), 0)),
-            pl.BlockSpec((k1, bx, ny), lambda p, t: (0, t, 0)),
-            pl.BlockSpec((m1, bx, ny), lambda p, t: (0, t, 0)),
-            pl.BlockSpec((m1,), lambda p, t: (0,)),
+            pl.BlockSpec((bx, ny), lambda p, t: (jnp.maximum(t - 1, 0), ZERO)),
+            pl.BlockSpec((bx, ny),
+                         lambda p, t: (jnp.minimum(t + 1, nt - 1), ZERO)),
+            pl.BlockSpec((bx, ny), lambda p, t: (t, ZERO)),
+            pl.BlockSpec((bx, ny), lambda p, t: (jnp.maximum(t - 1, 0), ZERO)),
+            pl.BlockSpec((bx, ny),
+                         lambda p, t: (jnp.minimum(t + 1, nt - 1), ZERO)),
+            pl.BlockSpec((k1, bx, ny), lambda p, t: (ZERO, t, ZERO)),
+            pl.BlockSpec((m1, bx, ny), lambda p, t: (ZERO, t, ZERO)),
+            pl.BlockSpec((m1, 1, 1), lambda p, t: (ZERO, ZERO, ZERO)),
         ],
         out_specs=[
-            pl.BlockSpec((bx, ny), lambda p, t: (t, 0)),
-            pl.BlockSpec((m1,), lambda p, t: (0,)),
-            pl.BlockSpec((k1,), lambda p, t: (0,)),
+            pl.BlockSpec((bx, ny), lambda p, t: (t, ZERO)),
+            pl.BlockSpec((m1, 1, 1), lambda p, t: (ZERO, ZERO, ZERO)),
+            pl.BlockSpec((k1, 1, 1), lambda p, t: (ZERO, ZERO, ZERO)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nx, ny), dt),
-            jax.ShapeDtypeStruct((m1,), dt),
-            jax.ShapeDtypeStruct((k1,), dt),
+            jax.ShapeDtypeStruct((m1, 1, 1), dt),
+            jax.ShapeDtypeStruct((k1, 1, 1), dt),
         ],
         scratch_shapes=[
-            pltpu.VMEM((k1,), dt),
-            pltpu.VMEM((m1,), acc),
-            pltpu.VMEM((m1,), acc),
+            pltpu.VMEM((k1, 1, 1), dt),
+            pltpu.VMEM((m1, 1, 1), acc),
+            pltpu.VMEM((m1, 1, 1), acc),
         ],
         interpret=interpret,
+        name="arnoldi_step",
     )(coeffs,
       inv_diag.reshape(nx, ny), inv_diag.reshape(nx, ny),
       inv_diag.reshape(nx, ny),
       vin.reshape(nx, ny), vin.reshape(nx, ny), vin.reshape(nx, ny),
-      c_rows.reshape(k1, nx, ny), v_basis.reshape(m1, nx, ny), mask)
-    return wout.reshape(-1), h, bj[:k]
+      c_rows.reshape(k1, nx, ny), v_basis.reshape(m1, nx, ny),
+      mask.reshape(m1, 1, 1))
+    return wout.reshape(-1), h.reshape(m1), bj.reshape(k1)[:k]

@@ -66,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_s, l_s, acc_s, *,
                                     "block_q", "block_k"))
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int | None = None,
-                           interpret: bool = True,
+                           interpret: bool,
                            block_q: int = 128, block_k: int = 128) -> jax.Array:
     """q (B, Hq, Tq, D); k, v (B, Hkv, Tk, D) → (B, Hq, Tq, D)."""
     b, hq, tq, d = q.shape

@@ -21,14 +21,25 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.tiling import ZERO, padded_tiles
+
 
 def _kernel(v_ref, w_ref, mask_ref, wout_ref, h_ref, h1_s, h2_s):
     phase = pl.program_id(0)
     t = pl.program_id(1)
     nt = pl.num_programs(1)
     v = v_ref[...]        # (m1, bn) storage dtype
-    mask = mask_ref[...]  # (m1,)
+    mask = mask_ref[...]  # (m1, 1)
     acc = h1_s.dtype      # accumulation dtype (== storage unless widened)
+
+    # V·w and Vᵀh as broadcast-multiply + reduce on the vector unit: 2-D
+    # shapes Mosaic lowers, exact in the storage dtype (an fp32 MXU pass
+    # would round through bf16)
+    def proj(w):          # (1, bn) → (m1, 1)
+        return jnp.sum(v * w, axis=1, keepdims=True)
+
+    def expand(h):        # (m1, 1) → (1, bn)
+        return jnp.sum(h.astype(v.dtype) * v, axis=0, keepdims=True)
 
     @pl.when(jnp.logical_and(phase == 0, t == 0))
     def _init():
@@ -37,17 +48,17 @@ def _kernel(v_ref, w_ref, mask_ref, wout_ref, h_ref, h1_s, h2_s):
 
     @pl.when(phase == 0)
     def _p0():
-        h1_s[...] += (mask * (v @ w_ref[...])).astype(acc)
+        h1_s[...] += (mask * proj(w_ref[...])).astype(acc)
 
     @pl.when(phase == 1)
     def _p1():
-        w1 = w_ref[...] - v.T @ h1_s[...].astype(v.dtype)
+        w1 = w_ref[...] - expand(h1_s[...])
         wout_ref[...] = w1
-        h2_s[...] += (mask * (v @ w1)).astype(acc)
+        h2_s[...] += (mask * proj(w1)).astype(acc)
 
     @pl.when(phase == 2)
     def _p2():
-        wout_ref[...] = wout_ref[...] - v.T @ h2_s[...].astype(v.dtype)
+        wout_ref[...] = wout_ref[...] - expand(h2_s[...])
         @pl.when(t == nt - 1)
         def _emit():
             h_ref[...] = (h1_s[...] + h2_s[...]).astype(h_ref.dtype)
@@ -56,7 +67,7 @@ def _kernel(v_ref, w_ref, mask_ref, wout_ref, h_ref, h1_s, h2_s):
 @functools.partial(jax.jit, static_argnames=("interpret", "block_n",
                                              "acc_dtype"))
 def fused_orthog_pallas(v_basis: jax.Array, w: jax.Array, mask: jax.Array, *,
-                        interpret: bool = True, block_n: int = 2048,
+                        interpret: bool, block_n: int = 2048,
                         acc_dtype=None):
     """v_basis (m1, n), w (n,), mask (m1,) → (w_orth (n,), h (m1,)).
 
@@ -69,8 +80,6 @@ def fused_orthog_pallas(v_basis: jax.Array, w: jax.Array, mask: jax.Array, *,
     acc_dtype: widen ONLY the h accumulation scratch (fp32 storage / fp64
     accumulate); outputs stay in w.dtype.
     """
-    from repro.kernels.dia_spmv import padded_tiles
-
     m1, n = v_basis.shape
     bn, n_pad, nt = padded_tiles(n, block_n, "fused_orthog", steps_factor=3)
     if n_pad != n:
@@ -82,22 +91,23 @@ def fused_orthog_pallas(v_basis: jax.Array, w: jax.Array, mask: jax.Array, *,
         _kernel,
         grid=(3, nt),
         in_specs=[
-            pl.BlockSpec((m1, bn), lambda p, t: (0, t)),
-            pl.BlockSpec((bn,), lambda p, t: (t,)),
-            pl.BlockSpec((m1,), lambda p, t: (0,)),
+            pl.BlockSpec((m1, bn), lambda p, t: (ZERO, t)),
+            pl.BlockSpec((1, bn), lambda p, t: (ZERO, t)),
+            pl.BlockSpec((m1, 1), lambda p, t: (ZERO, ZERO)),
         ],
         out_specs=[
-            pl.BlockSpec((bn,), lambda p, t: (t,)),
-            pl.BlockSpec((m1,), lambda p, t: (0,)),
+            pl.BlockSpec((1, bn), lambda p, t: (ZERO, t)),
+            pl.BlockSpec((m1, 1), lambda p, t: (ZERO, ZERO)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n_pad,), w.dtype),
-            jax.ShapeDtypeStruct((m1,), w.dtype),
+            jax.ShapeDtypeStruct((1, n_pad), w.dtype),
+            jax.ShapeDtypeStruct((m1, 1), w.dtype),
         ],
         scratch_shapes=[
-            pltpu.VMEM((m1,), acc),
-            pltpu.VMEM((m1,), acc),
+            pltpu.VMEM((m1, 1), acc),
+            pltpu.VMEM((m1, 1), acc),
         ],
         interpret=interpret,
-    )(v_basis, w, mask)
-    return wout[:n], h
+        name="fused_orthog",
+    )(v_basis, w.reshape(1, n_pad), mask.reshape(m1, 1))
+    return wout[0, :n], h[:, 0]

@@ -1,9 +1,11 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
-Every op has a pure-jnp reference path (ref.py) — the default on CPU — and a
-Pallas path (`use_kernel=True`) compiled for TPU and validated on CPU via
-`interpret=True`. The solver/model layers call THESE wrappers so the kernel
-routing is a config flag, not a code change.
+Every op has a pure-jnp reference path (ref.py, the default) and a Pallas
+path (`use_kernel=True`). The solver/model layers call THESE wrappers so
+the kernel routing is a config flag, not a code change. The backend decides
+how a kernel runs, here and nowhere else (`interpret_mode`): compiled by
+Mosaic on a TPU, in the Pallas interpreter on the CPU. A caller may still
+pass `interpret=` explicitly (the CPU tests do).
 """
 from __future__ import annotations
 
@@ -14,14 +16,60 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 
+FP64_ON_TPU = (
+    "the Pallas kernels run fp32 on a TPU (Mosaic has no fp64): with "
+    "use_kernel=True set KrylovConfig(inner_dtype='float32', "
+    "cgs2_acc='native'), or keep fp64 with use_kernel=False")
+
+
+def interpret_mode(*dtypes) -> bool:
+    """How a kernel over operands of `dtypes` runs on this backend: False
+    (compiled) on a TPU, True (interpreted) on the CPU. Any other backend,
+    and an fp64 operand on a TPU, is an error — never a silent fallback."""
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend != "tpu":
+        raise RuntimeError(f"Pallas kernels run on a TPU (compiled) or the "
+                           f"CPU (interpreted), not on {backend!r}")
+    if not kernels_take(*dtypes):
+        raise TypeError(FP64_ON_TPU)
+    return False
+
+
+def kernels_take(*dtypes) -> bool:
+    """Whether the kernel path can run operands of `dtypes` on this backend
+    (fp64 only in the CPU interpreter). The solver's dispatch points
+    (`StencilOp`/`DIAOp.apply`, the Arnoldi cycle) ask this, so the fp64
+    work of a kernel solver (the mixed-precision residual replay and fp64
+    fallback cycles, the fp64 retry rung) runs on jnp on a TPU."""
+    wide = any(d is not None and jnp.dtype(d) == jnp.float64 for d in dtypes)
+    return not (wide and jax.default_backend() == "tpu")
+
+
+def check_solver_request(cfg, use_kernel: bool) -> None:
+    """The one check of a solver's kernel request, made where a solver is
+    built from its KrylovConfig: on a TPU, `use_kernel=True` with fp64
+    inner storage or fp64 CGS2 accumulation raises instead of running its
+    cycles on jnp."""
+    acc = "float64" if cfg.cgs2_acc == "float64" else None
+    if use_kernel and not kernels_take(cfg.inner_dtype, acc):
+        raise TypeError(FP64_ON_TPU)
+
+
+def _resolve(interpret, *dtypes) -> bool:
+    return interpret_mode(*dtypes) if interpret is None else interpret
+
 
 def stencil5_matvec(coeffs: jax.Array, x: jax.Array, *, use_kernel: bool = False,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool | None = None) -> jax.Array:
     """(…, 5, nx, ny) coeffs × (…, nx, ny) field → (…, nx, ny)."""
     if use_kernel:
         from repro.kernels.stencil_matvec import stencil5_matvec_pallas
 
-        fn = functools.partial(stencil5_matvec_pallas, interpret=interpret)
+        fn = functools.partial(stencil5_matvec_pallas,
+                               interpret=_resolve(interpret, coeffs.dtype,
+                                                  x.dtype))
         if x.ndim > 2:  # batched: map over leading dims
             for _ in range(x.ndim - 2):
                 fn = jax.vmap(fn)
@@ -30,7 +78,7 @@ def stencil5_matvec(coeffs: jax.Array, x: jax.Array, *, use_kernel: bool = False
 
 
 def dia_spmv(dia, x: jax.Array, *, use_kernel: bool = False,
-             interpret: bool = True, op_stride: int | None = None,
+             interpret: bool | None = None, op_stride: int | None = None,
              op_index: jax.Array | None = None) -> jax.Array:
     """DIA sparse matvec on flat (…, n) vectors.
 
@@ -61,6 +109,7 @@ def dia_spmv(dia, x: jax.Array, *, use_kernel: bool = False,
                                             dia_spmv_strided_pallas)
 
         data = dia.data
+        interpret = _resolve(interpret, data.dtype, x.dtype)
         if op_stride is not None:
             return dia_spmv_strided_pallas(dia.offsets, data, x,
                                            op_stride=op_stride,
@@ -88,7 +137,7 @@ def dia_spmv(dia, x: jax.Array, *, use_kernel: bool = False,
 
 
 def fused_orthog(v_basis: jax.Array, w: jax.Array, mask: jax.Array, *,
-                 use_kernel: bool = False, interpret: bool = True,
+                 use_kernel: bool = False, interpret: bool | None = None,
                  acc_dtype=None):
     """CGS2 projection: orthogonalize w against the masked rows of v_basis.
 
@@ -101,14 +150,15 @@ def fused_orthog(v_basis: jax.Array, w: jax.Array, mask: jax.Array, *,
     if use_kernel:
         from repro.kernels.fused_orthog import fused_orthog_pallas
 
-        return fused_orthog_pallas(v_basis, w, mask, interpret=interpret,
-                                   acc_dtype=acc_dtype)
+        return fused_orthog_pallas(
+            v_basis, w, mask, acc_dtype=acc_dtype,
+            interpret=_resolve(interpret, v_basis.dtype, w.dtype, acc_dtype))
     return ref.fused_orthog(v_basis, w, mask, acc_dtype=acc_dtype)
 
 
 def arnoldi_step(coeffs: jax.Array, inv_diag: jax.Array, c_rows: jax.Array,
                  v_basis: jax.Array, vin: jax.Array, mask: jax.Array, *,
-                 use_kernel: bool = False, interpret: bool = True,
+                 use_kernel: bool = False, interpret: bool | None = None,
                  acc_dtype=None):
     """One fused (deflated) Arnoldi inner iteration: Jacobi apply + 5-point
     stencil matvec + C-projection + CGS2 as ONE launch (the lockstep hot
@@ -119,16 +169,18 @@ def arnoldi_step(coeffs: jax.Array, inv_diag: jax.Array, c_rows: jax.Array,
     if use_kernel:
         from repro.kernels.arnoldi_step import arnoldi_step_pallas
 
-        return arnoldi_step_pallas(coeffs, inv_diag, c_rows, v_basis, vin,
-                                   mask, interpret=interpret,
-                                   acc_dtype=acc_dtype)
+        return arnoldi_step_pallas(
+            coeffs, inv_diag, c_rows, v_basis, vin, mask, acc_dtype=acc_dtype,
+            interpret=_resolve(interpret, coeffs.dtype, v_basis.dtype,
+                               acc_dtype))
     return ref.arnoldi_step(coeffs, inv_diag, c_rows, v_basis, vin, mask,
                             acc_dtype=acc_dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
-                    use_kernel: bool = False, interpret: bool = True) -> jax.Array:
+                    use_kernel: bool = False,
+                    interpret: bool | None = None) -> jax.Array:
     """Chunked-softmax attention (beyond-paper LM hot spot).
 
     q: (B, Hq, Tq, D), k/v: (B, Hkv, Tk, D) — GQA broadcast when Hq > Hkv.
@@ -136,6 +188,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if use_kernel:
         from repro.kernels.flash_attention import flash_attention_pallas
 
-        return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                      interpret=interpret)
+        return flash_attention_pallas(
+            q, k, v, causal=causal, window=window,
+            interpret=_resolve(interpret, q.dtype, k.dtype, v.dtype))
     return ref.flash_attention(q, k, v, causal=causal, window=window)

@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.tiling import ZERO, row_block
+
 
 def _kernel(c_ref, x_ref, xup_ref, xdn_ref, o_ref, *, nx_tiles: int):
     t = pl.program_id(0)
@@ -42,15 +44,13 @@ def _kernel(c_ref, x_ref, xup_ref, xdn_ref, o_ref, *, nx_tiles: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows"))
 def stencil5_matvec_pallas(coeffs: jax.Array, x: jax.Array, *,
-                           interpret: bool = True, block_rows: int = 64) -> jax.Array:
+                           interpret: bool, block_rows: int = 64) -> jax.Array:
     """coeffs (5, nx, ny) × x (nx, ny) → (nx, ny).
 
     Dtype-polymorphic: output/accumulation carry result_type(coeffs, x) —
     fp32 operands (mixed-precision inner cycles) never silently widen."""
     nx, ny = x.shape
-    bx = min(block_rows, nx)
-    while nx % bx:
-        bx -= 1  # largest divisor ≤ block_rows (grids here are powers of two)
+    bx = row_block(nx, block_rows)
     nt = nx // bx
     out_dtype = jnp.result_type(coeffs.dtype, x.dtype)
 
@@ -58,13 +58,14 @@ def stencil5_matvec_pallas(coeffs: jax.Array, x: jax.Array, *,
         functools.partial(_kernel, nx_tiles=nt),
         grid=(nt,),
         in_specs=[
-            pl.BlockSpec((5, bx, ny), lambda t: (0, t, 0)),
-            pl.BlockSpec((bx, ny), lambda t: (t, 0)),
+            pl.BlockSpec((5, bx, ny), lambda t: (ZERO, t, ZERO)),
+            pl.BlockSpec((bx, ny), lambda t: (t, ZERO)),
             # clamped neighbor tiles supply the halo rows
-            pl.BlockSpec((bx, ny), lambda t: (jnp.maximum(t - 1, 0), 0)),
-            pl.BlockSpec((bx, ny), lambda t: (jnp.minimum(t + 1, nt - 1), 0)),
+            pl.BlockSpec((bx, ny), lambda t: (jnp.maximum(t - 1, 0), ZERO)),
+            pl.BlockSpec((bx, ny), lambda t: (jnp.minimum(t + 1, nt - 1), ZERO)),
         ],
-        out_specs=pl.BlockSpec((bx, ny), lambda t: (t, 0)),
+        out_specs=pl.BlockSpec((bx, ny), lambda t: (t, ZERO)),
         out_shape=jax.ShapeDtypeStruct((nx, ny), out_dtype),
         interpret=interpret,
+        name="stencil5_matvec",
     )(coeffs, x, x, x)

@@ -23,7 +23,6 @@ import traceback
 
 import jax
 
-from repro import compat
 from repro.configs.base import SHAPES
 from repro.configs.registry import get_config, list_archs
 from repro.launch.mesh import (make_debug_mesh, make_production_mesh,
@@ -59,7 +58,7 @@ def _calibrated_costs(cfg, shape, mesh, optimizer):
     for r in (1, 2):
         lo, _ = lower_cell(_reduced_cfg(cfg, r), shape, mesh,
                            optimizer=optimizer)
-        cost = compat.cost_analysis(lo.compile())
+        cost = lo.compile().cost_analysis() or {}
         pts.append((r, float(cost.get("flops", 0.0) or 0.0),
                     float(cost.get("bytes accessed", 0.0) or 0.0)))
     (n1, f1, b1), (n2, f2, b2) = pts
@@ -113,7 +112,7 @@ def run_cell(arch: str, shape_name: str, mesh, mesh_name: str,
     rec["compile_s"] = round(time.perf_counter() - t1, 2)
 
     mem = _memory_dict(compiled)
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     hlo = compiled.as_text()
     raw_flops = float(cost.get("flops", 0.0) or 0.0)
     raw_bytes = float(cost.get("bytes accessed", 0.0) or 0.0)

@@ -11,7 +11,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.distributed.sharding import param_specs
 from repro.launch.partition import (batch_specs, cache_specs, logits_spec,
@@ -40,7 +39,7 @@ def _named(tree, mesh):
 def make_cell_plan(cfg: ModelConfig, shape: ShapeConfig, mesh,
                    optimizer: str = "adamw") -> CellPlan:
     """Build the step + shardings for a cell. Must run under
-    ``compat.set_mesh(mesh)`` so logical-axis resolution sees the mesh."""
+    ``jax.set_mesh(mesh)`` so logical-axis resolution sees the mesh."""
     b, t = shape.global_batch, shape.seq_len
     params_abs = api.abstract_params(cfg)
     p_specs = param_specs(params_abs)
@@ -112,7 +111,7 @@ def make_cell_plan(cfg: ModelConfig, shape: ShapeConfig, mesh,
 def lower_cell(cfg: ModelConfig, shape: ShapeConfig, mesh,
                optimizer: str = "adamw"):
     """Lower (no compile) one cell under the mesh. Returns (lowered, plan)."""
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         plan = make_cell_plan(cfg, shape, mesh, optimizer=optimizer)
         jitted = jax.jit(plan.step_fn,
                          in_shardings=plan.in_shardings,

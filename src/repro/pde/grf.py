@@ -4,8 +4,9 @@ perturbation source of the label-expansion stage (core/expand.py).
 
 Spectral (Matérn-like) sampling: white noise shaped by the power spectrum
     sqrt_spec(k) ∝ scale * (4π²|k|² + τ²)^(−α/2)
-via FFT. The white-noise tensor is the *latent*; its low-frequency block is
-the sorting feature ("parameter matrix" P^(i) of Algorithm 1).
+via the 2-D DFT, written as real matmuls. The white-noise tensor is the
+*latent*; its low-frequency block is the sorting feature ("parameter
+matrix" P^(i) of Algorithm 1).
 
 Key handling: batched draws derive per-draw keys with `jax.random.fold_in`
 on the draw index, NOT `jax.random.split` on the batch size — so draw i of
@@ -50,17 +51,41 @@ def sample_grf(spec: GRFSpec, key: jax.Array,
 
     The latent is the low-frequency complex spectrum (real/imag stacked):
     nearby latents ⇒ nearby fields, which is exactly the property the sorting
-    pass exploits. `dtype` selects the noise/spectrum precision — fp32 draws
-    run the FFT in complex64 (the label-expansion waves perturb fp64 anchors
+    pass exploits. `dtype` selects the noise/spectrum precision, and the
+    transforms run in it (the label-expansion waves perturb fp64 anchors
     but may sample perturbation fields in fp32).
     """
     noise = jax.random.normal(key, (spec.nx, spec.ny), dtype=dtype)
-    coef = jnp.fft.fft2(noise) * _sqrt_spectrum(spec, dtype=dtype)
-    field = jnp.real(jnp.fft.ifft2(coef))
+    re, im, field = _spectral_shape_dft(noise, _sqrt_spectrum(spec, dtype))
     m = spec.feature_modes
-    low = coef[:m, :m]
-    feats = jnp.concatenate([jnp.real(low).ravel(), jnp.imag(low).ravel()])
+    feats = jnp.concatenate([re[:m, :m].ravel(), im[:m, :m].ravel()])
     return field, feats
+
+
+def _dft(n: int, dtype):
+    """(C, S) with the n-point DFT matrix F = C − iS (symmetric)."""
+    k = jnp.arange(n)
+    ang = (2 * jnp.pi / n) * ((k[:, None] * k[None, :]) % n).astype(dtype)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _spectral_shape_dft(noise, sqrt_spec):
+    """fft2 → spectrum → real(ifft2) as real DFT matmuls: the TPU has no
+    complex128 FFT. Dense O(n³) for an n×n grid (an FFT is O(n² log n)),
+    which is small beside a solve at the families' grids. Returns
+    (Re coef, Im coef, field)."""
+    nx, ny = noise.shape
+    hi = jax.lax.Precision.HIGHEST
+    cx, sx = _dft(nx, noise.dtype)
+    cy, sy = _dft(ny, noise.dtype)
+    mm = partial(jnp.matmul, precision=hi)
+    a_re, a_im = mm(cx, noise), -mm(sx, noise)          # F_x · noise
+    re = (mm(a_re, cy) + mm(a_im, sy)) * sqrt_spec       # · F_y, shaped
+    im = (mm(a_im, cy) - mm(a_re, sy)) * sqrt_spec
+    b_re = mm(cx, re) - mm(sx, im)                       # conj(F_x) · coef
+    b_im = mm(cx, im) + mm(sx, re)
+    field = (mm(b_re, cy) - mm(b_im, sy)) / (nx * ny)   # real(· conj(F_y))
+    return re, im, field
 
 
 def batch_keys(key: jax.Array, n) -> jax.Array:

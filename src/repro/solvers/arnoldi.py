@@ -103,6 +103,9 @@ def _arnoldi_cycle_impl(op, c_rows, r0, tol_abs, *, m: int, orthog: str = "cgs2"
     acc_dtype = jnp.float64 if h_acc == "float64" else None
     k = c_rows.shape[0]
     dt = r0.dtype
+    # fp64 cycles of a kernel solver (its fp64 replay/fallback) run on jnp
+    # where the kernels take no fp64 (a TPU)
+    use_kernel = use_kernel and kops.kernels_take(dt, acc_dtype)
     beta = jnp.linalg.norm(r0)
     safe_beta = jnp.maximum(beta, jnp.finfo(dt).tiny)
 

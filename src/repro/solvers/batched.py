@@ -80,6 +80,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.kernels import ops as kops
 from repro.obs.telemetry import KrylovTelemetry, drain_chain
 from repro.solvers import devlinalg as dl
 from repro.solvers import gcrodr as _seq
@@ -454,6 +455,7 @@ class BatchedGCRODRSolver:
                 "BatchedGCRODRSolver implements the paper-faithful "
                 "ritz_refresh='cycle' schedule; 'final' needs per-chain "
                 "last-cycle snapshots (use the sequential engine)")
+        kops.check_solver_request(cfg, use_kernel)
         self.cfg = cfg
         self.use_kernel = use_kernel
         # policy: optional core.robust.RetryPolicy — arms the in-dispatch
@@ -546,6 +548,12 @@ class BatchedGCRODRSolver:
 
         Returns (x (B, n) np.ndarray, [SolveStats] * B).
         """
+        if self.sharding is None:
+            return self._solve_batch(ops, b, padded_rows)
+        with self.sharding.partitioner():
+            return self._solve_batch(ops, b, padded_rows)
+
+    def _solve_batch(self, ops, b, padded_rows):
         cfg = self.cfg
         if cfg.inner_dtype == "float32":
             return self._solve_batch_mixed(ops, b, padded_rows)

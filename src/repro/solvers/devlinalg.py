@@ -6,7 +6,8 @@ least squares via batched QR with an SVD min-norm fallback, stacked
 harmonic-Ritz extraction via a batched fixed-sweep subspace-iteration
 eigensolver on the small (m ≲ 200) pencils, and stacked masked triangular
 inverses. Everything here is pure `jnp` on TPU-supported primitives
-(matmul, QR, SVD, LU solve, `fori_loop`) so a whole GCRO-DR cycle — Arnoldi
+(matmul, QR, SVD, triangular solve, `fori_loop`; the TPU has no fp64 LU,
+so square solves go through QR) so a whole GCRO-DR cycle — Arnoldi
 sweep, LS update, recycle-space refresh — traces into ONE device program
 with no host round-trip (solvers/batched.py).
 
@@ -116,15 +117,27 @@ def tri_inv_stacked(r, want):
     return inv, ok
 
 
+def _qr_solve(a, b):
+    """Stacked a⁻¹·b via Householder QR + triangular solve — the TPU's
+    fp64 square solve (it implements no fp64 LU). A singular `a` yields
+    non-finite entries, like an LU solve; callers gate on that."""
+    q, r = jnp.linalg.qr(a)
+    return jax.lax.linalg.triangular_solve(
+        r, q.swapaxes(-1, -2) @ b, left_side=True, lower=False)
+
+
 def _svd_lstsq(a, rhs):
     """Stacked min-norm LS via SVD pinv — the rank-deficient fallback,
-    matching np.linalg.lstsq(rcond=None) cutoff semantics."""
-    u, s, vt = jnp.linalg.svd(a, full_matrices=False)
-    eps = jnp.finfo(a.dtype).eps
+    matching np.linalg.lstsq(rcond=None) cutoff semantics in the storage
+    dtype. The SVD itself runs in fp64 (the result is cast back): in JAX 0.9
+    an fp32 SVD with vectors aborts the TPU compiler; fp64 compiles."""
+    dt, wide = a.dtype, jnp.float64
+    u, s, vt = jnp.linalg.svd(a.astype(wide), full_matrices=False)
+    eps = jnp.finfo(dt).eps
     cut = s[..., :1] * max(a.shape[-2:]) * eps
-    sinv = jnp.where(s > cut, 1.0 / jnp.maximum(s, _tiny(a.dtype)), 0.0)
-    utb = jnp.einsum("bij,bi->bj", u, rhs)
-    return jnp.einsum("bji,bj->bi", vt, sinv * utb)
+    sinv = jnp.where(s > cut, 1.0 / jnp.maximum(s, _tiny(wide)), 0.0)
+    utb = jnp.einsum("bij,bi->bj", u, rhs.astype(wide))
+    return jnp.einsum("bji,bj->bi", vt, sinv * utb).astype(dt)
 
 
 def lstsq_stacked(a, rhs):
@@ -134,7 +147,9 @@ def lstsq_stacked(a, rhs):
     rhs: (B, R) with dead rows zeroed. One stacked QR solves the whole
     batch; chains whose R factor trips the conditioning gate are blended
     with the stacked SVD min-norm solution instead (the hostlinalg
-    np.linalg.lstsq fallback, without leaving the device).
+    np.linalg.lstsq fallback, without leaving the device). The SVD runs
+    only in a call where some chain trips the gate (`lax.cond`): a healthy
+    cycle pays for the QR alone.
     """
     q, r = jnp.linalg.qr(a)
     ok = _diag_ok(r)
@@ -143,8 +158,9 @@ def lstsq_stacked(a, rhs):
     safe = jnp.where(ok[:, None, None], r, eye[None])
     y_qr = jax.lax.linalg.triangular_solve(
         safe, qtb[..., None], left_side=True, lower=False)[..., 0]
-    y_svd = _svd_lstsq(a, rhs)
-    return jnp.where(ok[:, None], y_qr, y_svd)
+    return jax.lax.cond(
+        ok.all(), lambda: y_qr,
+        lambda: jnp.where(ok[:, None], y_qr, _svd_lstsq(a, rhs)))
 
 
 def hessenberg_lstsq_stacked(h, j, beta):
@@ -216,9 +232,9 @@ def harmonic_ritz_first_cycle_stacked(h, j, k: int,
     jm1 = jnp.clip(j - 1, 0, m - 1)
     em = jax.nn.one_hot(jm1, m, dtype=dt)
     h2 = h[jnp.arange(bsz), jnp.clip(j, 0, m), jm1]   # h[j, j-1] per chain
-    corr = jnp.linalg.solve(hm.swapaxes(1, 2), em[..., None])[..., 0]
+    corr = _qr_solve(hm.swapaxes(1, 2), em[..., None])[..., 0]
     a = hm + (h2 ** 2)[:, None, None] * corr[:, :, None] * em[:, None, :]
-    ainv = jnp.linalg.inv(a)
+    ainv = _qr_solve(a, jnp.broadcast_to(jnp.eye(m, dtype=dt), a.shape))
     p = _dominant_subspace(ainv, k, sweeps)
     p = p * _row_mask(j - 1, m)
     ok = ((j > k) & jnp.isfinite(p).all(axis=(1, 2))
@@ -282,7 +298,7 @@ def harmonic_ritz_deflated_stacked(g, whv, j, k: int,
     """
     a1 = g.swapaxes(1, 2) @ g                    # SPD (+ identity dead block)
     a2 = g.swapaxes(1, 2) @ whv
-    mm = jnp.linalg.solve(a1, a2)
+    mm = _qr_solve(a1, a2)
     solve_ok = jnp.isfinite(mm).all(axis=(1, 2))  # singular ĜᵀĜ → NaN → gate
     mm = jnp.where(solve_ok[:, None, None], mm, 0.0)
     p = _dominant_subspace(mm, k, sweeps)

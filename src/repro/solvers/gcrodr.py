@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.kernels import ops as kops
 from repro.obs.telemetry import KrylovTelemetry
 from repro.solvers.arnoldi import arnoldi_cycle
 from repro.solvers.gmres import (_downcast32, _ir_refine, _residual_norms,
@@ -125,6 +126,7 @@ class GCRODRSolver:
 
     def __init__(self, cfg: KrylovConfig, use_kernel: bool = False,
                  stall_break: bool = False):
+        kops.check_solver_request(cfg, use_kernel)
         self.cfg = cfg
         self.use_kernel = use_kernel
         # stall_break: break out of no-progress cycles instead of spinning to

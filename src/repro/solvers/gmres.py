@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.kernels import ops as kops
 from repro.obs.telemetry import KrylovTelemetry
 from repro.solvers.arnoldi import arnoldi_cycle
 from repro.solvers.hostlinalg import hessenberg_lstsq
@@ -251,6 +252,7 @@ def _gmres_solve_mixed(op: PreconditionedOp, b, cfg: KrylovConfig, x0=None,
 def solve_gmres(problem_op, b_field, cfg: KrylovConfig, precond=None,
                 use_kernel: bool = False):
     """Convenience wrapper over field-form problems (Stencil5 + (nx,ny) b)."""
+    kops.check_solver_request(cfg, use_kernel)
     base = as_operator(problem_op, use_kernel=use_kernel)
     op = PreconditionedOp(base, precond)
     x, stats = gmres_solve(op, jnp.asarray(b_field).reshape(-1), cfg)

@@ -44,7 +44,9 @@ class StencilOp:
     def apply(self, v: jax.Array) -> jax.Array:
         nx, ny = self.grid
         field = v.reshape(*v.shape[:-1], nx, ny)
-        out = kops.stencil5_matvec(self.coeffs, field, use_kernel=self.use_kernel)
+        out = kops.stencil5_matvec(
+            self.coeffs, field, use_kernel=self.use_kernel
+            and kops.kernels_take(self.coeffs.dtype, v.dtype))
         return out.reshape(*v.shape[:-1], nx * ny)
 
 
@@ -68,7 +70,9 @@ class DIAOp:
         return self.dia.n
 
     def apply(self, v: jax.Array) -> jax.Array:
-        return kops.dia_spmv(self.dia, v, use_kernel=self.use_kernel)
+        return kops.dia_spmv(self.dia, v, use_kernel=self.use_kernel
+                             and kops.kernels_take(self.dia.data.dtype,
+                                                   v.dtype))
 
 
 @jax.tree_util.register_pytree_node_class

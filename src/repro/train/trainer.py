@@ -23,7 +23,6 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.distributed.compression import compress_tree, init_error_tree
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optim import Optimizer, adamw
@@ -106,7 +105,7 @@ class Trainer:
             return new_state, {"loss": loss}
 
         if self.mesh is not None:
-            with compat.set_mesh(self.mesh):
+            with jax.set_mesh(self.mesh):
                 return jax.jit(step, donate_argnums=0)
         return jax.jit(step, donate_argnums=0)
 
@@ -131,7 +130,7 @@ class Trainer:
                 raise RuntimeError(f"injected fault at step {i}")
             batch = get(i)
             if self.mesh is not None:
-                with compat.set_mesh(self.mesh):
+                with jax.set_mesh(self.mesh):
                     self.state, metrics = self._step_fn(self.state, batch)
             else:
                 self.state, metrics = self._step_fn(self.state, batch)

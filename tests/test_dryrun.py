@@ -115,10 +115,8 @@ def test_cell_plans_build_for_every_arch_on_tiny_mesh():
     from repro.configs.registry import get_smoke_config, list_archs
     from repro.launch.steps import make_cell_plan
 
-    from repro import compat
-
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         for arch in list_archs():
             cfg = get_smoke_config(arch)
             for shape_name, shape in SHAPES.items():

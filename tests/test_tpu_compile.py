@@ -1,0 +1,206 @@
+"""The main-path Pallas kernels compile for a TPU v5e chip.
+
+No chip is needed: the TPU compiler compiles for a described topology, so
+these tests catch what the CPU interpreter cannot (block tiling, int32
+index maps, VMEM limits, ops Mosaic does not lower). Each kernel compiles
+as the lockstep solver calls it — vmapped over 8 chains, fp32 — at the
+families' default grid (64x64) and at 256x256 (n = 65,536, near the
+paper's largest systems). The topology is described inside a fixture, so
+only the worker that runs this file loads the TPU library.
+
+Also here: how the backend steers the kernel path (`kernels/ops.py`), and
+where the compile cache lands (`repro.compile_cache`).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+B, M1, K, KP1 = 8, 41, 15, 9     # chains, m + 1, k, expansion fan-out
+F32 = jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _kernel_case(name, nx, sds):
+    n = nx * nx
+    if name == "stencil5_matvec":
+        from repro.kernels.stencil_matvec import stencil5_matvec_pallas
+
+        fn = jax.vmap(lambda c, x: stencil5_matvec_pallas(c, x,
+                                                          interpret=False))
+        return fn, (sds(B, 5, nx, nx), sds(B, nx, nx))
+    if name == "fused_orthog":
+        from repro.kernels.fused_orthog import fused_orthog_pallas
+
+        fn = jax.vmap(lambda v, w, m: fused_orthog_pallas(v, w, m,
+                                                          interpret=False))
+        return fn, (sds(B, M1, n), sds(B, n), sds(B, M1))
+    if name == "arnoldi_step":
+        from repro.kernels.arnoldi_step import arnoldi_step_pallas
+
+        fn = jax.vmap(lambda *a: arnoldi_step_pallas(*a, interpret=False))
+        return fn, (sds(B, 5, nx, nx), sds(B, n), sds(B, K, n),
+                    sds(B, M1, n), sds(B, n), sds(B, M1))
+    from repro.kernels.dia_spmv import dia_spmv_strided_pallas
+
+    offsets = (-nx, -1, 0, 1, nx)   # Stencil5.to_dia, as expansion calls it
+
+    def fn(data, x):
+        return dia_spmv_strided_pallas(offsets, data, x, op_stride=KP1,
+                                       interpret=False)
+    return fn, (sds(B, 5, n), sds(B * KP1, n))
+
+
+@pytest.mark.parametrize("nx", [64, 256])
+@pytest.mark.parametrize("kernel", ["stencil5_matvec", "fused_orthog",
+                                    "arnoldi_step", "dia_spmv_strided"])
+def test_kernel_compiles_for_v5e(kernel, nx, one_chip):
+    def sds(*shape):
+        return jax.ShapeDtypeStruct(shape, F32, sharding=one_chip)
+
+    fn, args = _kernel_case(kernel, nx, sds)
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert kernel.split("_strided")[0] in hlo   # the kernel's stable name
+
+
+@pytest.mark.parametrize("dtype,acc", [(jnp.float64, None),
+                                       (jnp.float32, jnp.float64)],
+                         ids=["fp64_storage", "fp64_cgs2_acc"])
+def test_fp64_kernel_request_on_tpu_raises(dtype, acc, monkeypatch):
+    """On a TPU an fp64 kernel request is an error, never a quiet jnp
+    fallback (Mosaic has no fp64)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    nx, n = 8, 64
+    args = (jnp.ones((5, nx, nx), dtype), jnp.ones((n,), dtype),
+            jnp.ones((2, n), dtype), jnp.ones((M1, n), dtype),
+            jnp.ones((n,), dtype), jnp.ones((M1,), dtype))
+    with pytest.raises(TypeError, match="fp32 on a TPU"):
+        ops.arnoldi_step(*args, use_kernel=True, acc_dtype=acc)
+
+
+def test_backend_decides_interpret(monkeypatch):
+    assert ops.interpret_mode(jnp.float64) is True          # CPU: interpreter
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_mode(jnp.float32) is False         # TPU: compiled
+    assert not ops.kernels_take(jnp.float64)
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="not on 'gpu'"):
+        ops.interpret_mode(jnp.float32)
+
+
+@pytest.mark.parametrize("field", ["inner_dtype", "cgs2_acc"])
+def test_fp64_solver_request_on_tpu_raises(field, monkeypatch):
+    """A kernel solver built on a TPU from a config with fp64 inner storage
+    or fp64 CGS2 accumulation raises at construction; the fp32 kernel
+    config and the fp64 jnp config are accepted."""
+    from repro.solvers.batched import BatchedGCRODRSolver
+    from repro.solvers.gcrodr import GCRODRSolver
+    from repro.solvers.gmres import solve_gmres
+    from repro.solvers.types import KrylovConfig
+
+    kw = {"inner_dtype": "float32", field: "float64"}
+    cfg = KrylovConfig(**kw)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for make in (GCRODRSolver, BatchedGCRODRSolver):
+        with pytest.raises(TypeError, match="fp32 on a TPU"):
+            make(cfg, use_kernel=True)
+        make(cfg, use_kernel=False)
+        make(KrylovConfig(inner_dtype="float32"), use_kernel=True)
+    with pytest.raises(TypeError, match="fp32 on a TPU"):
+        solve_gmres(None, jnp.ones((4, 4)), cfg, use_kernel=True)
+
+
+def test_fp64_applies_leave_the_kernels_on_tpu(monkeypatch):
+    """The fp64 work of a kernel solver (the mixed-precision residual
+    replay, the fp64 fallback and retry cycles) applies through jnp where
+    the kernels take no fp64, instead of raising; fp32 keeps the kernels."""
+    from repro.kernels import ref
+    from repro.pde.dia import DIA
+    from repro.solvers.arnoldi import _arnoldi_cycle_impl
+    from repro.solvers.operator import DIAOp, PreconditionedOp, StencilOp
+    from repro.solvers.precond import JacobiPrecond
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.kernels_take(F32) and not ops.kernels_take(jnp.float64)
+    c = jax.random.normal(jax.random.PRNGKey(0), (5, 4, 4), jnp.float64)
+    v = jnp.arange(16.0, dtype=jnp.float64)
+    st = StencilOp(c, use_kernel=True)
+    assert jnp.allclose(st.apply(v),
+                        ref.stencil5_matvec(c, v.reshape(4, 4)).ravel())
+    dia = DIA(offsets=(-4, -1, 0, 1, 4), data=c.reshape(5, 16))
+    assert jnp.allclose(DIAOp(dia, use_kernel=True).apply(v),
+                        ref.dia_spmv(dia.offsets, dia.data, v))
+    op = PreconditionedOp(st, JacobiPrecond(jnp.ones(16)))
+    cyc = _arnoldi_cycle_impl(op, jnp.zeros((0, 16)), v, 1e-12, m=4,
+                              use_kernel=True)
+    assert int(cyc.j_used) == 4 and jnp.isfinite(cyc.h).all()
+
+
+def test_gspmd_partitioner_switch_exists():
+    """`ChainSharding.partitioner()` uses JAX's private Shardy switch
+    (`jax._src.config.use_shardy_partitioner`): this fails on the JAX that
+    drops it, before a sharded solve on a TPU would."""
+    from repro.distributed.sharding import ChainSharding
+
+    was = jax.config.jax_use_shardy_partitioner
+    with ChainSharding.partitioner():
+        assert jax.config.jax_use_shardy_partitioner is False
+    assert jax.config.jax_use_shardy_partitioner == was
+
+
+@pytest.mark.parametrize("from_env", [False, True], ids=["checkout", "env"])
+def test_compile_cache_placement(from_env, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and the program sets no directory;
+    otherwise the cache sits at the fixed `<root>/.jax_cache`."""
+    from repro import compile_cache
+
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    saved = {k: getattr(jax.config, k) for k in keys}
+    env_dir = str(tmp_path / "from_env")
+    if from_env:
+        monkeypatch.setenv(compile_cache.ENV, env_dir)
+    else:
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+    try:
+        got = compile_cache.enable(str(tmp_path))
+        if from_env:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == \
+                saved["jax_compilation_cache_dir"]
+        else:
+            assert got == str(tmp_path / ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        for k, v in saved.items():
+            jax.config.update(k, v)
