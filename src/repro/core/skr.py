@@ -336,11 +336,13 @@ class SteadyWork(pipeline.WorkAdapter):
         row's operators, factor the stacked preconditioner, pack the RHS."""
         cfg = self.cfg
         clamped = np.where(idx >= 0, idx, 0)
+        obs.hostlink("h2d", clamped)
         st5 = self._all_st5.take(jnp.asarray(clamped))   # (W, 5, nx, ny)
         if self.fault is not None and self.fault.nan_operator:
             from repro.pde.dia import Stencil5
 
             coeffs, dirty = np.array(st5.coeffs, copy=True), False
+            obs.hostlink("d2h", coeffs)
             for w, i in enumerate(idx):
                 if i < 0:
                     continue
@@ -348,6 +350,7 @@ class SteadyWork(pipeline.WorkAdapter):
                 if poisoned is not coeffs[w]:
                     coeffs[w], dirty = poisoned, True
             if dirty:   # the preconditioner factors the poisoned operator
+                obs.hostlink("h2d", coeffs)
                 st5 = Stencil5(jnp.asarray(coeffs))
         precond = make_preconditioner_batched(cfg.precond, st5,
                                               use_kernel=cfg.use_kernel)
@@ -358,6 +361,7 @@ class SteadyWork(pipeline.WorkAdapter):
             for w, i in enumerate(idx):
                 if i >= 0:
                     bvec[w] = self.fault.apply_rhs(int(i), bvec[w])
+        obs.hostlink("h2d", bvec)
         return ops, jnp.asarray(bvec)
 
     def execute_row(self, solver, t: int, idx: np.ndarray, prepared):

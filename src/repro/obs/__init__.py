@@ -4,12 +4,14 @@ One process-global switch gates three signal families:
 
 * **spans** (`obs.span(...)`) — nested wall-time tracing over pipeline
   phases, ring-buffered, exportable as JSONL or a Chrome/Perfetto
-  `trace.json` (`obs/trace.py`);
+  `trace.json`, and mirrored into the JAX profiler as `skr:<name>` host
+  annotations (`obs/trace.py`);
 * **device Krylov telemetry** — per-cycle per-chain convergence rings the
   lockstep solver accumulates ON DEVICE and drains in its one finalize
   fetch (`obs/telemetry.py`; threaded through `solvers/batched.py`);
-* **counters/gauges** (`obs.record_dispatch(...)`) — lockstep utilization
-  and iteration-imbalance scalars merged into `SequenceStats.summary()`
+* **counters/gauges** (`obs.record_dispatch(...)`, `obs.hostlink(...)`) —
+  lockstep utilization and efficiency, and the bytes the row path moves
+  between host and device, merged into `SequenceStats.summary()`
   (`obs/metrics.py`).
 
 Disabled (the default, and the state every import starts in) the
@@ -17,7 +19,7 @@ instrumentation compiles out: `span()` is a `None`-check returning a shared
 no-op, `krylov_capacity()` returns 0 so the jitted cycle programs trace
 WITHOUT telemetry buffers (identical jaxprs → bitwise-identical numerics,
 zero extra dispatches — regression-tested in tests/test_obs.py), and
-`record_dispatch` returns immediately.
+`record_dispatch` and `hostlink` return immediately.
 
 Usage:
 
@@ -27,10 +29,13 @@ Usage:
     obs.export_chrome_trace("results/TRACE_heat.json")
     print(obs.summary()["utilization"])
     obs.disable()
+    tracer, registry = obs.last()   # the closed session, still readable
 """
 from __future__ import annotations
 
 from typing import Optional
+
+import jax
 
 from repro.obs.metrics import Registry
 from repro.obs.telemetry import (KrylovTelemetry, TelemetryConfig,
@@ -40,7 +45,7 @@ from repro.obs.trace import NULL_SPAN, Tracer
 __all__ = [
     "enable", "disable", "enabled", "span", "instant", "counter",
     "counter_add", "gauge_set", "tracer", "registry", "record_dispatch",
-    "record_stream", "krylov_capacity",
+    "record_stream", "hostlink", "last", "krylov_capacity",
     "delta_enabled", "summary", "export_chrome_trace", "export_jsonl",
     "KrylovTelemetry", "TelemetryConfig", "drain_chain", "ring_order",
     "Tracer", "Registry",
@@ -49,6 +54,8 @@ __all__ = [
 _TRACER: Optional[Tracer] = None
 _REGISTRY: Optional[Registry] = None
 _KRYLOV: Optional[TelemetryConfig] = None
+# the (tracer, registry) of the session the last disable() closed
+_LAST: tuple = (None, None)
 
 
 def enable(trace_capacity: int = 65536, krylov_capacity: int = 128,
@@ -62,7 +69,8 @@ def enable(trace_capacity: int = 65536, krylov_capacity: int = 128,
     delta_qc: also record the per-cycle δ(Q,C) recycle-refresh angle (adds
     one (k×k) SVD to the fused deflated-cycle program).
     """
-    global _TRACER, _REGISTRY, _KRYLOV
+    global _TRACER, _REGISTRY, _KRYLOV, _LAST
+    _LAST = (None, None)
     _TRACER = Tracer(capacity=trace_capacity)
     _REGISTRY = Registry()
     _KRYLOV = TelemetryConfig(capacity=max(int(krylov_capacity), 1),
@@ -71,8 +79,12 @@ def enable(trace_capacity: int = 65536, krylov_capacity: int = 128,
 
 
 def disable():
-    """Turn observability OFF and drop all buffers."""
-    global _TRACER, _REGISTRY, _KRYLOV
+    """Turn observability OFF. The closed session's tracer and registry stay
+    readable through `last()` until the next `enable()`; nothing records
+    into them any more."""
+    global _TRACER, _REGISTRY, _KRYLOV, _LAST
+    if _TRACER is not None:
+        _LAST = (_TRACER, _REGISTRY)
     _TRACER = None
     _REGISTRY = None
     _KRYLOV = None
@@ -80,6 +92,12 @@ def disable():
 
 def enabled() -> bool:
     return _TRACER is not None
+
+
+def last() -> tuple:
+    """(tracer, registry) of the session the last `disable()` closed;
+    (None, None) before any, and from the next `enable()` on."""
+    return _LAST
 
 
 # ---------------------------------------------------------------- tracing
@@ -131,7 +149,7 @@ def gauge_set(name: str, value: float):
         r.gauge_set(name, value)
 
 
-def record_dispatch(live: int, total: int, iters=None, cycles: int = 0):
+def record_dispatch(live: int, total: int, iters=None, cycles=None):
     """Lockstep occupancy hook (see Registry.record_dispatch); also samples
     a Chrome counter track so utilization renders on the trace timeline."""
     r = _REGISTRY
@@ -155,6 +173,18 @@ def record_stream(queue_depth: int, occupied: int, slots: int):
     if t is not None:
         t.counter("stream", {"queue": queue_depth, "occupied": occupied,
                              "free": slots - occupied}, cat="serve")
+
+
+def hostlink(direction: str, *arrays):
+    """Count the bytes of `arrays` (pytrees of numpy or device arrays, by
+    `nbytes`) moved host→device (`direction` "h2d") or device→host ("d2h")
+    into the counter `hostlink.<direction>_bytes`; free no-op when
+    disabled. The row path calls it at each explicit put and fetch."""
+    r = _REGISTRY
+    if r is None:
+        return
+    r.counter_add(f"hostlink.{direction}_bytes",
+                  float(sum(a.nbytes for a in jax.tree.leaves(arrays))))
 
 
 # --------------------------------------------------- device Krylov config

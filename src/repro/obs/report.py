@@ -90,7 +90,8 @@ def render_report(families: dict, tracer=None, registry=None) -> str:
                    f"{int(c.get('lockstep.rows_total', 0))}")
         out.append(f"  utilization             "
                    f"{100.0 * snap['utilization']:7.1f}%")
-        imb = snap["gauges"].get("lockstep.iter_imbalance")
-        if imb is not None:
-            out.append(f"  iter imbalance (last)   {imb:8.2f}")
+        eff = registry.lockstep_eff()
+        if eff is not None:
+            out.append(f"  lockstep efficiency     {100.0 * eff:7.1f}%"
+                       "   (cycles needed / paid)")
     return "\n".join(out)

@@ -7,7 +7,14 @@ entry point degenerates to a `None` check returning a shared no-op object,
 so instrumented hot loops (the per-cycle flag fetch of the lockstep solver)
 pay one attribute load when tracing is off and NOTHING is allocated.
 
-Spans carry (name, category, start, duration, thread id, attrs). The ring
+Spans carry (name, category, start, duration, thread id, attrs). Each
+live span is also mirrored into the JAX profiler as a host annotation
+(`jax.profiler.TraceAnnotation`) named `skr:<name>`, with `.<what>` added
+for a span that carries a `what` argument (`skr:host_sync.cycle_flags`),
+so a profile taken while tracing is on places the program's own phases on
+the profiler's clock, next to the device operations; the `skr:` prefix
+tells them from JAX's own annotations. Outside a profile an annotation
+costs one native call on entry and exit. The ring
 buffer (`collections.deque(maxlen=...)`) bounds memory on long trajectory
 runs: old events fall off the front, and `dropped` counts them so exports
 are honest about truncation.
@@ -31,6 +38,8 @@ import time
 from collections import deque
 from typing import Optional
 
+from jax.profiler import TraceAnnotation
+
 
 class _NullSpan:
     """Shared no-op context manager returned while tracing is disabled."""
@@ -48,9 +57,10 @@ NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records itself into the tracer's ring on exit."""
+    """One live span: records itself into the tracer's ring on exit, and
+    holds its profiler annotation open while it runs."""
 
-    __slots__ = ("tracer", "name", "cat", "args", "t0")
+    __slots__ = ("tracer", "name", "cat", "args", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self.tracer = tracer
@@ -58,13 +68,19 @@ class _Span:
         self.cat = cat
         self.args = args
         self.t0 = 0
+        self.ann = None
 
     def __enter__(self):
+        what = self.args.get("what")
+        self.ann = TraceAnnotation(f"skr:{self.name}"
+                                   + (f".{what}" if what else ""))
+        self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self.ann.__exit__(*exc)
         self.tracer._record({
             "ph": "X", "name": self.name, "cat": self.cat,
             "ts": self.t0, "dur": t1 - self.t0,

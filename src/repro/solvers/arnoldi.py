@@ -99,74 +99,85 @@ def _arnoldi_cycle_impl(op, c_rows, r0, tol_abs, *, m: int, orthog: str = "cgs2"
     runs this whole dispatch in fp32 by handing in a casted operator and an
     fp32 residual; nothing below assumes f64.
     """
-    n = r0.shape[0]
-    acc_dtype = jnp.float64 if h_acc == "float64" else None
-    k = c_rows.shape[0]
-    dt = r0.dtype
-    # fp64 cycles of a kernel solver (its fp64 replay/fallback) run on jnp
-    # where the kernels take no fp64 (a TPU)
-    use_kernel = use_kernel and kops.kernels_take(dt, acc_dtype)
-    beta = jnp.linalg.norm(r0)
-    safe_beta = jnp.maximum(beta, jnp.finfo(dt).tiny)
+    with jax.named_scope("skr/arnoldi"):
+        n = r0.shape[0]
+        acc_dtype = jnp.float64 if h_acc == "float64" else None
+        k = c_rows.shape[0]
+        dt = r0.dtype
+        # fp64 cycles of a kernel solver (its fp64 replay/fallback) run on
+        # jnp where the kernels take no fp64 (a TPU)
+        use_kernel = use_kernel and kops.kernels_take(dt, acc_dtype)
+        beta = jnp.linalg.norm(r0)
+        safe_beta = jnp.maximum(beta, jnp.finfo(dt).tiny)
 
-    v = jnp.zeros((m + 1, n), dt).at[0].set(r0 / safe_beta)
-    h = jnp.zeros((m + 1, m), dt)
-    b = jnp.zeros((k, m), dt)
-    cs = jnp.zeros((m,), dt)
-    sn = jnp.zeros((m,), dt)
-    g = jnp.zeros((m + 1,), dt).at[0].set(beta)
+        v = jnp.zeros((m + 1, n), dt).at[0].set(r0 / safe_beta)
+        h = jnp.zeros((m + 1, m), dt)
+        b = jnp.zeros((k, m), dt)
+        cs = jnp.zeros((m,), dt)
+        sn = jnp.zeros((m,), dt)
+        g = jnp.zeros((m + 1,), dt).at[0].set(beta)
 
-    def cond(carry):
-        v, h, b, cs, sn, g, j, res, brk = carry
-        return (j < m) & (res > tol_abs) & (~brk)
+        def cond(carry):
+            v, h, b, cs, sn, g, j, res, brk = carry
+            return (j < m) & (res > tol_abs) & (~brk)
 
-    # fused single-launch inner iteration (tentpole kernel): Jacobi apply +
-    # stencil matvec + C-projection + CGS2 in one dispatch. Routed ONLY when
-    # the kernel path is requested AND the operator matches — the unfused
-    # composition below stays byte-for-byte for every other configuration.
-    fuse = use_kernel and _fusable(op, orthog)
-    if fuse:
-        inv_diag = (jnp.ones_like(r0) if op.precond is None
-                    else op.precond.inv_diag)
-
-    def body(carry):
-        v, h, b, cs, sn, g, j, res, brk = carry
+        # fused single-launch inner iteration (tentpole kernel): Jacobi
+        # apply + stencil matvec + C-projection + CGS2 in one dispatch.
+        # Routed ONLY when the kernel path is requested AND the operator
+        # matches — the unfused composition below stays byte-for-byte for
+        # every other configuration.
+        fuse = use_kernel and _fusable(op, orthog)
         if fuse:
-            mask = (jnp.arange(m + 1) <= j).astype(dt)
-            w, hcol, bj = kops.arnoldi_step(op.base.coeffs, inv_diag,
-                                            c_rows, v, v[j], mask,
-                                            use_kernel=True,
-                                            acc_dtype=acc_dtype)
-            b_new = b.at[:, j].set(bj) if k > 0 else b
-        else:
-            w = apply_op(op, v[j])
-            if k > 0:
-                bj = c_rows @ w
-                w = w - c_rows.T @ bj
-                b_new = b.at[:, j].set(bj)
-            else:
-                b_new = b
-            if orthog == "cgs2":
-                mask = (jnp.arange(m + 1) <= j).astype(dt)
-                w, hcol = kops.fused_orthog(v, w, mask, use_kernel=use_kernel,
-                                            acc_dtype=acc_dtype)
-            else:
-                w, hcol = _mgs(v, w, j, m)
-        hj1 = jnp.linalg.norm(w)
-        brk_new = hj1 < 1e-14 * safe_beta
-        v = v.at[j + 1].set(w / jnp.maximum(hj1, jnp.finfo(dt).tiny))
-        hcol = hcol.at[j + 1].set(hj1)
-        h = h.at[:, j].set(hcol)
-        # Progressive Givens on a copy of the new column → exact LS residual.
-        cs, sn, col = _givens_apply(cs, sn, hcol, j)
-        gj = g[j]
-        g = g.at[j].set(cs[j] * gj).at[j + 1].set(-sn[j] * gj)
-        res = jnp.abs(g[j + 1])
-        return (v, h, b_new, cs, sn, g, j + 1, res, brk_new)
+            inv_diag = (jnp.ones_like(r0) if op.precond is None
+                        else op.precond.inv_diag)
 
-    init = (v, h, b, cs, sn, g, jnp.array(0), beta, jnp.array(False))
-    v, h, b, cs, sn, g, j, res, brk = jax.lax.while_loop(cond, body, init)
-    return CycleResult(v=v, h=h, b=b, j_used=j, res_est=res, breakdown=brk)
+        def body(carry):
+            v, h, b, cs, sn, g, j, res, brk = carry
+            # phase scopes name their whole path (solvers/batched.py
+            # lists them); the rest of the body is the basis bookkeeping
+            if fuse:
+                mask = (jnp.arange(m + 1) <= j).astype(dt)
+                with jax.named_scope("skr/arnoldi/matvec"):
+                    w, hcol, bj = kops.arnoldi_step(
+                        op.base.coeffs, inv_diag, c_rows, v, v[j], mask,
+                        use_kernel=True, acc_dtype=acc_dtype)
+                b_new = b.at[:, j].set(bj) if k > 0 else b
+            else:
+                with jax.named_scope("skr/arnoldi/matvec"):
+                    w = apply_op(op, v[j])
+                if k > 0:
+                    with jax.named_scope("skr/arnoldi/orthog"):
+                        bj = c_rows @ w
+                        w = w - c_rows.T @ bj
+                    b_new = b.at[:, j].set(bj)
+                else:
+                    b_new = b
+                with jax.named_scope("skr/arnoldi/orthog"):
+                    if orthog == "cgs2":
+                        mask = (jnp.arange(m + 1) <= j).astype(dt)
+                        w, hcol = kops.fused_orthog(
+                            v, w, mask, use_kernel=use_kernel,
+                            acc_dtype=acc_dtype)
+                    else:
+                        w, hcol = _mgs(v, w, j, m)
+            hj1 = jnp.linalg.norm(w)
+            brk_new = hj1 < 1e-14 * safe_beta
+            v = v.at[j + 1].set(w / jnp.maximum(hj1, jnp.finfo(dt).tiny))
+            hcol = hcol.at[j + 1].set(hj1)
+            h = h.at[:, j].set(hcol)
+            # Progressive Givens on a copy of the new column → exact LS
+            # residual.
+            cs, sn, col = _givens_apply(cs, sn, hcol, j)
+            gj = g[j]
+            g = g.at[j].set(cs[j] * gj).at[j + 1].set(-sn[j] * gj)
+            res = jnp.abs(g[j + 1])
+            return (v, h, b_new, cs, sn, g, j + 1, res, brk_new)
+
+        init = (v, h, b, cs, sn, g, jnp.array(0), beta, jnp.array(False))
+        v, h, b, cs, sn, g, j, res, brk = jax.lax.while_loop(cond, body,
+                                                             init)
+        return CycleResult(v=v, h=h, b=b, j_used=j, res_est=res,
+                           breakdown=brk)
 
 
 arnoldi_cycle = partial(jax.jit,

@@ -101,17 +101,31 @@ _deflated_update_b = jax.vmap(_seq._deflated_update)
 _whv_blocks_b = jax.vmap(_seq._whv_blocks)
 _next_cu_b = jax.vmap(_seq._next_cu)
 _apply_cols_b = jax.vmap(jax.vmap(apply_op, in_axes=(None, 1), out_axes=1))
-_from_z_b = jax.jit(jax.vmap(lambda op, z: op.from_z(z)))
-# outer iterative-refinement step, per chain: x += d (upcast) + true fp64
-# residual of the UNpreconditioned base — one dispatch per outer pass
-_ir_accum_b = jax.jit(jax.vmap(_ir_accum))
+
+
+@jax.jit
+def _from_z_b(ops, z):
+    """Per-chain solution x from the preconditioned iterate z."""
+    with jax.named_scope("skr/finalize"):
+        return jax.vmap(lambda op, z: op.from_z(z))(ops, z)
+
+
+@jax.jit
+def _ir_accum_b(base, b, x, d):
+    """Outer iterative-refinement step, per chain: x += d (upcast) + true
+    fp64 residual of the UNpreconditioned base — one dispatch per outer
+    pass."""
+    with jax.named_scope("skr/update"):
+        return jax.vmap(_ir_accum)(base, b, x, d)
 
 
 @jax.jit
 def _downcast_masked(r, need):
     """fp32 correction right-hand sides: live rows downcast, the rest zero
     (a zero row is the lockstep engine's own padding no-op)."""
-    return jnp.where(jnp.asarray(need)[:, None], r, 0.0).astype(jnp.float32)
+    with jax.named_scope("skr/update"):
+        return jnp.where(jnp.asarray(need)[:, None], r,
+                         0.0).astype(jnp.float32)
 
 
 @jax.jit
@@ -158,6 +172,12 @@ def _mask(mask, new, old):
 # host drains them inside the finalize fetch it already pays, so the
 # host_syncs = 2 + cycles invariant holds with telemetry ON
 # (tests/test_transfer_guard.py runs both ways).
+#
+# Phase scopes: the programs' work sits under `jax.named_scope` names with
+# the root `skr` — `skr/entry`, `skr/arnoldi` (with `skr/arnoldi/matvec`
+# and `skr/arnoldi/orthog`, solvers/arnoldi.py), `skr/lstsq`, `skr/update`,
+# `skr/ritz`, `skr/finalize` — so that device time in a profile is named
+# by solver phase (README "Observability"). They change no equation.
 # ---------------------------------------------------------------------------
 
 
@@ -202,8 +222,9 @@ def _delta_qc_b(c_old, c_new, ok):
 @partial(jax.jit, static_argnames=("k",))
 def _zeros_state(b, *, k: int):
     bsz, n = b.shape
-    return (jnp.zeros_like(b), jnp.zeros((bsz, n, k), b.dtype),
-            jnp.zeros((bsz, n, k), b.dtype))
+    with jax.named_scope("skr/entry"):
+        return (jnp.zeros_like(b), jnp.zeros((bsz, n, k), b.dtype),
+                jnp.zeros((bsz, n, k), b.dtype))
 
 
 def _active_mask(s, aux):
@@ -260,42 +281,43 @@ def _entry(ops, b, z0, c0, u0, uc, cok, pad_in, tol, lim, div,
     contain=True the state gains a per-chain `quar` bool and aux gains the
     absolute divergence threshold `div * ||b||`; a chain whose RHS is
     already non-finite is quarantined at entry (its row never solves)."""
-    bsz = b.shape[0]
-    dt = b.dtype
-    bnorm = jnp.linalg.norm(b, axis=1)
-    tol_abs = tol * bnorm
-    zerob = bnorm == 0.0
-    pad = pad_in if pad_given else zerob
-    aux = dict(b=b, bnorm=bnorm, tol_abs=tol_abs, zerob=zerob, pad=pad,
-               lim=lim)
-    s = dict(z=z0, r=b, rnorm=bnorm, c=c0, u=u0,
-             est=jnp.zeros(bsz, bool), stalled=jnp.zeros(bsz, bool),
-             no_prog=jnp.zeros(bsz, jnp.int32),
-             iters=jnp.zeros(bsz, jnp.int32),
-             matvecs=jnp.zeros(bsz, jnp.int32),
-             cycles=jnp.zeros(bsz, jnp.int32))
-    if contain:
-        aux["div_abs"] = div * bnorm
-        s["quar"] = ~jnp.isfinite(bnorm) & ~pad
-    if use_carry and k > 0:
-        want = cok & ~zerob & ~pad & (bnorm > tol_abs)
-        au = _apply_cols_b(ops, uc)
-        q, rr = jnp.linalg.qr(au)
-        inv_rr, ok = dl.tri_inv_stacked(rr, want)
-        u_new = _mat_post_b(uc, inv_rr)
-        z2, r2, rn2 = _warm_start_b(u_new, q, s["z"], s["r"])
-        s["z"] = _mask(ok, z2, s["z"])
-        s["r"] = _mask(ok, r2, s["r"])
-        s["rnorm"] = jnp.where(ok, rn2, s["rnorm"])
-        s["c"] = _mask(ok, q, s["c"])
-        s["u"] = _mask(ok, u_new, s["u"])
-        s["est"] = ok
-        s["matvecs"] = jnp.where(want, k, 0).astype(jnp.int32)
-    if tele_cap > 0:
-        _tele_init(s, bsz, dt, tele_cap=tele_cap, tele_delta=tele_delta)
-    f = _flags(s, aux, jnp.zeros(bsz, bool), jnp.zeros(bsz, bool),
-               jnp.zeros((), bool))
-    return s, aux, f
+    with jax.named_scope("skr/entry"):
+        bsz = b.shape[0]
+        dt = b.dtype
+        bnorm = jnp.linalg.norm(b, axis=1)
+        tol_abs = tol * bnorm
+        zerob = bnorm == 0.0
+        pad = pad_in if pad_given else zerob
+        aux = dict(b=b, bnorm=bnorm, tol_abs=tol_abs, zerob=zerob, pad=pad,
+                   lim=lim)
+        s = dict(z=z0, r=b, rnorm=bnorm, c=c0, u=u0,
+                 est=jnp.zeros(bsz, bool), stalled=jnp.zeros(bsz, bool),
+                 no_prog=jnp.zeros(bsz, jnp.int32),
+                 iters=jnp.zeros(bsz, jnp.int32),
+                 matvecs=jnp.zeros(bsz, jnp.int32),
+                 cycles=jnp.zeros(bsz, jnp.int32))
+        if contain:
+            aux["div_abs"] = div * bnorm
+            s["quar"] = ~jnp.isfinite(bnorm) & ~pad
+        if use_carry and k > 0:
+            want = cok & ~zerob & ~pad & (bnorm > tol_abs)
+            au = _apply_cols_b(ops, uc)
+            q, rr = jnp.linalg.qr(au)
+            inv_rr, ok = dl.tri_inv_stacked(rr, want)
+            u_new = _mat_post_b(uc, inv_rr)
+            z2, r2, rn2 = _warm_start_b(u_new, q, s["z"], s["r"])
+            s["z"] = _mask(ok, z2, s["z"])
+            s["r"] = _mask(ok, r2, s["r"])
+            s["rnorm"] = jnp.where(ok, rn2, s["rnorm"])
+            s["c"] = _mask(ok, q, s["c"])
+            s["u"] = _mask(ok, u_new, s["u"])
+            s["est"] = ok
+            s["matvecs"] = jnp.where(want, k, 0).astype(jnp.int32)
+        if tele_cap > 0:
+            _tele_init(s, bsz, dt, tele_cap=tele_cap, tele_delta=tele_delta)
+        f = _flags(s, aux, jnp.zeros(bsz, bool), jnp.zeros(bsz, bool),
+                   jnp.zeros((), bool))
+        return s, aux, f
 
 
 @partial(jax.jit, static_argnames=("m", "k", "orthog", "use_kernel",
@@ -310,57 +332,67 @@ def _fresh_cycle(ops, s, aux, *, m: int, k: int, orthog: str,
     (k > 0) harmonic-Ritz space establishment, all under the same jit."""
     bsz, n = s["r"].shape
     dt = s["r"].dtype
-    active = _active_mask(s, aux)
-    eff_tol = jnp.where(active, aux["tol_abs"], jnp.inf)
-    empty_c = jnp.zeros((bsz, 0, n), dt)
+    with jax.named_scope("skr/update"):
+        active = _active_mask(s, aux)
+        eff_tol = jnp.where(active, aux["tol_abs"], jnp.inf)
+    with jax.named_scope("skr/arnoldi"):
+        empty_c = jnp.zeros((bsz, 0, n), dt)
     cyc = jax.vmap(partial(_arnoldi_cycle_impl, m=m, orthog=orthog,
                            use_kernel=use_kernel, h_acc=h_acc))(
         ops, empty_c, s["r"], eff_tol)
-    j = cyc.j_used.astype(jnp.int32)
-    step = j > 0
-    y = dl.hessenberg_lstsq_stacked(cyc.h, j, s["rnorm"])
-    rprev = s["rnorm"]
-    z, r, rn = _fresh_update_b(ops, aux["b"], s["z"], cyc.v, y.astype(dt))
-    if contain:
-        z, r, rn, quar = _contain_guard(s, aux, active, s["z"], s["r"],
-                                        rprev, z, r, rn)
-        s = dict(s, quar=quar)
-    s = dict(s, z=z, r=r, rnorm=rn,
-             iters=s["iters"] + jnp.where(step, j, 0),
-             matvecs=s["matvecs"] + jnp.where(step, j + 1, 0),
-             cycles=s["cycles"] + step.astype(jnp.int32))
-    if stall_break:
-        s["no_prog"] = jnp.where(step & (s["rnorm"] > 0.99 * rprev),
-                                 s["no_prog"] + 1, 0)
-    any_grew = jnp.zeros((), bool)
+    with jax.named_scope("skr/lstsq"):
+        j = cyc.j_used.astype(jnp.int32)
+        step = j > 0
+        y = dl.hessenberg_lstsq_stacked(cyc.h, j, s["rnorm"])
+    with jax.named_scope("skr/update"):
+        rprev = s["rnorm"]
+        z, r, rn = _fresh_update_b(ops, aux["b"], s["z"], cyc.v,
+                                   y.astype(dt))
+        if contain:
+            z, r, rn, quar = _contain_guard(s, aux, active, s["z"], s["r"],
+                                            rprev, z, r, rn)
+            s = dict(s, quar=quar)
+        s = dict(s, z=z, r=r, rnorm=rn,
+                 iters=s["iters"] + jnp.where(step, j, 0),
+                 matvecs=s["matvecs"] + jnp.where(step, j + 1, 0),
+                 cycles=s["cycles"] + step.astype(jnp.int32))
+        if stall_break:
+            s["no_prog"] = jnp.where(step & (s["rnorm"] > 0.99 * rprev),
+                                     s["no_prog"] + 1, 0)
+        any_grew = jnp.zeros((), bool)
     if k > 0:
         # establish / re-establish recycle spaces per chain, on device
-        p, ritz_ok = dl.harmonic_ritz_first_cycle_stacked(cyc.h, j, k)
-        q, inv_rr, qr_ok = dl.refresh_factors(cyc.h @ p, ritz_ok & step)
-        est_new = qr_ok if not contain else qr_ok & ~s["quar"]
-        c_new, yk = _fresh_cu_b(cyc.v, cyc.h, p, q)
-        u_new = _mat_post_b(yk, inv_rr)
-        s["c"] = _mask(est_new, c_new, s["c"])
-        s["u"] = _mask(est_new, u_new, s["u"])
-        s["est"] = s["est"] | est_new
+        with jax.named_scope("skr/ritz"):
+            p, ritz_ok = dl.harmonic_ritz_first_cycle_stacked(cyc.h, j, k)
+            q, inv_rr, qr_ok = dl.refresh_factors(cyc.h @ p,
+                                                  ritz_ok & step)
+            est_new = qr_ok if not contain else qr_ok & ~s["quar"]
+            c_new, yk = _fresh_cu_b(cyc.v, cyc.h, p, q)
+            u_new = _mat_post_b(yk, inv_rr)
+            s["c"] = _mask(est_new, c_new, s["c"])
+            s["u"] = _mask(est_new, u_new, s["u"])
+            s["est"] = s["est"] | est_new
     else:
         # adaptive restart growth (see gmres_solve): grow when any chain
         # ran a full cycle and stalled; the host doubles m on the flag
-        grew = (step & (j == m) & (s["rnorm"] > aux["tol_abs"])
-                & (s["rnorm"] > 0.5 * rprev))
-        any_grew = grew.any()
-        if can_grow:
-            # a longer cycle deserves a fresh shot at making progress
-            s["no_prog"] = jnp.where(any_grew, 0, s["no_prog"])
-        s["stalled"] = s["stalled"] | (cyc.breakdown & step
-                                      & (s["rnorm"] > aux["tol_abs"]))
-    if stall_break:
-        s["stalled"] = s["stalled"] | (s["no_prog"] >= 3)
-    if tele_cap > 0:
-        # a fresh cycle (re)establishes the space: no before/after pair to
-        # compare, so δ is recorded NaN
-        s = _tele_record(s, k, tele_cap=tele_cap, tele_delta=tele_delta)
-    return s, _flags(s, aux, active, step, any_grew)
+        with jax.named_scope("skr/update"):
+            grew = (step & (j == m) & (s["rnorm"] > aux["tol_abs"])
+                    & (s["rnorm"] > 0.5 * rprev))
+            any_grew = grew.any()
+            if can_grow:
+                # a longer cycle deserves a fresh shot at making progress
+                s["no_prog"] = jnp.where(any_grew, 0, s["no_prog"])
+            s["stalled"] = s["stalled"] | (cyc.breakdown & step
+                                          & (s["rnorm"] > aux["tol_abs"]))
+    with jax.named_scope("skr/update"):
+        if stall_break:
+            s["stalled"] = s["stalled"] | (s["no_prog"] >= 3)
+        if tele_cap > 0:
+            # a fresh cycle (re)establishes the space: no before/after
+            # pair to compare, so δ is recorded NaN
+            s = _tele_record(s, k, tele_cap=tele_cap,
+                             tele_delta=tele_delta)
+        return s, _flags(s, aux, active, step, any_grew)
 
 
 @partial(jax.jit, static_argnames=("mi", "k", "orthog", "use_kernel",
@@ -373,60 +405,71 @@ def _deflated_cycle(ops, s, aux, *, mi: int, k: int, orthog: str,
     """One lockstep deflated cycle (Alg. 2 l.19-33) as ONE device program:
     deflated Arnoldi sweep → stacked Ĝ least-squares → solution update →
     stacked generalized harmonic-Ritz refresh of (C, U)."""
-    active = _active_mask(s, aux)
-    eff_tol = jnp.where(active, aux["tol_abs"], jnp.inf)
+    with jax.named_scope("skr/update"):
+        active = _active_mask(s, aux)
+        eff_tol = jnp.where(active, aux["tol_abs"], jnp.inf)
+    with jax.named_scope("skr/arnoldi"):
+        c_rows = jnp.swapaxes(s["c"], 1, 2)
     cyc = jax.vmap(partial(_arnoldi_cycle_impl, m=mi, orthog=orthog,
                            use_kernel=use_kernel, h_acc=h_acc))(
-        ops, jnp.swapaxes(s["c"], 1, 2), s["r"], eff_tol)
-    j = cyc.j_used.astype(jnp.int32)
-    step = j > 0
-    dt = s["r"].dtype
+        ops, c_rows, s["r"], eff_tol)
+    with jax.named_scope("skr/lstsq"):
+        j = cyc.j_used.astype(jnp.int32)
+        step = j > 0
+        dt = s["r"].dtype
 
-    ctr, vr, dnorm = _rhs_and_dnorm_b(s["c"], s["u"], cyc.v, s["r"])
-    g = dl.assemble_g_stacked(dnorm, cyc.b, cyc.h, j)
-    rhs = jnp.concatenate([ctr, vr], axis=1)
-    ys = dl.lstsq_stacked(g, rhs)
-    # frozen chains (j = 0) still have Cᵀr ≠ 0 — force their update to the
-    # padded no-op the host engine produced by skipping them outright
-    ys = jnp.where(step[:, None], ys, 0.0)
-    y_k, y_m = ys[:, :k], ys[:, k:]
-    ut = _scaled_cols_b(s["u"], dnorm)
-    rprev = s["rnorm"]
-    z, r, rn = _deflated_update_b(ops, aux["b"], s["z"], ut, cyc.v,
-                                  y_k.astype(dt), y_m.astype(dt))
-    if contain:
-        z, r, rn, quar = _contain_guard(s, aux, active, s["z"], s["r"],
-                                        rprev, z, r, rn)
-        s = dict(s, quar=quar)
-    s = dict(s, z=z, r=r, rnorm=rn,
-             iters=s["iters"] + jnp.where(step, j, 0),
-             matvecs=s["matvecs"] + jnp.where(step, j + 1, 0),
-             cycles=s["cycles"] + step.astype(jnp.int32))
-    if stall_break:
-        s["no_prog"] = jnp.where(step & (s["rnorm"] > 0.99 * rprev),
-                                 s["no_prog"] + 1, 0)
-        s["stalled"] = s["stalled"] | (s["no_prog"] >= 3)
+        ctr, vr, dnorm = _rhs_and_dnorm_b(s["c"], s["u"], cyc.v, s["r"])
+        g = dl.assemble_g_stacked(dnorm, cyc.b, cyc.h, j)
+        rhs = jnp.concatenate([ctr, vr], axis=1)
+        ys = dl.lstsq_stacked(g, rhs)
+        # frozen chains (j = 0) still have Cᵀr ≠ 0 — force their update to
+        # the padded no-op the host engine produced by skipping them
+        ys = jnp.where(step[:, None], ys, 0.0)
+        y_k, y_m = ys[:, :k], ys[:, k:]
+    with jax.named_scope("skr/update"):
+        ut = _scaled_cols_b(s["u"], dnorm)
+        rprev = s["rnorm"]
+        z, r, rn = _deflated_update_b(ops, aux["b"], s["z"], ut, cyc.v,
+                                      y_k.astype(dt), y_m.astype(dt))
+        if contain:
+            z, r, rn, quar = _contain_guard(s, aux, active, s["z"], s["r"],
+                                            rprev, z, r, rn)
+            s = dict(s, quar=quar)
+        s = dict(s, z=z, r=r, rnorm=rn,
+                 iters=s["iters"] + jnp.where(step, j, 0),
+                 matvecs=s["matvecs"] + jnp.where(step, j + 1, 0),
+                 cycles=s["cycles"] + step.astype(jnp.int32))
+        if stall_break:
+            s["no_prog"] = jnp.where(step & (s["rnorm"] > 0.99 * rprev),
+                                     s["no_prog"] + 1, 0)
+            s["stalled"] = s["stalled"] | (s["no_prog"] >= 3)
 
     # next recycle spaces from the stacked generalized harmonic-Ritz pencil
-    cu, cv, vu, vv = _whv_blocks_b(s["c"], ut, cyc.v)
-    whv = dl.assemble_whv_stacked(cu, cv, vu, vv, j)
-    p, ritz_ok = dl.harmonic_ritz_deflated_stacked(g, whv, j, k)
-    if contain:   # a quarantined chain must not refresh from garbage
-        ritz_ok = ritz_ok & ~s["quar"]
-    q, inv_rr, ref_ok = dl.refresh_factors(g @ p, ritz_ok & step)
-    c_new, yk = _next_cu_b(ut, cyc.v, s["c"], p[:, :k], p[:, k:],
-                           q[:, :k], q[:, k:])
-    u_new = _mat_post_b(yk, inv_rr)
-    c_old = s["c"]
-    s["c"] = _mask(ref_ok, c_new, s["c"])
-    s["u"] = _mask(ref_ok, u_new, s["u"])
-    s["stalled"] = s["stalled"] | (cyc.breakdown & step
-                                  & (s["rnorm"] > aux["tol_abs"]))
+    with jax.named_scope("skr/ritz"):
+        cu, cv, vu, vv = _whv_blocks_b(s["c"], ut, cyc.v)
+        whv = dl.assemble_whv_stacked(cu, cv, vu, vv, j)
+        p, ritz_ok = dl.harmonic_ritz_deflated_stacked(g, whv, j, k)
+        if contain:   # a quarantined chain must not refresh from garbage
+            ritz_ok = ritz_ok & ~s["quar"]
+        q, inv_rr, ref_ok = dl.refresh_factors(g @ p, ritz_ok & step)
+        c_new, yk = _next_cu_b(ut, cyc.v, s["c"], p[:, :k], p[:, k:],
+                               q[:, :k], q[:, k:])
+        u_new = _mat_post_b(yk, inv_rr)
+        c_old = s["c"]
+        s["c"] = _mask(ref_ok, c_new, s["c"])
+        s["u"] = _mask(ref_ok, u_new, s["u"])
+    with jax.named_scope("skr/update"):
+        s["stalled"] = s["stalled"] | (cyc.breakdown & step
+                                      & (s["rnorm"] > aux["tol_abs"]))
     if tele_cap > 0:
-        delta = (_delta_qc_b(c_old, s["c"], ref_ok) if tele_delta else None)
-        s = _tele_record(s, k, tele_cap=tele_cap, tele_delta=tele_delta,
-                         delta=delta)
-    return s, _flags(s, aux, active, step, jnp.zeros((), bool))
+        with jax.named_scope("skr/ritz"):
+            delta = (_delta_qc_b(c_old, s["c"], ref_ok) if tele_delta
+                     else None)
+        with jax.named_scope("skr/update"):
+            s = _tele_record(s, k, tele_cap=tele_cap,
+                             tele_delta=tele_delta, delta=delta)
+    with jax.named_scope("skr/update"):
+        return s, _flags(s, aux, active, step, jnp.zeros((), bool))
 
 
 class BatchedGCRODRSolver:
@@ -548,10 +591,11 @@ class BatchedGCRODRSolver:
 
         Returns (x (B, n) np.ndarray, [SolveStats] * B).
         """
-        if self.sharding is None:
-            return self._solve_batch(ops, b, padded_rows)
-        with self.sharding.partitioner():
-            return self._solve_batch(ops, b, padded_rows)
+        with obs.span("solve_batch", cat="solver"):
+            if self.sharding is None:
+                return self._solve_batch(ops, b, padded_rows)
+            with self.sharding.partitioner():
+                return self._solve_batch(ops, b, padded_rows)
 
     def _solve_batch(self, ops, b, padded_rows):
         cfg = self.cfg
@@ -559,6 +603,8 @@ class BatchedGCRODRSolver:
             return self._solve_batch_mixed(ops, b, padded_rows)
         k = cfg.k
         t0 = time.perf_counter()
+        if not isinstance(b, jax.Array):
+            obs.hostlink("h2d", b)
         b = self._dev(jnp.asarray(b))
         if self.sharding is not None:
             ops = self.sharding.put_tree(ops)
@@ -570,32 +616,40 @@ class BatchedGCRODRSolver:
         # scalar host→device, which transfer_guard("disallow") rejects)
         z0, c0, u0 = (self._dev(a) for a in _zeros_state(b, k=k))
         use_carry = k > 0 and self.u_carry is not None
-        uc = (self._dev(jnp.asarray(self.u_carry)) if use_carry
-              else u0)
-        cok = jnp.asarray(self.carry_ok if use_carry
-                          else np.zeros(bsz, bool))
         pad_given = padded_rows is not None
-        pad_in = jnp.asarray(np.asarray(padded_rows) if pad_given
-                             else np.zeros(bsz, bool))
+        # containment is STATIC: no policy → the exact pre-containment
+        # programs, bitwise-identical
+        contain = self.policy is not None
+        div = (self.policy.divergence_ratio if contain else 0.0)
+        with obs.span("carry_upload", cat="solver"):
+            carry = self.u_carry if use_carry else None
+            cok_np = (self.carry_ok if use_carry
+                      else np.zeros(bsz, bool))
+            pad_np = (np.asarray(padded_rows) if pad_given
+                      else np.zeros(bsz, bool))
+            # 0-d numpy scalars: a bare python scalar counts as an
+            # IMPLICIT host→device transfer under
+            # jax.transfer_guard("disallow")
+            scalars = (np.asarray(cfg.tol, dt),
+                       np.asarray(cfg.maxiter, np.int32),
+                       np.asarray(div, dt))
+            obs.hostlink("h2d", carry, cok_np, pad_np, scalars)
+            uc = self._dev(jnp.asarray(carry)) if use_carry else u0
+            cok = jnp.asarray(cok_np)
+            pad_in = jnp.asarray(pad_np)
+            tol_d, lim_d, div_d = (jnp.asarray(a) for a in scalars)
         # telemetry config is STATIC: capacity 0 (obs disabled) traces the
         # exact pre-telemetry programs — bitwise-identical, no extra work
         tele_cap = obs.krylov_capacity()
         tele_delta = obs.delta_enabled() and k > 0
-        # containment is STATIC the same way: no policy → the exact
-        # pre-containment programs, bitwise-identical
-        contain = self.policy is not None
-        div = (self.policy.divergence_ratio if contain else 0.0)
-        # 0-d numpy scalars: a bare python scalar counts as an IMPLICIT
-        # host→device transfer under jax.transfer_guard("disallow")
         s, aux, f = _entry(ops, b, z0, c0, u0, uc, cok, pad_in,
-                           jnp.asarray(np.asarray(cfg.tol, dt)),
-                           jnp.asarray(np.asarray(cfg.maxiter, np.int32)),
-                           jnp.asarray(np.asarray(div, dt)),
+                           tol_d, lim_d, div_d,
                            k=k, use_carry=use_carry, pad_given=pad_given,
                            contain=contain, tele_cap=tele_cap,
                            tele_delta=tele_delta)
         with obs.span("host_sync", cat="solver", what="entry_flags"):
             fl = jax.device_get(f)
+        obs.hostlink("d2h", fl)
         any_active, all_est = bool(fl[0]), bool(fl[1])
         host_syncs, dispatches = 1, 1
 
@@ -604,21 +658,23 @@ class BatchedGCRODRSolver:
 
         # ---- the cycle loop: one fused dispatch + one 4-flag sync each ---
         while any_active:
-            if k == 0 or not all_est:
-                s, f = _fresh_cycle(
-                    ops, s, aux, m=m_fresh, k=k, orthog=cfg.orthog,
-                    use_kernel=self.use_kernel, h_acc=cfg.cgs2_acc,
-                    stall_break=self.stall_break,
-                    can_grow=m_fresh < m_cap, contain=contain,
-                    tele_cap=tele_cap, tele_delta=tele_delta)
-            else:
-                s, f = _deflated_cycle(
-                    ops, s, aux, mi=cfg.m - k, k=k, orthog=cfg.orthog,
-                    use_kernel=self.use_kernel, h_acc=cfg.cgs2_acc,
-                    stall_break=self.stall_break, contain=contain,
-                    tele_cap=tele_cap, tele_delta=tele_delta)
+            with obs.span("cycle_dispatch", cat="solver"):
+                if k == 0 or not all_est:
+                    s, f = _fresh_cycle(
+                        ops, s, aux, m=m_fresh, k=k, orthog=cfg.orthog,
+                        use_kernel=self.use_kernel, h_acc=cfg.cgs2_acc,
+                        stall_break=self.stall_break,
+                        can_grow=m_fresh < m_cap, contain=contain,
+                        tele_cap=tele_cap, tele_delta=tele_delta)
+                else:
+                    s, f = _deflated_cycle(
+                        ops, s, aux, mi=cfg.m - k, k=k, orthog=cfg.orthog,
+                        use_kernel=self.use_kernel, h_acc=cfg.cgs2_acc,
+                        stall_break=self.stall_break, contain=contain,
+                        tele_cap=tele_cap, tele_delta=tele_delta)
             with obs.span("host_sync", cat="solver", what="cycle_flags"):
                 fl = jax.device_get(f)
+            obs.hostlink("d2h", fl)
             any_active, all_est, any_step, any_grew = map(bool, fl[:4])
             if contain and bool(fl[4]):
                 # the health flag rides the SAME fetch: zero extra syncs
@@ -649,6 +705,7 @@ class BatchedGCRODRSolver:
             fetch = fetch + tuple(s[t] for t in tkeys) + (s["tcnt"],)
         with obs.span("host_sync", cat="solver", what="finalize"):
             got = jax.device_get(fetch)
+        obs.hostlink("d2h", got)
         (x, rnorm, iters, matvecs, cycles, stalled, established, u_np,
          bnorm, zerob, pad) = got[:11]
         quar = got[11] if contain else np.zeros(bsz, bool)
@@ -689,33 +746,36 @@ class BatchedGCRODRSolver:
         # lockstep occupancy: this solve was one dispatch of bsz rows, of
         # which the non-padded ones did real work
         if obs.enabled():
-            obs.record_dispatch(int((~pad).sum()), bsz,
-                                iters=[int(iters[i]) for i in range(bsz)
-                                       if not pad[i]],
-                                cycles=host_syncs - 2)
+            live = np.nonzero(~pad)[0]
+            obs.record_dispatch(len(live), bsz,
+                                iters=[int(iters[i]) for i in live],
+                                cycles=[int(cycles[i]) for i in live])
 
         if k > 0:
-            # carry Ỹ_k per chain (Alg. 2 line 34); chains that never owned
-            # a space this solve keep their previous carry — BITWISE (the
-            # old numpy rows are reused, not round-tripped). The carry is
-            # stored in the SOLVE dtype (fp32 under the mixed inner solver).
-            if contain:
-                # carry quarantine: a quarantined chain's space was built
-                # from (or alongside) a diverging iterate — never let it
-                # seed the chain's NEXT system; the chain restarts cold
-                established = established & ~quar
-            if self.u_carry is None:
-                self.u_carry = np.zeros((bsz, n, k), dtype=u_np.dtype)
-                self.carry_ok = np.zeros(bsz, dtype=bool)
-            keep = established[:, None, None]
-            self.u_carry = np.where(keep, u_np,
-                                    self.u_carry.astype(u_np.dtype))
-            self.carry_ok = self.carry_ok | established
-            if contain and quar.any():
-                self.u_carry[quar] = 0.0
-                self.carry_ok = self.carry_ok & ~quar
-                obs.counter_add("health.quarantined_chains",
-                                int(quar.sum()))
+            with obs.span("carry_store", cat="solver"):
+                # carry Ỹ_k per chain (Alg. 2 line 34); chains that never
+                # owned a space this solve keep their previous carry —
+                # BITWISE (the old numpy rows are reused, not
+                # round-tripped). The carry is stored in the SOLVE dtype
+                # (fp32 under the mixed inner solver).
+                if contain:
+                    # carry quarantine: a quarantined chain's space was
+                    # built from (or alongside) a diverging iterate — never
+                    # let it seed the chain's NEXT system; the chain
+                    # restarts cold
+                    established = established & ~quar
+                if self.u_carry is None:
+                    self.u_carry = np.zeros((bsz, n, k), dtype=u_np.dtype)
+                    self.carry_ok = np.zeros(bsz, dtype=bool)
+                keep = established[:, None, None]
+                self.u_carry = np.where(keep, u_np,
+                                        self.u_carry.astype(u_np.dtype))
+                self.carry_ok = self.carry_ok | established
+                if contain and quar.any():
+                    self.u_carry[quar] = 0.0
+                    self.carry_ok = self.carry_ok & ~quar
+                    obs.counter_add("health.quarantined_chains",
+                                    int(quar.sum()))
         self.systems_solved += int((~zerob & ~pad).sum())
         return x, stats
 
@@ -734,6 +794,9 @@ class BatchedGCRODRSolver:
         """
         cfg = self.cfg
         t0 = time.perf_counter()
+        if not isinstance(b, jax.Array):
+            b = np.asarray(b, np.float64)
+            obs.hostlink("h2d", b)
         b = self._dev(jnp.asarray(b, jnp.float64))
         if self.sharding is not None:
             ops = self.sharding.put_tree(ops)
@@ -741,6 +804,7 @@ class BatchedGCRODRSolver:
         x = self._dev(jnp.zeros((bsz, n), b.dtype))
         r = b
         bnorm = np.asarray(jnp.linalg.norm(b, axis=1))
+        obs.hostlink("d2h", bnorm)
         host_syncs, dispatches = 1, 1
         rnorm = bnorm.copy()
         tol_abs = cfg.tol * bnorm
@@ -770,9 +834,10 @@ class BatchedGCRODRSolver:
         # push the public carry (possibly from a checkpoint or an earlier
         # precision) down into the inner solver, stored fp32
         if self.u_carry is not None:
-            inner.u_carry = np.asarray(self.u_carry, np.float32)
-            inner.carry_ok = (self.carry_ok.copy()
-                              if self.carry_ok is not None else None)
+            with obs.span("carry_upload", cat="solver"):
+                inner.u_carry = np.asarray(self.u_carry, np.float32)
+                inner.carry_ok = (self.carry_ok.copy()
+                                  if self.carry_ok is not None else None)
         fallback = False
         passes = 0
         while True:
@@ -794,6 +859,7 @@ class BatchedGCRODRSolver:
                                                    / rnorm[need]).min())))
                 inner.cfg = dataclasses.replace(cfg, inner_dtype="float64",
                                                 tol=tol_i, maxiter=budget)
+                obs.hostlink("h2d", need)
                 d, st_in = inner.solve_batch(ops32, _downcast_masked(r, need))
                 outer += need
             else:
@@ -816,6 +882,7 @@ class BatchedGCRODRSolver:
                 self._inner64.carry_ok = (inner.carry_ok.copy()
                                           if inner.carry_ok is not None
                                           else None)
+                obs.hostlink("h2d", need)
                 rhs = jnp.where(jnp.asarray(need)[:, None], r, 0.0)
                 d, st_in = self._inner64.solve_batch(ops, rhs)
                 if self._inner64.u_carry is not None:
@@ -831,12 +898,16 @@ class BatchedGCRODRSolver:
                 matvecs[i] += st_in[i].matvecs
                 cycles[i] += st_in[i].cycles
             rprev, x_prev, r_prev = rnorm, x, r
-            x, r, rn = _ir_accum_b(ops.base, b, x, jnp.asarray(d))
+            obs.hostlink("h2d", d)
+            with obs.span("cycle_dispatch", cat="solver"):
+                x, r, rn = _ir_accum_b(ops.base, b, x, jnp.asarray(d))
             matvecs += need
             rnorm = np.asarray(rn)
+            obs.hostlink("d2h", rnorm)
             host_syncs += 1
             bad = need & (~np.isfinite(rnorm) | (rnorm > rprev))
             if bad.any():   # overflow OR diverging correction — roll back
+                obs.hostlink("h2d", bad, bad)   # the masks of two selects
                 x = _sel(~bad, x, x_prev)
                 r = _sel(~bad, r, r_prev)
                 rnorm = np.where(bad, rprev, rnorm)
@@ -852,6 +923,7 @@ class BatchedGCRODRSolver:
         # ---- finalize ----------------------------------------------------
         self.x_device = x   # fp64 accumulated iterate, device-resident
         x_np = np.asarray(x)
+        obs.hostlink("d2h", x_np)
         host_syncs += 1
         wall = time.perf_counter() - t0
         converged = zerob | (rnorm <= tol_abs)
@@ -893,15 +965,16 @@ class BatchedGCRODRSolver:
                     if outer_hist is not None and not pad[i] else None),
             ))
         if cfg.k > 0 and inner.u_carry is not None:
-            self.u_carry = np.asarray(inner.u_carry, np.float32)
-            self.carry_ok = (inner.carry_ok.copy()
-                             if inner.carry_ok is not None else None)
-            if quar.any():   # carry quarantine, as in the fp64 path
-                self.u_carry[quar] = 0.0
-                if self.carry_ok is not None:
-                    self.carry_ok = self.carry_ok & ~quar
-                inner.u_carry[quar] = 0.0
-                if inner.carry_ok is not None:
-                    inner.carry_ok = inner.carry_ok & ~quar
+            with obs.span("carry_store", cat="solver"):
+                self.u_carry = np.asarray(inner.u_carry, np.float32)
+                self.carry_ok = (inner.carry_ok.copy()
+                                 if inner.carry_ok is not None else None)
+                if quar.any():   # carry quarantine, as in the fp64 path
+                    self.u_carry[quar] = 0.0
+                    if self.carry_ok is not None:
+                        self.carry_ok = self.carry_ok & ~quar
+                    inner.u_carry[quar] = 0.0
+                    if inner.carry_ok is not None:
+                        inner.carry_ok = inner.carry_ok & ~quar
         self.systems_solved += int((~zerob & ~pad).sum())
         return x_np, stats

@@ -231,9 +231,9 @@ class SequenceStats:
                 "label_quality": self.label_quality,
             },
         }
-        # merge the live telemetry registry (occupancy counters, imbalance
-        # gauges) when observability is on; a late import keeps the stats
-        # layer usable without the obs package on the path
+        # merge the live telemetry registry (occupancy, lockstep and
+        # host-link counters) when observability is on; a late import keeps
+        # the stats layer usable without the obs package on the path
         from repro import obs
         if obs.enabled():
             out["obs"] = obs.summary()

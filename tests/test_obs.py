@@ -12,7 +12,10 @@ The load-bearing guarantees:
 * the Chrome trace export is loadable and shows row prefetch overlapping
   solve dispatch on distinct thread tracks;
 * the fused device δ(Q,C) proxy agrees with the host oracle
-  `core.metrics.delta_subspace`.
+  `core.metrics.delta_subspace`;
+* the solver's phase scopes name its device work and change no equation;
+  spans mirror into the profiler as `skr:` annotations; the lockstep and
+  host-link counters equal their reckoning from SolveStats and shapes.
 """
 import json
 
@@ -159,12 +162,21 @@ def test_device_delta_qc_matches_host_oracle():
 # ------------------------------------------------------ registry/summary
 def test_registry_utilization_and_summary_merge():
     obs.enable()
-    obs.record_dispatch(3, 4, iters=[10, 12, 14], cycles=2)
+    obs.record_dispatch(3, 4, iters=[10, 12, 14], cycles=[2, 1, 2])
     snap = obs.summary()
     assert snap["utilization"] == pytest.approx(0.75)
     assert snap["counters"]["lockstep.rows_live"] == 3
     assert snap["counters"]["lockstep.rows_total"] == 4
+    assert snap["counters"]["krylov.iterations"] == 36
     assert snap["counters"]["krylov.cycles"] == 2
+    # the lockstep efficiency pair replaces the last-value imbalance gauge
+    assert snap["counters"]["lockstep.cycles_needed"] == 5
+    assert snap["counters"]["lockstep.cycles_paid"] == 6
+    assert obs.registry().lockstep_eff() == pytest.approx(5 / 6)
+    assert snap["gauges"] == {}
+    from repro.obs.report import render_report
+    assert "lockstep efficiency        83.3%" in render_report(
+        {}, registry=obs.registry())
     # SequenceStats.summary() carries the live registry when enabled
     seq = SequenceStats()
     assert "obs" in seq.summary()
@@ -228,3 +240,208 @@ def test_heat_trajectory_trace_and_telemetry(tmp_path):
     assert {t for *_, t in prep}.isdisjoint({t for *_, t in exe})
     assert any(a < e1 and s1 < b for a, b, _ in prep
                for s1, e1, _ in exe), "prefetch/solve overlap missing"
+
+
+# ------------------------------------------------ device phase scopes
+SCOPES = ("skr/entry", "skr/arnoldi", "skr/arnoldi/matvec",
+          "skr/arnoldi/orthog", "skr/lstsq", "skr/update", "skr/ritz",
+          "skr/finalize")
+
+
+def _programs(k=4):
+    """The lockstep solver's four device programs at a small shape, each
+    as (jitted function, positional args, static kwargs)."""
+    from repro.solvers import batched as bt
+
+    ops, b = _batched_ops(nx=8)
+    b = jnp.asarray(b)
+    bsz = b.shape[0]
+    z0, c0, u0 = bt._zeros_state(b, k=k)
+    args = (ops, b, z0, c0, u0, u0, jnp.ones(bsz, bool),
+            jnp.zeros(bsz, bool), jnp.asarray(1e-8),
+            jnp.asarray(np.int32(100)), jnp.asarray(0.0))
+    entry_kw = dict(k=k, use_carry=True, pad_given=True, contain=True)
+    s, aux, _ = bt._entry(*args, **entry_kw)
+    cyc_kw = dict(k=k, orthog="cgs2", use_kernel=False, h_acc="native",
+                  stall_break=True, contain=True)
+    return {"entry": (bt._entry, args, entry_kw),
+            "fresh": (bt._fresh_cycle, (ops, s, aux),
+                      dict(cyc_kw, m=10, can_grow=False)),
+            "deflated": (bt._deflated_cycle, (ops, s, aux),
+                         dict(cyc_kw, mi=6)),
+            "finalize": (bt._from_z_b, (ops, s["z"]), {})}
+
+
+def test_phase_scopes_name_the_programs_work():
+    """Every scope of the table is in the programs' HLO `op_name`
+    metadata, and nearly all of the cycle programs' own instructions
+    carry an `skr` scope: the rest are the call sites of a few nested jits
+    (`jit(norm)`), whose bodies carry it."""
+    import re
+
+    seen = set()
+    for name, (fn, args, kw) in _programs().items():
+        hlo = fn.lower(*args, **kw).as_text(dialect="hlo", debug_info=True)
+        own = [n for n in re.findall(r'op_name="([^"]*)"', hlo)
+               if n.startswith("jit(")]
+        seen |= {sc for sc in SCOPES for n in own if sc in n}
+        bare = [n for n in own if "skr" not in n]
+        assert all("/" not in n for n in bare), (name, bare)
+        if name in ("fresh", "deflated"):
+            assert len(bare) <= 0.1 * len(own), (name, bare, len(own))
+    assert seen == set(SCOPES)
+
+
+def test_phase_scopes_change_no_equation(monkeypatch):
+    """The scopes are trace-time metadata: each program's jaxpr is the
+    same with `jax.named_scope` made a no-op."""
+    import contextlib
+
+    def jaxprs():
+        out = {}
+        for name, (fn, args, kw) in _programs().items():
+            out[name] = str(jax.make_jaxpr(
+                lambda *a, fn=fn, kw=kw: fn.__wrapped__(*a, **kw))(*args))
+        return out
+
+    scoped = jaxprs()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    assert jaxprs() == scoped
+
+
+# --------------------------------------- spans, counters: off and on
+def _two_solves(solver, ops, b):
+    """A cold then a warm-started solve (carry upload and store)."""
+    x1, st1 = solver.solve_batch(ops, b)
+    x2, st2 = solver.solve_batch(ops, 1.5 * b)
+    return [np.asarray(x1), np.asarray(x2)], st1 + st2
+
+
+def test_obs_off_creates_no_span_and_computes_no_counter(monkeypatch):
+    """Off → on (spans and counters, no device telemetry: the benchmark's
+    traced mode) → off, under the transfer guard: the outputs agree
+    bitwise, the sync and dispatch counts match, and while off no span
+    object is built and no counter computed."""
+    from repro.obs import trace as obs_trace
+    from repro.obs.metrics import Registry
+
+    ops, b = _batched_ops()
+    cfg = KrylovConfig(m=18, k=6, tol=1e-8, maxiter=2000)
+
+    def run():
+        with jax.transfer_guard("disallow"):
+            return _two_solves(BatchedGCRODRSolver(cfg), ops, b)
+
+    def boom(*a, **k):
+        raise AssertionError("observability work while disabled")
+
+    with monkeypatch.context() as m:
+        m.setattr(obs_trace._Span, "__init__", boom)
+        m.setattr(Registry, "counter_add", boom)
+        m.setattr(Registry, "record_dispatch", boom)
+        xs_off, st_off = run()
+    obs.enable(krylov_capacity=0)
+    xs_on, st_on = run()
+    assert obs.summary()["counters"]["hostlink.d2h_bytes"] > 0
+    obs.disable()
+    xs_off2, st_off2 = run()
+    for a, b2, c in zip(xs_off, xs_on, xs_off2):
+        assert np.array_equal(a, b2) and np.array_equal(a, c)
+    for a, b2, c in zip(st_off, st_on, st_off2):
+        assert a.host_syncs == b2.host_syncs == c.host_syncs
+        assert a.dispatches == b2.dispatches == c.dispatches
+
+
+def test_lockstep_cycle_counters_match_the_harness_arithmetic():
+    """cycles_needed / cycles_paid is the benchmark's `lockstep.eff`
+    arithmetic on the solve's own SolveStats: per dispatch, Σ live chains'
+    cycles over live chains × the largest, summed over dispatches."""
+    ops, b = _batched_ops(chains=4, seed=5)
+    cfg = KrylovConfig(m=6, k=0, tol=1e-8, maxiter=2000)
+    solver = BatchedGCRODRSolver(cfg)
+    obs.enable(krylov_capacity=0)
+    dispatches = []
+    for pad in ([False] * 4, [False, True, False, False]):
+        _, stats = solver.solve_batch(ops, b, padded_rows=np.array(pad))
+        dispatches.append([s.cycles for s in stats if not s.padded])
+    c = obs.summary()["counters"]
+    need = sum(sum(d) for d in dispatches)
+    paid = sum(len(d) * max(d) for d in dispatches)
+    assert len({n for d in dispatches for n in d}) > 1, \
+        "chains of equal cycle counts do not test the ratio"
+    assert c["lockstep.cycles_needed"] == need
+    assert c["lockstep.cycles_paid"] == paid
+    assert obs.registry().lockstep_eff() == pytest.approx(need / paid)
+
+
+@pytest.mark.parametrize("contain", [False, True],
+                         ids=["plain", "containment"])
+def test_hostlink_bytes_match_the_shapes(contain):
+    """The byte counters equal the reckoning from the arrays' shapes: a
+    cold and a warm solve of numpy right-hand sides."""
+    from repro.core.robust import RetryPolicy
+
+    ops, b = _batched_ops()
+    bsz, n = b.shape
+    k = 6
+    solver = BatchedGCRODRSolver(
+        KrylovConfig(m=18, k=k, tol=1e-8, maxiter=2000),
+        policy=RetryPolicy() if contain else None)
+    obs.enable(krylov_capacity=0)
+    _, stats = _two_solves(solver, ops, b)
+    f8, i4 = 8, 4
+    flags = 5 if contain else 4                  # booleans per flag fetch
+    h2d = d2h = 0
+    for warm, st in ((False, stats[0]), (True, stats[bsz])):
+        h2d += bsz * n * f8                      # the right-hand sides
+        h2d += bsz * n * k * f8 if warm else 0   # the recycle carry
+        h2d += 2 * bsz + f8 + i4 + f8            # carry_ok, pad; tol, lim, div
+        d2h += flags * (st.host_syncs - 1)       # entry + per-cycle flags
+        d2h += (bsz * n * f8                     # x
+                + bsz * n * k * f8               # U
+                + 2 * bsz * f8                   # rnorm, bnorm
+                + 3 * bsz * i4                   # iters, matvecs, cycles
+                + 4 * bsz                        # stalled, est, zerob, pad
+                + (bsz if contain else 0))       # quar
+    c = obs.summary()["counters"]
+    assert c["hostlink.h2d_bytes"] == h2d
+    assert c["hostlink.d2h_bytes"] == d2h
+
+
+def test_spans_mirror_into_the_profiler(tmp_path):
+    """Each span opens a `skr:<name>` profiler annotation (`.<what>` for a
+    host sync) on the thread that runs it, while the tracer's records
+    keep their names and arguments; the session stays readable through
+    `obs.last()` after `disable()` until the next `enable()`."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    ops, b = _batched_ops()
+    solver = BatchedGCRODRSolver(KrylovConfig(m=18, k=6, tol=1e-8,
+                                              maxiter=2000))
+    _two_solves(solver, ops, b)                 # compile outside the trace
+    obs.enable(krylov_capacity=0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _two_solves(solver, ops, b)
+    finally:
+        jax.profiler.stop_trace()
+    obs.disable()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:") for line in plane.lines
+             for ev in line.events if ev.name.startswith("skr:")}
+    assert names == {"skr:solve_batch", "skr:carry_upload",
+                     "skr:host_sync.entry_flags", "skr:cycle_dispatch",
+                     "skr:host_sync.cycle_flags", "skr:host_sync.finalize",
+                     "skr:carry_store"}
+    tracer, registry = obs.last()
+    spans = [e for e in tracer.snapshot() if e["ph"] == "X"]
+    assert {e["args"]["what"] for e in spans if e["name"] == "host_sync"} \
+        == {"entry_flags", "cycle_flags", "finalize"}
+    assert sum(e["name"] == "solve_batch" for e in spans) == 2
+    assert registry.snapshot()["counters"]["lockstep.dispatches"] == 2
+    obs.enable()
+    assert obs.last() == (None, None)
