@@ -4,16 +4,19 @@ SKR datagen (paper App. E.2.2).
 The sequential `GCRODRSolver` advances ONE recycling chain and pays the full
 host↔device round-trip + dispatch latency per tiny cycle. This engine
 advances B independent chains (one per sorted chunk) SIMULTANEOUSLY, and —
-unlike the sequential solver — keeps the WHOLE cycle on device: the Arnoldi
-sweep, the Hessenberg least-squares, the harmonic-Ritz extraction and the
-recycle-space refresh are one fused jitted program per cycle (the stacked
-drivers in `solvers/devlinalg.py`, with `hostlinalg.py` kept as the
-reference oracle). The host's only job per cycle is fetching four boolean
-flags — `device_get` of (any chain still active, every active chain owns a
+unlike the sequential solver — keeps every length-n array on device: the
+Arnoldi sweep, the Hessenberg least-squares and the harmonic-Ritz pencil
+are one fused jitted program per cycle (the stacked drivers in
+`solvers/devlinalg.py`). Per cycle the host fetches four boolean flags —
+`device_get` of (any chain still active, every active chain owns a
 recycle space, any chain advanced, restart growth requested) — to pick the
-next cycle's static shape. That is ONE host sync per cycle; a full
-`solve_batch` costs 2 + #cycles syncs (entry flags + per-cycle flags +
-finalize fetch), tracked in `SolveStats.host_syncs`.
+next cycle's static shape, and in the same fetch the small (k+m)² pencils:
+it takes their invariant subspaces with LAPACK (`hostlinalg.ritz_*_padded`;
+a TPU emulates fp64 with loops of f32 pairs, so a device eigensolve there
+is serial latency) and a refresh program rebuilds (C, U) from them on the
+device. That is ONE host sync per cycle, and no array of length n crosses
+the host link; a full `solve_batch` costs 2 + #cycles syncs (entry flags +
+per-cycle flags + finalize fetch), tracked in `SolveStats.host_syncs`.
 
 Each chain keeps its OWN recycle carry U_k — the chains never exchange
 Krylov information, exactly the App. E.2.2 task decomposition.
@@ -35,8 +38,9 @@ Lockstep semantics (who iterates when):
   healthy warm starts — the steady state of a sorted sequence — every chain
   goes straight to deflated cycles and the per-chain math is identical to
   `GCRODRSolver.solve`, modulo vmapped-matmul float reassociation and the
-  eigensolver family (batched subspace iteration instead of LAPACK — same
-  invariant subspace on gapped pencils, tested in test_devlinalg.py).
+  eigenvector basis (one stacked LAPACK eig of the padded pencils instead
+  of a per-chain one — same invariant subspace, tested in
+  test_devlinalg.py).
 * Rare rank trouble in the batched warm-start QR drops the carry for the
   affected chains only (the masked `devlinalg.tri_inv_stacked` gate); a
   failed harmonic-Ritz refresh keeps the chain's previous space, as in the
@@ -83,6 +87,7 @@ from repro import obs
 from repro.kernels import ops as kops
 from repro.obs.telemetry import KrylovTelemetry, drain_chain
 from repro.solvers import devlinalg as dl
+from repro.solvers import hostlinalg as hl
 from repro.solvers import gcrodr as _seq
 from repro.solvers.arnoldi import _arnoldi_cycle_impl
 from repro.solvers.gmres import _ir_accum
@@ -329,7 +334,12 @@ def _fresh_cycle(ops, s, aux, *, m: int, k: int, orthog: str,
                  tele_cap: int = 0, tele_delta: bool = False):
     """One lockstep fresh GMRES(m) cycle (Alg. 2 l.9-18) as ONE device
     program: Arnoldi sweep → stacked Hessenberg LS → solution update →
-    (k > 0) harmonic-Ritz space establishment, all under the same jit."""
+    (k > 0) the harmonic-Ritz pencil of the space establishment.
+
+    Returns (s, flags, pend): for k > 0 `pend` holds what the host
+    eigensolve and `_fresh_refresh` need, and the flags hold the
+    deflated-ready flag from before the refresh, which the host recomputes
+    from `pend["ready"]` (BatchedGCRODRSolver._host_ritz); None for k = 0."""
     bsz, n = s["r"].shape
     dt = s["r"].dtype
     with jax.named_scope("skr/update"):
@@ -361,18 +371,13 @@ def _fresh_cycle(ops, s, aux, *, m: int, k: int, orthog: str,
                                      s["no_prog"] + 1, 0)
         any_grew = jnp.zeros((), bool)
     if k > 0:
-        # establish / re-establish recycle spaces per chain, on device
+        # establish / re-establish recycle spaces per chain: the pencil
         with jax.named_scope("skr/ritz"):
-            p, ritz_ok = dl.harmonic_ritz_first_cycle_stacked(cyc.h, j, k)
-            q, inv_rr, qr_ok = dl.refresh_factors(cyc.h @ p,
-                                                  ritz_ok & step)
-            est_new = qr_ok if not contain else qr_ok & ~s["quar"]
-            c_new, yk = _fresh_cu_b(cyc.v, cyc.h, p, q)
-            u_new = _mat_post_b(yk, inv_rr)
-            s["c"] = _mask(est_new, c_new, s["c"])
-            s["u"] = _mask(est_new, u_new, s["u"])
-            s["est"] = s["est"] | est_new
+            pend = dict(a=dl.first_cycle_pencil_stacked(cyc.h, j), h=cyc.h,
+                        v=cyc.v, j=j,
+                        can=step if not contain else step & ~s["quar"])
     else:
+        pend = None
         # adaptive restart growth (see gmres_solve): grow when any chain
         # ran a full cycle and stalled; the host doubles m on the flag
         with jax.named_scope("skr/update"):
@@ -387,12 +392,33 @@ def _fresh_cycle(ops, s, aux, *, m: int, k: int, orthog: str,
     with jax.named_scope("skr/update"):
         if stall_break:
             s["stalled"] = s["stalled"] | (s["no_prog"] >= 3)
-        if tele_cap > 0:
-            # a fresh cycle (re)establishes the space: no before/after
-            # pair to compare, so δ is recorded NaN
+        if k > 0:
+            pend["ready"] = s["est"] | ~_active_mask(s, aux)
+        elif tele_cap > 0:
             s = _tele_record(s, k, tele_cap=tele_cap,
                              tele_delta=tele_delta)
-        return s, _flags(s, aux, active, step, any_grew)
+        return s, _flags(s, aux, active, step, any_grew), pend
+
+
+@partial(jax.jit, static_argnames=("k", "tele_cap", "tele_delta"))
+def _fresh_refresh(s, v, h, p, q, inv_rr, est_new, *, k: int,
+                   tele_cap: int = 0, tele_delta: bool = False):
+    """The fresh cycle's last step: the first recycle spaces C = V_{m+1} Q,
+    U = V_m P R⁻¹ from the host's P, Q, R⁻¹ for the chains `est_new`; the
+    others keep theirs."""
+    s = dict(s)
+    with jax.named_scope("skr/ritz"):
+        c_new, yk = _fresh_cu_b(v, h, p, q)
+        u_new = _mat_post_b(yk, inv_rr)
+        s["c"] = _mask(est_new, c_new, s["c"])
+        s["u"] = _mask(est_new, u_new, s["u"])
+        s["est"] = s["est"] | est_new
+    if tele_cap > 0:
+        # a fresh cycle (re)establishes the space: no before/after pair to
+        # compare, so δ is recorded NaN
+        with jax.named_scope("skr/update"):
+            s = _tele_record(s, k, tele_cap=tele_cap, tele_delta=tele_delta)
+    return s
 
 
 @partial(jax.jit, static_argnames=("mi", "k", "orthog", "use_kernel",
@@ -404,7 +430,11 @@ def _deflated_cycle(ops, s, aux, *, mi: int, k: int, orthog: str,
                     tele_delta: bool = False):
     """One lockstep deflated cycle (Alg. 2 l.19-33) as ONE device program:
     deflated Arnoldi sweep → stacked Ĝ least-squares → solution update →
-    stacked generalized harmonic-Ritz refresh of (C, U)."""
+    the stacked generalized harmonic-Ritz pencil M.
+
+    Returns (s, flags, pend): `pend` holds what the host eigensolve and
+    `_deflated_refresh` need. The flags do not depend on the refresh.
+    `tele_cap`, `tele_delta`: recorded by `_deflated_refresh`."""
     with jax.named_scope("skr/update"):
         active = _active_mask(s, aux)
         eff_tol = jnp.where(active, aux["tol_abs"], jnp.inf)
@@ -444,23 +474,37 @@ def _deflated_cycle(ops, s, aux, *, mi: int, k: int, orthog: str,
                                      s["no_prog"] + 1, 0)
             s["stalled"] = s["stalled"] | (s["no_prog"] >= 3)
 
-    # next recycle spaces from the stacked generalized harmonic-Ritz pencil
+    # the stacked generalized harmonic-Ritz pencil of the next spaces
     with jax.named_scope("skr/ritz"):
         cu, cv, vu, vv = _whv_blocks_b(s["c"], ut, cyc.v)
         whv = dl.assemble_whv_stacked(cu, cv, vu, vv, j)
-        p, ritz_ok = dl.harmonic_ritz_deflated_stacked(g, whv, j, k)
+        pend = dict(mm=dl.deflated_pencil_stacked(g, whv), j=j, g=g, ut=ut,
+                    v=cyc.v, step=step)
+    with jax.named_scope("skr/update"):
+        s["stalled"] = s["stalled"] | (cyc.breakdown & step
+                                      & (s["rnorm"] > aux["tol_abs"]))
+        return s, _flags(s, aux, active, step, jnp.zeros((), bool)), pend
+
+
+@partial(jax.jit, static_argnames=("k", "contain", "tele_cap",
+                                   "tele_delta"))
+def _deflated_refresh(s, g, ut, v, step, p, ritz_ok, *, k: int,
+                      contain: bool = False, tele_cap: int = 0,
+                      tele_delta: bool = False):
+    """The deflated cycle's last step: the next recycle spaces C' = Ŵ Q,
+    U' = V̂ P R⁻¹ (Alg. 2 l.31-33) from the host's harmonic-Ritz basis P,
+    for chains that stepped with ritz_ok; the others keep theirs."""
+    s = dict(s)
+    with jax.named_scope("skr/ritz"):
         if contain:   # a quarantined chain must not refresh from garbage
             ritz_ok = ritz_ok & ~s["quar"]
         q, inv_rr, ref_ok = dl.refresh_factors(g @ p, ritz_ok & step)
-        c_new, yk = _next_cu_b(ut, cyc.v, s["c"], p[:, :k], p[:, k:],
+        c_new, yk = _next_cu_b(ut, v, s["c"], p[:, :k], p[:, k:],
                                q[:, :k], q[:, k:])
         u_new = _mat_post_b(yk, inv_rr)
         c_old = s["c"]
         s["c"] = _mask(ref_ok, c_new, s["c"])
         s["u"] = _mask(ref_ok, u_new, s["u"])
-    with jax.named_scope("skr/update"):
-        s["stalled"] = s["stalled"] | (cyc.breakdown & step
-                                      & (s["rnorm"] > aux["tol_abs"]))
     if tele_cap > 0:
         with jax.named_scope("skr/ritz"):
             delta = (_delta_qc_b(c_old, s["c"], ref_ok) if tele_delta
@@ -468,8 +512,7 @@ def _deflated_cycle(ops, s, aux, *, mi: int, k: int, orthog: str,
         with jax.named_scope("skr/update"):
             s = _tele_record(s, k, tele_cap=tele_cap,
                              tele_delta=tele_delta, delta=delta)
-    with jax.named_scope("skr/update"):
-        return s, _flags(s, aux, active, step, jnp.zeros((), bool))
+    return s
 
 
 class BatchedGCRODRSolver:
@@ -656,26 +699,40 @@ class BatchedGCRODRSolver:
         m_fresh = cfg.m  # k=0: grows adaptively, mirroring gmres_solve
         m_cap = min(n, cfg.m_max if cfg.m_max else 8 * cfg.m)
 
-        # ---- the cycle loop: one fused dispatch + one 4-flag sync each ---
+        # ---- the cycle loop: a cycle program, one flag sync, (k > 0) the
+        # host eigensolve and a refresh program, each cycle ---------------
         while any_active:
+            fresh = k == 0 or not all_est
             with obs.span("cycle_dispatch", cat="solver"):
-                if k == 0 or not all_est:
-                    s, f = _fresh_cycle(
+                if fresh:
+                    s, f, pend = _fresh_cycle(
                         ops, s, aux, m=m_fresh, k=k, orthog=cfg.orthog,
                         use_kernel=self.use_kernel, h_acc=cfg.cgs2_acc,
                         stall_break=self.stall_break,
                         can_grow=m_fresh < m_cap, contain=contain,
                         tele_cap=tele_cap, tele_delta=tele_delta)
                 else:
-                    s, f = _deflated_cycle(
+                    s, f, pend = _deflated_cycle(
                         ops, s, aux, mi=cfg.m - k, k=k, orthog=cfg.orthog,
                         use_kernel=self.use_kernel, h_acc=cfg.cgs2_acc,
                         stall_break=self.stall_break, contain=contain,
                         tele_cap=tele_cap, tele_delta=tele_delta)
+            # the small pencils ride the flag fetch (None for k = 0)
+            small = None if pend is None else {
+                key: pend[key] for key in
+                (("a", "h", "j", "can", "ready") if fresh else ("mm", "j"))}
             with obs.span("host_sync", cat="solver", what="cycle_flags"):
-                fl = jax.device_get(f)
-            obs.hostlink("d2h", fl)
+                fl, small = jax.device_get((f, small))
+            obs.hostlink("d2h", fl, small)
             any_active, all_est, any_step, any_grew = map(bool, fl[:4])
+            if pend is not None:
+                with obs.span("cycle_dispatch", cat="solver"):
+                    s, ready = self._host_ritz(
+                        s, pend, small, fresh, contain=contain,
+                        tele_cap=tele_cap, tele_delta=tele_delta)
+                if fresh:
+                    all_est = ready
+                dispatches += 1
             if contain and bool(fl[4]):
                 # the health flag rides the SAME fetch: zero extra syncs
                 obs.counter_add("health.lockstep_quarantine_flag")
@@ -778,6 +835,38 @@ class BatchedGCRODRSolver:
                                     int(quar.sum()))
         self.systems_solved += int((~zerob & ~pad).sum())
         return x, stats
+
+    def _host_ritz(self, s, pend, small, fresh, *, contain, tele_cap,
+                   tele_delta):
+        """One cycle's harmonic-Ritz eigensolve on the host (LAPACK, from
+        the fetched pencils `small`), then the refresh program on the
+        device. Returns (s, all_est): a fresh cycle's deflated-ready flag
+        after the refresh, None after a deflated cycle."""
+        k = self.cfg.k
+        dt = small["a" if fresh else "mm"].dtype
+        t0 = time.perf_counter()
+        if fresh:
+            p, ok = hl.ritz_first_cycle_padded(small["a"], small["j"], k)
+            q, inv_rr, ok = hl.refresh_factors_stacked(small["h"] @ p, ok)
+            est_new = ok & small["can"]
+            ups = (p.astype(dt), q.astype(dt), inv_rr.astype(dt), est_new)
+        else:
+            p, ok = hl.ritz_deflated_padded(small["mm"], small["j"], k)
+            ups = (p.astype(dt), ok)
+        live = small["j"] > 0
+        obs.counter_add("ritz.host_s", time.perf_counter() - t0)
+        obs.counter_add("ritz.host_chains", int(live.sum()))
+        obs.counter_add("ritz.host_gated", int((live & ~ok).sum()))
+        obs.hostlink("h2d", ups)
+        ups = [self._dev(jnp.asarray(a)) for a in ups]
+        if fresh:
+            s = _fresh_refresh(s, pend["v"], pend["h"], *ups, k=k,
+                               tele_cap=tele_cap, tele_delta=tele_delta)
+            return s, bool((small["ready"] | est_new).all())
+        s = _deflated_refresh(s, pend["g"], pend["ut"], pend["v"],
+                              pend["step"], *ups, k=k, contain=contain,
+                              tele_cap=tele_cap, tele_delta=tele_delta)
+        return s, None
 
     # ------------------------------------------------------------------
     def _solve_batch_mixed(self, ops, b, padded_rows=None):

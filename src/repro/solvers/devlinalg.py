@@ -1,15 +1,16 @@
 """Batched ON-DEVICE small dense linear algebra for the lockstep engine.
 
-Device counterparts of the `hostlinalg.py` stacked drivers (which stay the
+Device counterparts of `hostlinalg.py`'s LAPACK drivers (which stay the
 reference oracle, regression-tested against this module): stacked Hessenberg
-least squares via batched QR with an SVD min-norm fallback, stacked
-harmonic-Ritz extraction via a batched fixed-sweep subspace-iteration
-eigensolver on the small (m ≲ 200) pencils, and stacked masked triangular
-inverses. Everything here is pure `jnp` on TPU-supported primitives
-(matmul, QR, SVD, triangular solve, `fori_loop`; the TPU has no fp64 LU,
-so square solves go through QR) so a whole GCRO-DR cycle — Arnoldi
-sweep, LS update, recycle-space refresh — traces into ONE device program
-with no host round-trip (solvers/batched.py).
+least squares via batched QR with an SVD min-norm fallback, the padded
+harmonic-Ritz pencils, the recycle-space refresh factors, and stacked
+masked triangular inverses. Everything here is pure `jnp` on TPU-supported
+primitives (matmul, QR, SVD, triangular solve; the TPU has no fp64 LU, so
+square solves go through QR) so a GCRO-DR cycle — Arnoldi sweep, LS
+update, the pencil of the next recycle space — traces into ONE device
+program (solvers/batched.py). The pencils' eigensolve runs on the host
+(`hostlinalg.ritz_*_padded`): a TPU emulates fp64 with loops of f32 pairs,
+and an iterative eigensolve of (k+m)² matrices there is serial latency.
 
 Ragged widths (the lockstep reality: every chain runs its own j ≤ m Arnoldi
 steps) are handled by PADDING, not loops:
@@ -19,38 +20,21 @@ steps) are handled by PADDING, not loops:
   and the padded solution entries come out EXACTLY zero (the engines'
   padded-update no-op convention).
 * Eigen pencils pad with a BIG diagonal (first-cycle) or decouple to a zero
-  block (deflated), so padded eigendirections are never dominant and the
-  extracted subspace lives entirely in the live block.
+  block (deflated), so padded eigendirections are never among the wanted
+  ones and the extracted subspace lives entirely in the live block.
 
 Rank trouble is gated, never raised: every driver returns an `ok` mask (or
-blends in a fallback solution) and the caller keeps the previous recycle
-space for gated chains — mirroring hostlinalg's try/except + pivot-gate
-behavior chain-by-chain.
-
-Why subspace iteration and not a batched nonsymmetric QR eig: the recycle
-space only needs a good basis of the smallest-|θ| harmonic-Ritz invariant
-subspace; an orthogonal (inverse) iteration with a fixed sweep count gets
-principal angles to LAPACK-level agreement on gapped pencils and a
-comparable-quality space on clustered ones (where LAPACK's own
-eigenvector basis is arbitrary anyway) — measured in
-tests/test_devlinalg.py, and end-to-end by the batched-vs-sequential
-equivalence suite. Sweeps are data-independent (static trace), which is
-what lets the whole cycle live inside one dispatch.
+blends in a fallback solution, or non-finite pencil entries the host gates
+on) and the caller keeps the previous recycle space for gated chains —
+mirroring hostlinalg's try/except + pivot-gate behavior chain-by-chain.
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
 # conditioning gate shared with hostlinalg._stack_well_conditioned
 _RTOL = 1e-12
-# subspace-iteration sweeps per refresh: each sweep applies the iteration
-# matrix twice then re-orthonormalizes (QR), so the invariant-subspace
-# error contracts ~gap² per sweep; 48 sweeps put gapped pencils at the
-# LAPACK agreement floor while staying cheap (m ≲ 200 matmuls)
-_RITZ_SWEEPS = 48
 
 
 def _tiny(dt) -> float:
@@ -58,9 +42,9 @@ def _tiny(dt) -> float:
 
 
 def _big(dt) -> float:
-    """Pencil-padding diagonal: large enough that padded eigendirections of
-    an inverse iteration are negligible after one sweep, small enough that
-    its reciprocal and products stay representable (fp32-safe)."""
+    """Pencil-padding diagonal: large enough that padded eigenvalues are
+    never among the smallest |θ|, small enough that its reciprocal and
+    products stay representable (fp32-safe)."""
     return 1e30 if dt == jnp.float64 else 1e12
 
 
@@ -180,48 +164,16 @@ def hessenberg_lstsq_stacked(h, j, beta):
 
 
 # ---------------------------------------------------------------------------
-# harmonic-Ritz extraction (the batched fixed-sweep eigensolver)
+# harmonic-Ritz pencils (their eigensolve runs on the host:
+# hostlinalg.ritz_first_cycle_padded, hostlinalg.ritz_deflated_padded)
 # ---------------------------------------------------------------------------
 
 
-def _det_init(bsz: int, n: int, k: int, dt):
-    """Deterministic full-rank start basis (incoherent w.r.t. any structured
-    pencil; no PRNG so re-traces are bitwise-stable)."""
-    i = jnp.arange(1, n + 1, dtype=dt)[:, None]
-    l = jnp.arange(1, k + 1, dtype=dt)[None, :]
-    q0 = jnp.linalg.qr(jnp.sin(i * l * 0.7 + 0.3 * l))[0]
-    return jnp.broadcast_to(q0[None], (bsz, n, k))
-
-
-def _dominant_subspace(mm, k: int, sweeps: int):
-    """Orthogonal (subspace) iteration: the dominant k-dimensional invariant
-    subspace of each stacked matrix mm (B, n, n). Two applications per
-    sweep, then QR re-orthonormalization. Returns Q (B, n, k)."""
-    bsz, n, _ = mm.shape
-    q0 = _det_init(bsz, n, k, mm.dtype)
-
-    def sweep(_, q):
-        return jnp.linalg.qr(mm @ (mm @ q))[0]
-
-    return jax.lax.fori_loop(0, sweeps, sweep, q0)
-
-
-def harmonic_ritz_first_cycle_stacked(h, j, k: int,
-                                      sweeps: int = _RITZ_SWEEPS):
-    """Fresh-cycle harmonic-Ritz bases for B chains, on device.
-
-    Pencil (Alg. 2 line 14): A = H_m + h²_{m+1,m} H_m⁻ᴴ e_m e_mᴴ at the
-    per-chain effective width j; the wanted space is the smallest-|θ|
-    invariant subspace of A — extracted as the DOMINANT subspace of A⁻¹ by
-    subspace iteration. Dead rows/columns are padded with a BIG diagonal so
-    their inverse eigendirections are negligible and the iterate collapses
-    into the live block.
-
-    Returns (p, ok): p (B, m, k) zero outside live rows; ok (B,) — chains
-    with j > k, a nonsingular pencil and finite iterates. Oracle:
-    hostlinalg.harmonic_ritz_first_cycle_stacked (same invariant subspace,
-    not the same basis).
-    """
+def first_cycle_pencil_stacked(h, j):
+    """The padded fresh-cycle pencils A (B, m, m) = H_m + h²_{m+1,m} H_m⁻ᴴ
+    e_m e_mᴴ at the per-chain widths j (Alg. 2 line 14), dead rows and
+    columns a BIG diagonal, so that their eigenvalues are never among the
+    smallest |θ|. A chain with a singular H_m gets non-finite entries."""
     bsz, _, m = h.shape
     dt = h.dtype
     big = _big(dt)
@@ -233,13 +185,7 @@ def harmonic_ritz_first_cycle_stacked(h, j, k: int,
     em = jax.nn.one_hot(jm1, m, dtype=dt)
     h2 = h[jnp.arange(bsz), jnp.clip(j, 0, m), jm1]   # h[j, j-1] per chain
     corr = _qr_solve(hm.swapaxes(1, 2), em[..., None])[..., 0]
-    a = hm + (h2 ** 2)[:, None, None] * corr[:, :, None] * em[:, None, :]
-    ainv = _qr_solve(a, jnp.broadcast_to(jnp.eye(m, dtype=dt), a.shape))
-    p = _dominant_subspace(ainv, k, sweeps)
-    p = p * _row_mask(j - 1, m)
-    ok = ((j > k) & jnp.isfinite(p).all(axis=(1, 2))
-          & (jnp.linalg.norm(p, axis=1).min(axis=-1) > 0.5))
-    return p, ok
+    return hm + (h2 ** 2)[:, None, None] * corr[:, :, None] * em[:, None, :]
 
 
 def assemble_g_stacked(dnorm, bb, h, j):
@@ -281,33 +227,13 @@ def assemble_whv_stacked(cu, cv, vu, vv, j):
     return whv
 
 
-def harmonic_ritz_deflated_stacked(g, whv, j, k: int,
-                                   sweeps: int = _RITZ_SWEEPS):
-    """Deflated-cycle harmonic-Ritz bases for B chains, on device.
-
-    Generalized pencil (Alg. 2 line 29): ĜᴴĜ z = θ ĜᴴŴᴴV̂ z; the wanted
-    smallest-|θ| space is the DOMINANT subspace of M = (ĜᴴĜ)⁻¹ ĜᴴŴᴴV̂.
-    With the padding conventions of `assemble_*_stacked`, M is block
-    diagonal with a ZERO dead block (unit Ĝ columns ⊥ live ones, zero Ŵᴴ V̂
-    there), so the dominant subspace lives entirely in the live block.
-    Replaces the "one per-chain eig loop left" in hostlinalg.
-
-    Returns (p, ok): p (B, k+mi, k); ok gates singular/ill-conditioned
-    pencils (caller keeps the previous recycle space, as hostlinalg's
-    try/except does).
-    """
+def deflated_pencil_stacked(g, whv):
+    """M = (ĜᴴĜ)⁻¹ ĜᴴŴᴴV̂ (B, k+mi, k+mi) from the padded blocks of
+    `assemble_*_stacked`: block diagonal with a ZERO dead block. A chain
+    with a singular ĜᴴĜ gets non-finite entries."""
     a1 = g.swapaxes(1, 2) @ g                    # SPD (+ identity dead block)
     a2 = g.swapaxes(1, 2) @ whv
-    mm = _qr_solve(a1, a2)
-    solve_ok = jnp.isfinite(mm).all(axis=(1, 2))  # singular ĜᵀĜ → NaN → gate
-    mm = jnp.where(solve_ok[:, None, None], mm, 0.0)
-    p = _dominant_subspace(mm, k, sweeps)
-    live = _row_mask(j + k - 1, g.shape[-1])     # rows r < k + j
-    p = p * live
-    ok = (solve_ok
-          & jnp.isfinite(p).all(axis=(1, 2))
-          & (jnp.linalg.norm(p, axis=1).min(axis=-1) > 0.5))
-    return p, ok
+    return _qr_solve(a1, a2)
 
 
 def refresh_factors(gp, want):

@@ -85,22 +85,26 @@ def right_tri_solve(u: np.ndarray, r: np.ndarray) -> np.ndarray:
 
 
 # --------------------------------------------------------------------------
-# Stacked (multi-chain) variants — the host half of the batched lockstep
-# engine (solvers/batched.py). Each takes B chains' small blocks at per-chain
-# EFFECTIVE widths j[i] and uses one LAPACK call on the whole stack whenever
-# the widths agree (the lockstep common case: every unconverged chain ran a
-# full cycle); ragged widths fall back to a per-chain loop. B is the worker
-# count (≲ dozens), the blocks are m ≲ 200 — host microseconds either way,
-# but the stacked path keeps BLAS calls O(1) per lockstep cycle.
+# Stacked Hessenberg least squares — the oracle of the device's
+# (devlinalg.hessenberg_lstsq_stacked, tests/test_devlinalg.py). It takes B
+# chains' blocks at per-chain EFFECTIVE widths j[i] and uses one LAPACK call
+# on the whole stack whenever the widths agree; ragged widths fall back to a
+# per-chain loop.
 # --------------------------------------------------------------------------
+
+
+def _well_conditioned(r: np.ndarray, rtol: float = 1e-12) -> np.ndarray:
+    """(B,) per-factor gate: each R factor of a stacked QR safely
+    invertible (devlinalg._diag_ok)."""
+    diag = np.abs(np.diagonal(r, axis1=-2, axis2=-1))
+    return ((diag.min(axis=-1) > rtol * np.maximum(diag.max(axis=-1), 1e-300))
+            & np.isfinite(diag).all(axis=-1))
 
 
 def _stack_well_conditioned(r: np.ndarray, rtol: float = 1e-12) -> bool:
     """True when every R factor in a stacked QR is safely invertible —
     gate for the fast solve path (lstsq fallback handles the rest)."""
-    diag = np.abs(np.diagonal(r, axis1=-2, axis2=-1))
-    return bool(np.all(diag.min(axis=-1) >
-                       rtol * np.maximum(diag.max(axis=-1), 1e-300)))
+    return bool(_well_conditioned(r, rtol).all())
 
 
 def hessenberg_lstsq_stacked(h: np.ndarray, j: np.ndarray,
@@ -135,74 +139,98 @@ def hessenberg_lstsq_stacked(h: np.ndarray, j: np.ndarray,
     return y
 
 
-def lstsq_stacked(a_list: list, b_list: list) -> list:
-    """Per-chain min‖b_i − A_i y‖ (entries may be None = frozen chain).
+# --------------------------------------------------------------------------
+# Padded-stack harmonic-Ritz drivers — the host half of the lockstep
+# engine's cycles (solvers/batched.py). They take the padded (B, s, s)
+# stacks exactly as the device programs build them
+# (devlinalg.first_cycle_pencil_stacked, devlinalg.deflated_pencil_stacked)
+# and return a (B, s, k) fp64 basis zero outside each chain's live rows, and
+# an ok gate: the invariant subspace of each chain's live block, by one
+# stacked LAPACK eig in fp64 whatever the cycle's dtype.
+# --------------------------------------------------------------------------
 
-    One stacked QR + triangular solve when every live block has the same
-    shape; ragged or rank-deficient stacks fall back to per-chain lstsq.
-    """
-    out = [None] * len(a_list)
-    live = [i for i, a in enumerate(a_list) if a is not None]
-    if not live:
-        return out
-    shape0 = a_list[live[0]].shape
-    if all(a_list[i].shape == shape0 for i in live):
-        stack = np.stack([a_list[i] for i in live])
-        rhs = np.stack([b_list[i] for i in live])
-        q, r = np.linalg.qr(stack)
-        if _stack_well_conditioned(r):
-            ys = np.linalg.solve(
-                r, np.einsum("bij,bi->bj", q, rhs)[..., None])[..., 0]
-            for t, i in enumerate(live):
-                out[i] = ys[t]
-            return out
-    for i in live:
-        out[i], *_ = np.linalg.lstsq(a_list[i], b_list[i], rcond=None)
-    return out
+_RTOL = 1e-12          # the rank and conditioning gate of devlinalg
 
 
-def harmonic_ritz_first_cycle_stacked(h: np.ndarray, j: np.ndarray,
-                                      k: int) -> list:
-    """Fresh-cycle harmonic-Ritz bases for B chains: list of P_i
-    ((j_i, k_eff_i) arrays; None where j_i < 2 or the pencil is singular).
-
-    Uniform-width stacks share ONE np.linalg.eig call over the stacked
-    pencils; the per-chain basis selection (real spans of complex pairs +
-    rank-revealing QR) stays a loop — it is O(k²·j) bookkeeping.
-    """
-    h = np.asarray(h)
-    j = np.asarray(j, dtype=int)
-    bsz = h.shape[0]
-    out = [None] * bsz
-    act = [i for i in range(bsz) if min(k, int(j[i]) - 1) >= 1]
-    if not act:
-        return out
-    ji = int(j[act[0]])
-    if all(int(j[i]) == ji for i in act):
-        pencils, ok_idx = [], []
-        for i in act:
-            a = _first_cycle_pencil(h[i], ji)
-            if a is not None:
-                pencils.append(a)
-                ok_idx.append(i)
-        if ok_idx:
-            evals, evecs = np.linalg.eig(np.stack(pencils))  # stacked eig
-            for t, i in enumerate(ok_idx):
-                out[i] = real_spanning_basis(evals[t], evecs[t],
-                                             min(k, ji - 1))
-        return out
-    for i in act:
-        out[i] = harmonic_ritz_first_cycle(h[i], int(j[i]),
-                                           min(k, int(j[i]) - 1))
-    return out
+def _eig_stacked(a: np.ndarray):
+    """Stacked eig of (R, s, s). Returns (evals, evecs, ok): a row LAPACK
+    cannot diagonalize gets ok False and zeros."""
+    try:
+        w, v = np.linalg.eig(a)
+        return w, v, np.ones(len(a), bool)
+    except np.linalg.LinAlgError:   # some row did not converge: one by one
+        w = np.zeros(a.shape[:2], complex)
+        v = np.zeros(a.shape, complex)
+        ok = np.zeros(len(a), bool)
+        for i, ai in enumerate(a):
+            try:
+                w[i], v[i] = np.linalg.eig(ai)
+                ok[i] = True
+            except np.linalg.LinAlgError:
+                pass
+        return w, v, ok
 
 
-def harmonic_ritz_deflated_stacked(g_list: list, whv_list: list,
-                                   k: int) -> list:
-    """Deflated-cycle harmonic Ritz per chain (None entries pass through).
+def _spanning_basis(evecs, order, live, k: int):
+    """Orthonormal real basis (R, s, k) of the span of the eigenvectors
+    `order` (R, k) picks, rows outside `live` (R, s) zeroed: their real and
+    imaginary parts (a complex pair spans two real directions), then the k
+    leading left singular vectors. ok where that span has rank k."""
+    sel = np.take_along_axis(evecs, order[:, None, :], axis=2)
+    cand = np.concatenate([sel.real, sel.imag], axis=2) * live[:, :, None]
+    u, sv, _ = np.linalg.svd(cand, full_matrices=False)
+    ok = sv[:, k - 1] > _RTOL * np.maximum(sv[:, 0], 1e-300)
+    return u[:, :, :k], ok
 
-    The generalized pencil Ĝᴴ Ĝ z = θ Ĝᴴ Ŵᴴ V̂ z has no stacked LAPACK
-    driver — this is the one per-chain eig loop left in the lockstep engine.
-    """
-    return [None if g is None else harmonic_ritz_deflated(g, whv, k)
-            for g, whv in zip(g_list, whv_list)]
+
+def _ritz_padded(pencils, rows, live, k: int, largest: bool):
+    bsz, s, _ = pencils.shape
+    p = np.zeros((bsz, s, k))
+    ok = np.zeros(bsz, bool)
+    if rows.size == 0:
+        return p, ok
+    w, v, eig_ok = _eig_stacked(pencils[rows])
+    mag = np.abs(w)
+    order = np.argsort(-mag if largest else mag, axis=1, kind="stable")
+    pr, span_ok = _spanning_basis(v, order[:, :k], live[rows], k)
+    ok[rows] = eig_ok & span_ok
+    p[rows] = np.where(ok[rows][:, None, None], pr, 0.0)
+    return p, ok
+
+
+def ritz_first_cycle_padded(a: np.ndarray, j: np.ndarray, k: int):
+    """Fresh-cycle harmonic-Ritz bases from the padded pencils A (B, m, m)
+    (BIG dead diagonal): the k smallest-|θ| eigenvectors' real span in each
+    chain's live rows r < j. Returns (p (B, m, k), ok): ok needs j > k, a
+    finite pencil (H_m nonsingular), a converged eig and rank k."""
+    a = np.asarray(a, np.float64)
+    j = np.asarray(j)
+    rows = np.nonzero((j > k) & np.isfinite(a).all(axis=(1, 2)))[0]
+    live = np.arange(a.shape[-1])[None, :] < j[:, None]
+    return _ritz_padded(a, rows, live, k, largest=False)
+
+
+def ritz_deflated_padded(mm: np.ndarray, j: np.ndarray, k: int):
+    """Deflated-cycle harmonic-Ritz bases from the padded M = (ĜᴴĜ)⁻¹
+    ĜᴴŴᴴV̂ (B, k+mi, k+mi), dead block zero: the dominant (largest |μ| =
+    smallest |θ|) k-dimensional invariant subspace in each chain's live rows
+    r < k + j. Chains with j = 0 took no step and are not solved (the
+    refresh masks them). Returns (p (B, k+mi, k), ok): ok needs a finite M
+    (ĜᴴĜ nonsingular), a converged eig and rank k."""
+    mm = np.asarray(mm, np.float64)
+    j = np.asarray(j)
+    k_mi = mm.shape[-1]
+    rows = np.nonzero((j > 0) & np.isfinite(mm).all(axis=(1, 2)))[0]
+    live = np.arange(k_mi)[None, :] < (k + j)[:, None]
+    return _ritz_padded(mm, rows, live, k, largest=True)
+
+
+def refresh_factors_stacked(gp: np.ndarray, want: np.ndarray):
+    """Host twin of devlinalg.refresh_factors: stacked QR of the (B, R, k)
+    products + gated R inverse. Returns (q, inv_rr, ok): ok = want and R
+    well conditioned; gated chains get q = 0, inv_rr = I."""
+    q, rr = np.linalg.qr(gp)
+    ok = np.asarray(want) & _well_conditioned(rr, _RTOL)
+    eye = np.eye(rr.shape[-1])
+    inv_rr = np.linalg.inv(np.where(ok[:, None, None], rr, eye))
+    return np.where(ok[:, None, None], q, 0.0), inv_rr, ok

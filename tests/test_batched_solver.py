@@ -233,3 +233,38 @@ def test_stencil5_take_batched_indexing():
                                   np.asarray(coeffs[3]))
     one = st.take(2)
     assert one.coeffs.shape == (5, 8, 8)
+
+
+# ------------------------------------------ the eigensolve on the host
+
+# Σ iterations of each row below when the eigensolve ran on the device
+# (48 sweeps of batched subspace iteration; CPU, fp64)
+_DEVICE_EIG_ROW_ITERS = (239, 238, 226)
+
+
+def test_rows_converge_with_one_sync_per_cycle():
+    """Fresh then warm-started fp64 rows, the eigensolve on the host, under
+    the transfer guard: every label meets tol by its true residual, one
+    blocking sync per cycle (host_syncs = 2 + cycles), and each row's
+    iterations within 5 % of what the device eigensolve took."""
+    chains, rows = 4, 3
+    coeffs, b_all, subs = _chains(family="darcy", nx=16, num=chains * rows,
+                                  chains=chains, seed=9)
+    solver = BatchedGCRODRSolver(KC)
+    for t in range(rows):
+        idx = np.array([sub[t] for sub in subs])
+        st5 = Stencil5(coeffs).take(jnp.asarray(idx))
+        pre = make_preconditioner_batched("jacobi", st5)
+        ops = PreconditionedOp(StencilOp(st5.coeffs), pre)
+        b = b_all[idx]
+        with jax.transfer_guard("disallow"):
+            xs, stats = solver.solve_batch(ops, b)
+        for w in range(chains):
+            a = np.asarray(st5.take(w).to_dense())
+            res = np.linalg.norm(b[w] - a @ xs[w]) / np.linalg.norm(b[w])
+            assert stats[w].converged and res <= KC.tol * (1 + 1e-6), (w, res)
+        cycles = max(s.cycles for s in stats)
+        assert all(s.host_syncs == 2 + cycles for s in stats)
+        it_h = sum(s.iterations for s in stats)
+        it_d = _DEVICE_EIG_ROW_ITERS[t]
+        assert abs(it_h - it_d) <= 0.05 * it_d, (t, it_h, it_d)

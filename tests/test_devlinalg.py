@@ -1,8 +1,9 @@
 """devlinalg vs hostlinalg parity: the on-device stacked drivers against
 their host oracles — stacked QR least squares (uniform + ragged widths,
 ill-conditioned and rank-deficient fallback), masked triangular inverses,
-and the subspace-iteration harmonic-Ritz extraction (first-cycle and
-deflated pencils, gapped spectra where LAPACK's subspace is well defined)."""
+and the harmonic-Ritz extraction: the device-built padded pencils through
+the host's stacked LAPACK drivers (first-cycle and deflated pencils, gapped
+spectra where LAPACK's subspace is well defined)."""
 import numpy as np
 import pytest
 import scipy.linalg
@@ -135,51 +136,54 @@ def _smallest_eig_span(a, k):
     return np.real(evecs[:, order]), np.sort(np.abs(evals))
 
 
-@pytest.mark.parametrize("widths", [(10, 10), (10, 7)])
+def _first_cycle_host(h, j, k):
+    """The fresh cycle's split: the device builds the padded pencils, the
+    host driver takes their eigenvectors."""
+    a = dl.first_cycle_pencil_stacked(jnp.asarray(h), jnp.asarray(j))
+    return hl.ritz_first_cycle_padded(np.asarray(a), j, k)
+
+
+@pytest.mark.parametrize("widths", [(10, 10), (10, 7), (10, 7, 4)])
 def test_harmonic_ritz_first_cycle_matches_lapack_on_gapped(widths):
-    """Device subspace iteration vs the LAPACK eig that hostlinalg wraps:
-    same invariant subspace AND same smallest-|θ| Ritz values. (The host
-    basis itself is pivot-order arbitrary among equal-norm candidates, so
-    parity is defined against the eigendecomposition, and the host driver
-    must also produce a subspace of the same 2k-smallest candidate span.)"""
+    """The host driver on the device-built padded pencils vs LAPACK on each
+    chain's unpadded pencil, at mixed widths: same invariant subspace AND
+    same smallest-|θ| Ritz values, an orthonormal basis exactly zero
+    outside the live rows."""
     rng = np.random.default_rng(17)
     k, m = 3, 10
     j = np.asarray(widths)
     h = np.zeros((len(j), m + 1, m))
     for i, ji in enumerate(j):
         h[i, : ji + 1, :ji] = _gapped_hessenberg(ji, k, rng)
-    p_dev, ok = dl.harmonic_ritz_first_cycle_stacked(
-        jnp.asarray(h), jnp.asarray(j), k)
-    p_dev, ok = np.asarray(p_dev), np.asarray(ok)
+    p, ok = _first_cycle_host(h, j, k)
     assert ok.all()
-    p_host = hl.harmonic_ritz_first_cycle_stacked(h, j, k)
     for i, ji in enumerate(j):
         a = hl._first_cycle_pencil(h[i], int(ji))
         span, absev = _smallest_eig_span(a, k)
-        assert _angle(p_dev[i, :ji], span) < 1e-7, i
-        np.testing.assert_array_equal(p_dev[i, ji:], 0.0)
-        # Ritz-value parity on the device space
-        pq = p_dev[i, :ji]
+        assert _angle(p[i, :ji], span) < 1e-7, i
+        np.testing.assert_array_equal(p[i, ji:], 0.0)
+        np.testing.assert_allclose(p[i].T @ p[i], np.eye(k), atol=1e-12)
+        # Ritz-value parity on the host driver's space
+        pq = p[i, :ji]
         theta = np.sort(np.abs(np.linalg.eigvals(pq.T @ a @ pq)))
         np.testing.assert_allclose(theta, absev[:k], rtol=1e-8)
-        # host oracle stays inside the 2k-smallest candidate span
-        assert p_host[i] is not None and p_host[i].shape[1] == k
-        span2k, _ = _smallest_eig_span(a, 2 * k)
-        assert _angle(p_host[i],
-                      span2k @ (span2k.T @ p_host[i])) < 1e-7, i
 
 
 def test_harmonic_ritz_first_cycle_gates_short_and_singular():
+    """Too-short chains and singular H_m (non-finite pencils from the
+    device's QR solve) are gated with a zero basis; a graded but solvable
+    H_m is not."""
     rng = np.random.default_rng(19)
     k, m = 3, 8
-    j = np.asarray([8, 2, 8])                  # chain 1: j <= k → no space
-    h = _hessenberg_stack(3, m, j, rng)
+    j = np.asarray([8, 2, 8, 8])               # chain 1: j <= k → no space
+    h = _hessenberg_stack(4, m, j, rng)
     h[2, :m, :] = 0.0                          # chain 2: singular H_m
     h[2, m, m - 1] = 1.0
-    _, ok = dl.harmonic_ritz_first_cycle_stacked(
-        jnp.asarray(h), jnp.asarray(j), k)
-    ok = np.asarray(ok)
-    assert bool(ok[0]) and not bool(ok[1]) and not bool(ok[2])
+    h[3] *= np.logspace(0, -9, m + 1)[:, None]  # chain 3: graded
+    p, ok = _first_cycle_host(h, j, k)
+    assert ok.tolist() == [True, False, False, True]
+    assert np.isfinite(p).all()
+    np.testing.assert_array_equal(p[~ok], 0.0)
 
 
 # -------------------------------------------------- harmonic-Ritz, deflated
@@ -208,15 +212,20 @@ def _deflated_pencil_stack(bsz, k, mi, j, rng, gap=8.0):
     return g, whv
 
 
-@pytest.mark.parametrize("widths", [(6, 6), (6, 3)])
+def _deflated_host(g, whv, j, k):
+    """The deflated cycle's split: the device builds M, the host driver
+    takes its dominant subspace."""
+    mm = dl.deflated_pencil_stacked(jnp.asarray(g), jnp.asarray(whv))
+    return hl.ritz_deflated_padded(np.asarray(mm), j, k)
+
+
+@pytest.mark.parametrize("widths", [(6, 6), (6, 3), (6, 3, 1)])
 def test_harmonic_ritz_deflated_matches_lapack_on_gapped(widths):
     rng = np.random.default_rng(23)
     k, mi = 3, 6
     j = np.asarray(widths)
     g, whv = _deflated_pencil_stack(len(j), k, mi, j, rng)
-    p_dev, ok = dl.harmonic_ritz_deflated_stacked(
-        jnp.asarray(g), jnp.asarray(whv), jnp.asarray(j), k)
-    p_dev, ok = np.asarray(p_dev), np.asarray(ok)
+    p, ok = _deflated_host(g, whv, j, k)
     assert ok.all()
     for i, ji in enumerate(j):
         s = k + int(ji)
@@ -226,26 +235,34 @@ def test_harmonic_ritz_deflated_matches_lapack_on_gapped(widths):
         evals, evecs = np.linalg.eig(mm)
         order = np.argsort(np.abs(evals))[::-1][:k]
         span = np.real(evecs[:, order])
-        assert _angle(p_dev[i, :s], span) < 1e-7, i
-        np.testing.assert_array_equal(p_dev[i, s:], 0.0)
-        # host oracle stays inside the dominant 2k-candidate span (its
-        # pivoted-QR pick among near-equal candidates is order-arbitrary)
-        p_host = hl.harmonic_ritz_deflated(ge, we, k)
-        assert p_host.shape[1] == k
+        assert _angle(p[i, :s], span) < 1e-7, i
+        np.testing.assert_array_equal(p[i, s:], 0.0)
+        np.testing.assert_allclose(p[i].T @ p[i], np.eye(k), atol=1e-12)
+        # the sequential solver's driver stays inside the dominant
+        # 2k-candidate span (its pivoted-QR pick among near-equal
+        # candidates is order-arbitrary)
+        p_seq = hl.harmonic_ritz_deflated(ge, we, k)
+        assert p_seq.shape[1] == k
         order2k = np.argsort(np.abs(evals))[::-1][: 2 * k]
         span2k = np.linalg.qr(np.real(evecs[:, order2k]))[0]
-        assert _angle(p_host, span2k @ (span2k.T @ p_host)) < 1e-6, i
+        assert _angle(p_seq, span2k @ (span2k.T @ p_seq)) < 1e-6, i
 
 
 def test_harmonic_ritz_deflated_gates_singular_pencil():
+    """A singular ĜᵀĜ (non-finite M from the device's QR solve) is gated
+    with a zero basis, a graded but solvable Ĝ is not, and a chain that
+    took no step (j = 0) is left alone."""
+    rng = np.random.default_rng(37)
     k, mi = 3, 6
-    j = np.asarray([6])
-    g = np.zeros((1, k + mi + 1, k + mi))      # ĜᵀĜ singular → gate, no NaN
-    whv = np.zeros_like(g)
-    p, ok = dl.harmonic_ritz_deflated_stacked(
-        jnp.asarray(g), jnp.asarray(whv), jnp.asarray(j), k)
-    assert not bool(np.asarray(ok)[0])
-    assert np.isfinite(np.asarray(p)).all()
+    j = np.asarray([6, 6, 4, 0])
+    g, whv = _deflated_pencil_stack(4, k, mi, j, rng)
+    g[1] = 0.0                                 # chain 1: ĜᵀĜ singular
+    whv[1] = 0.0
+    g[2] *= np.logspace(0, -6, k + mi)[None, :]   # chain 2: graded Ĝ
+    p, ok = _deflated_host(g, whv, j, k)
+    assert ok.tolist() == [True, False, True, False]
+    assert np.isfinite(p).all()
+    np.testing.assert_array_equal(p[~ok], 0.0)
 
 
 # --------------------------------------------------- assemblers vs gcrodr
@@ -290,3 +307,25 @@ def test_assemblers_match_host_blocks():
             assert col[k + c + 1] == 1.0 and np.abs(col).sum() == 1.0
         np.testing.assert_array_equal(whv[i, :, k + ji:], 0.0)
         np.testing.assert_array_equal(whv[i, k + ji + 1:, :], 0.0)
+
+
+# ------------------------------------------------------- refresh factors
+
+def test_host_refresh_factors_match_the_device():
+    """The host twin of refresh_factors: the same Q, R⁻¹ and gate."""
+    rng = np.random.default_rng(41)
+    gp = rng.standard_normal((3, 11, 4))
+    gp[2, :, 3] = gp[2, :, 2]                # chain 2: rank deficient
+    want = np.asarray([True, False, True])
+    q, inv, ok = hl.refresh_factors_stacked(gp, want)
+    q_d, inv_d, ok_d = map(np.asarray,
+                           dl.refresh_factors(jnp.asarray(gp),
+                                              jnp.asarray(want)))
+    np.testing.assert_array_equal(ok, ok_d)
+    assert ok.tolist() == [True, False, False]
+    np.testing.assert_allclose(np.abs(q[0]), np.abs(q_d[0]), atol=1e-12)
+    np.testing.assert_allclose(np.abs(inv[0]), np.abs(inv_d[0]),
+                               rtol=1e-10, atol=1e-12)
+    np.testing.assert_array_equal(q[1:], 0.0)
+    np.testing.assert_array_equal(inv[1:], np.broadcast_to(np.eye(4),
+                                                           (2, 4, 4)))

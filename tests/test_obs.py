@@ -377,16 +377,24 @@ def test_lockstep_cycle_counters_match_the_harness_arithmetic():
 
 @pytest.mark.parametrize("contain", [False, True],
                          ids=["plain", "containment"])
-def test_hostlink_bytes_match_the_shapes(contain):
+def test_hostlink_bytes_match_the_shapes(contain, monkeypatch):
     """The byte counters equal the reckoning from the arrays' shapes: a
     cold and a warm solve of numpy right-hand sides."""
     from repro.core.robust import RetryPolicy
+    from repro.solvers import hostlinalg as hl
+
+    calls = {"ritz_first_cycle_padded": 0, "ritz_deflated_padded": 0}
+    for name in calls:
+        def spy(*a, real=getattr(hl, name), name=name):
+            calls[name] += 1
+            return real(*a)
+        monkeypatch.setattr(hl, name, spy)
 
     ops, b = _batched_ops()
     bsz, n = b.shape
-    k = 6
+    m, k = 18, 6
     solver = BatchedGCRODRSolver(
-        KrylovConfig(m=18, k=k, tol=1e-8, maxiter=2000),
+        KrylovConfig(m=m, k=k, tol=1e-8, maxiter=2000),
         policy=RetryPolicy() if contain else None)
     obs.enable(krylov_capacity=0)
     _, stats = _two_solves(solver, ops, b)
@@ -404,6 +412,17 @@ def test_hostlink_bytes_match_the_shapes(contain):
                 + 3 * bsz * i4                   # iters, matvecs, cycles
                 + 4 * bsz                        # stalled, est, zerob, pad
                 + (bsz if contain else 0))       # quar
+    # each cycle's harmonic-Ritz pencils down, the host's bases up
+    fresh, deflated = calls.values()
+    assert fresh > 0 and deflated > 0
+    d2h += fresh * (bsz * m * m * f8             # A
+                    + bsz * (m + 1) * m * f8     # H̄
+                    + bsz * i4 + 2 * bsz)        # j; can, ready
+    h2d += fresh * (bsz * m * k * f8             # P
+                    + bsz * (m + 1) * k * f8     # Q
+                    + bsz * k * k * f8 + bsz)    # R⁻¹; est
+    d2h += deflated * (bsz * m * m * f8 + bsz * i4)   # M (k + mi = m); j
+    h2d += deflated * (bsz * m * k * f8 + bsz)        # P; ok
     c = obs.summary()["counters"]
     assert c["hostlink.h2d_bytes"] == h2d
     assert c["hostlink.d2h_bytes"] == d2h
@@ -445,3 +464,30 @@ def test_spans_mirror_into_the_profiler(tmp_path):
     assert registry.snapshot()["counters"]["lockstep.dispatches"] == 2
     obs.enable()
     assert obs.last() == (None, None)
+
+
+def test_host_eig_counters(monkeypatch):
+    """`ritz.host_chains` counts the chain refreshes the host eigensolve
+    ran, which is Σ the chains' `SolveStats.cycles`; `ritz.host_gated` the
+    chains its gate kept on their old space; `ritz.host_s` its seconds."""
+    from repro.solvers import hostlinalg as hl
+
+    ops, b = _batched_ops()
+    cfg = KrylovConfig(m=18, k=6, tol=1e-8, maxiter=2000)
+    real, gated = hl.ritz_deflated_padded, []
+
+    def gate_first_chain(mm, j, k):
+        p, ok = real(mm, j, k)
+        ok = ok.copy()
+        ok[0] = False               # chain 0 keeps its space every cycle
+        gated.append(int(((j > 0) & ~ok).sum()))
+        return p, ok
+
+    monkeypatch.setattr(hl, "ritz_deflated_padded", gate_first_chain)
+    obs.enable(krylov_capacity=0)
+    _, stats = _two_solves(BatchedGCRODRSolver(cfg), ops, b)
+    c = obs.summary()["counters"]
+    assert all(s.converged for s in stats)
+    assert c["ritz.host_chains"] == sum(s.cycles for s in stats)
+    assert c["ritz.host_gated"] == sum(gated) > 0
+    assert c["ritz.host_s"] > 0

@@ -8,8 +8,10 @@ families' default grid (64x64) and at 256x256 (n = 65,536, near the
 paper's largest systems). The topology is described inside a fixture, so
 only the worker that runs this file loads the TPU library.
 
-Also here: how the backend steers the kernel path (`kernels/ops.py`), and
-where the compile cache lands (`repro.compile_cache`).
+Also here: the lockstep solver's fp64 cycle programs, which leave the
+harmonic-Ritz eigensolve to the host, and its refresh programs; how the
+backend steers the kernel path (`kernels/ops.py`), and where the
+compile cache lands (`repro.compile_cache`).
 """
 import os
 
@@ -137,6 +139,110 @@ def test_fp64_solver_request_on_tpu_raises(field, monkeypatch):
         make(KrylovConfig(inner_dtype="float32"), use_kernel=True)
     with pytest.raises(TypeError, match="fp32 on a TPU"):
         solve_gmres(None, jnp.ones((4, 4)), cfg, use_kernel=True)
+
+
+def _fp64_cycle_shapes(bsz, nx=64, m=40, k=15):
+    """Abstract operators, state and aux of the fp64 lockstep solver at
+    darcy-64-f64's shapes (n = nx², GCRO-DR m 40, k 15) for `bsz` chains."""
+    from repro.solvers import batched as bt
+    from repro.solvers.operator import PreconditionedOp, StencilOp
+    from repro.solvers.precond import JacobiPrecond
+
+    f8, n = jnp.float64, nx * nx
+
+    def sds(*shape, dt=f8):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    ops = PreconditionedOp(StencilOp(sds(bsz, 5, nx, nx)),
+                           JacobiPrecond(sds(bsz, n)))
+    vec, basis, mask = sds(bsz, n), sds(bsz, n, k), sds(bsz, dt=bool)
+    args = (ops, vec, vec, basis, basis, basis, mask, mask, sds(),
+            sds(dt=jnp.int32), sds())
+    s, aux, _ = jax.eval_shape(lambda *a: bt._entry(
+        *a, k=k, use_carry=True, pad_given=True), *args)
+    return ops, s, aux
+
+
+def _cycle_kw(name, m=40, k=15):
+    kw = dict(k=k, orthog="cgs2", use_kernel=False, h_acc="native",
+              stall_break=False)
+    return (dict(kw, m=m, can_grow=False) if name == "fresh"
+            else dict(kw, mi=m - k))
+
+
+def _cycle_fn(name):
+    from repro.solvers import batched as bt
+
+    return bt._fresh_cycle if name == "fresh" else bt._deflated_cycle
+
+
+@pytest.mark.parametrize("program", ["fresh", "deflated", "fresh_refresh",
+                                     "deflated_refresh"])
+def test_fp64_host_eig_programs_compile_for_v5e(program, one_chip):
+    """The fp64 cycle programs that end at the harmonic-Ritz pencil, and
+    the refresh programs that rebuild (C, U) from the host's basis, compile
+    for one v5e chip at darcy-64-f64's shapes and 64 chains, and fit its
+    memory."""
+    from repro.solvers import batched as bt
+
+    bsz = 64
+    def place(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    name = program.split("_")[0]
+    fn, kw = _cycle_fn(name), _cycle_kw(name)
+    ops, s, aux = _fp64_cycle_shapes(bsz)
+    if program == name:
+        lowered = fn.lower(place(ops), place(s), place(aux), **kw)
+    else:
+        s, _, pend = jax.eval_shape(lambda *a: fn(*a, **kw), ops, s, aux)
+        k, width = kw["k"], kw.get("m", kw.get("mi", 0) + kw["k"])
+        p = jax.ShapeDtypeStruct((bsz, width, k), jnp.float64)
+        mask = jax.ShapeDtypeStruct((bsz,), bool)
+        if name == "fresh":
+            q = jax.ShapeDtypeStruct((bsz, width + 1, k), jnp.float64)
+            inv = jax.ShapeDtypeStruct((bsz, k, k), jnp.float64)
+            lowered = bt._fresh_refresh.lower(
+                *place((s, pend["v"], pend["h"], p, q, inv, mask)), k=k)
+        else:
+            lowered = bt._deflated_refresh.lower(
+                *place((s, pend["g"], pend["ut"], pend["v"], pend["step"],
+                        p, mask)), k=k)
+    mem = lowered.compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < used < 16e9          # a v5e chip holds 16 GB
+
+
+@pytest.mark.parametrize("name", ["fresh", "deflated"])
+def test_fp64_host_eig_cycles_drop_the_sweep_loop(name):
+    """With the eigensolve on the host, a cycle program holds no loop of
+    its own: its loops are those of its Arnoldi sweep alone (an iterative
+    eigensolve, like the subspace iteration's sweep `fori_loop`, adds
+    one)."""
+    from functools import partial
+
+    from repro.solvers.arnoldi import _arnoldi_cycle_impl
+
+    bsz = 8
+    ops, s, aux = _fp64_cycle_shapes(bsz)
+    fn, kw = _cycle_fn(name), _cycle_kw(name)
+
+    def loops(f, *args):
+        text = str(jax.make_jaxpr(f)(*args))  # a fori_loop may be a scan
+        return text.count("while[") + text.count("scan[")
+
+    width = kw["m"] if name == "fresh" else kw["mi"]
+    c_rows = (jax.ShapeDtypeStruct((bsz, 0, s["r"].shape[1]), jnp.float64)
+              if name == "fresh" else
+              jax.ShapeDtypeStruct((bsz, kw["k"], s["r"].shape[1]),
+                                   jnp.float64))
+    sweep = jax.vmap(partial(_arnoldi_cycle_impl, m=width, orthog="cgs2",
+                             use_kernel=False, h_acc="native"))
+    arnoldi = loops(sweep, ops, c_rows, s["r"], aux["tol_abs"])
+    assert arnoldi >= 1
+    assert loops(lambda *a: fn.__wrapped__(*a, **kw), ops, s, aux) == arnoldi
 
 
 def test_fp64_applies_leave_the_kernels_on_tpu(monkeypatch):

@@ -4,10 +4,11 @@
 transfer while still permitting explicit ones (`jnp.asarray`, `device_put`,
 `device_get` / `np.asarray` on a device array). The refactored
 `BatchedGCRODRSolver.solve_batch` is designed to cross the boundary only at
-explicit, counted points — entry upload, one 4-flag fetch per cycle, one
-finalize fetch — so an entire lockstep solve (including warm-started
-follow-up solves and the k = 0 GMRES special case) must run clean under the
-guard. A regression here means some per-cycle host round-trip crept back
+explicit, counted points — entry upload, one fetch per cycle (4 flags and
+the small harmonic-Ritz pencils; the host's bases go back by explicit
+puts), one finalize fetch — so an entire lockstep solve (including
+warm-started follow-up solves and the k = 0 GMRES special case) must run
+clean under the guard. A regression here means some per-cycle host round-trip crept back
 into the hot loop."""
 import jax
 import jax.numpy as jnp
