@@ -5,6 +5,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import precision
+from repro.kernels.precision import matmul as mm
+
 
 def stencil5_matvec(coeffs: jax.Array, x: jax.Array) -> jax.Array:
     """y[i,j] = c·x[i,j] + n·x[i-1,j] + s·x[i+1,j] + w·x[i,j-1] + e·x[i,j+1]."""
@@ -48,17 +51,18 @@ def fused_orthog(v_basis: jax.Array, w: jax.Array, mask: jax.Array,
     """
     if acc_dtype is not None and jnp.dtype(acc_dtype) != w.dtype:
         acc = jnp.dtype(acc_dtype)
-        h1 = mask.astype(acc) * jnp.matmul(v_basis, w,
+        hp = precision.of(v_basis, w)
+        h1 = mask.astype(acc) * jnp.matmul(v_basis, w, precision=hp,
                                            preferred_element_type=acc)
-        w1 = w - v_basis.T @ h1.astype(w.dtype)
-        h2 = mask.astype(acc) * jnp.matmul(v_basis, w1,
+        w1 = w - mm(v_basis.T, h1.astype(w.dtype))
+        h2 = mask.astype(acc) * jnp.matmul(v_basis, w1, precision=hp,
                                            preferred_element_type=acc)
-        w2 = w1 - v_basis.T @ h2.astype(w.dtype)
+        w2 = w1 - mm(v_basis.T, h2.astype(w.dtype))
         return w2, (h1 + h2).astype(w.dtype)
-    h1 = mask * (v_basis @ w)
-    w1 = w - v_basis.T @ h1
-    h2 = mask * (v_basis @ w1)
-    w2 = w1 - v_basis.T @ h2
+    h1 = mask * mm(v_basis, w)
+    w1 = w - mm(v_basis.T, h1)
+    h2 = mask * mm(v_basis, w1)
+    w2 = w1 - mm(v_basis.T, h2)
     return w2, h1 + h2
 
 
@@ -73,8 +77,8 @@ def arnoldi_step(coeffs: jax.Array, inv_diag: jax.Array, c_rows: jax.Array,
     nx, ny = coeffs.shape[-2:]
     u = inv_diag * vin
     w = stencil5_matvec(coeffs, u.reshape(nx, ny)).reshape(-1)
-    bj = c_rows @ w
-    w = w - c_rows.T @ bj
+    bj = mm(c_rows, w)
+    w = w - mm(c_rows.T, bj)
     w, h = fused_orthog(v_basis, w, mask, acc_dtype=acc_dtype)
     return w, h, bj
 

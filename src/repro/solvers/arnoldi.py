@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+from repro.kernels.precision import matmul as mm
 from repro.solvers.operator import PreconditionedOp, StencilOp, apply_op
 from repro.solvers.precond import JacobiPrecond
 
@@ -74,7 +75,7 @@ def _mgs(v, w, j, m):
     def body(i, carry):
         w, h = carry
         active = (i <= j).astype(w.dtype)
-        hi = active * jnp.dot(v[i], w)
+        hi = active * mm(v[i], w)
         w = w - hi * v[i]
         return w, h.at[i].set(hi)
 
@@ -147,8 +148,8 @@ def _arnoldi_cycle_impl(op, c_rows, r0, tol_abs, *, m: int, orthog: str = "cgs2"
                     w = apply_op(op, v[j])
                 if k > 0:
                     with jax.named_scope("skr/arnoldi/orthog"):
-                        bj = c_rows @ w
-                        w = w - c_rows.T @ bj
+                        bj = mm(c_rows, w)
+                        w = w - mm(c_rows.T, bj)
                     b_new = b.at[:, j].set(bj)
                 else:
                     b_new = b
@@ -162,7 +163,12 @@ def _arnoldi_cycle_impl(op, c_rows, r0, tol_abs, *, m: int, orthog: str = "cgs2"
                         w, hcol = _mgs(v, w, j, m)
             hj1 = jnp.linalg.norm(w)
             brk_new = hj1 < 1e-14 * safe_beta
-            v = v.at[j + 1].set(w / jnp.maximum(hj1, jnp.finfo(dt).tiny))
+            # the new row by a select: under vmap a write at the per-chain
+            # j + 1 is a scatter over chains, whose scoped VMEM on a TPU
+            # outgrows the chip's 16 MiB at 128² in fp64
+            row = jnp.arange(m + 1)[:, None] == j + 1
+            vj1 = w / jnp.maximum(hj1, jnp.finfo(dt).tiny)
+            v = jnp.where(row, vj1[None], v)
             hcol = hcol.at[j + 1].set(hj1)
             h = h.at[:, j].set(hcol)
             # Progressive Givens on a copy of the new column → exact LS

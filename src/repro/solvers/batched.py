@@ -85,6 +85,7 @@ import numpy as np
 
 from repro import obs
 from repro.kernels import ops as kops
+from repro.kernels import precision
 from repro.obs.telemetry import KrylovTelemetry, drain_chain
 from repro.solvers import devlinalg as dl
 from repro.solvers import hostlinalg as hl
@@ -144,7 +145,19 @@ def _scaled_cols_b(u, dnorm):
 
 def _mat_post_b(y, inv_r):
     """Per-chain Y R⁻¹ (stacked right-multiply by the small R factor)."""
-    return jnp.einsum("bnk,bkl->bnl", y, inv_r)
+    return precision.einsum("bnk,bkl->bnl", y, inv_r)
+
+
+@jax.jit
+def _keep_carry(est, u, prev, quar):
+    """The device-resident recycle carry after a solve: a chain that owns a
+    space this solve takes it, the others keep `prev` bitwise; quarantined
+    chains (containment on, else `quar` is None) restart cold."""
+    with jax.named_scope("skr/update"):
+        if quar is not None:
+            est = est & ~quar
+        u = _mask(est, u, prev.astype(u.dtype))
+        return u if quar is None else _mask(quar, jnp.zeros_like(u), u)
 
 
 def _sel(mask_np, new, old):
@@ -218,7 +231,7 @@ def _delta_qc_b(c_old, c_new, ok):
     harmonic-Ritz refresh: sin θ_max = sqrt(1 − σ_min(C_oldᵀC_new)²) — one
     stacked (k × k) SVD, NaN where the refresh was rejected (the device
     twin of core/metrics.delta_subspace, tested against it)."""
-    ov = jnp.einsum("bnk,bnl->bkl", c_old, c_new)
+    ov = precision.einsum("bnk,bnl->bkl", c_old, c_new)
     sv = jnp.linalg.svd(ov, compute_uv=False)
     delta = jnp.sqrt(jnp.clip(1.0 - sv[:, -1] ** 2, 0.0, 1.0))
     return jnp.where(ok, delta, jnp.nan)
@@ -254,6 +267,17 @@ def _flags(s, aux, active_prev, step, any_grew):
     return jnp.stack(out)
 
 
+def _no_progress(s, step):
+    """Count, per chain, the cycles in a row that left its best residual so
+    far within 1 % (3 of them mark it stalled). Against the best and not
+    the previous cycle: at the fp32 round-off floor the residual wanders,
+    and a cycle that only wins back a rise must not restart the count."""
+    s["no_prog"] = jnp.where(step & (s["rnorm"] > 0.99 * s["best"]),
+                             s["no_prog"] + 1, 0)
+    s["best"] = jnp.minimum(s["best"], s["rnorm"])
+    return s
+
+
 def _contain_guard(s, aux, active, z_prev, r_prev, rn_prev, z, r, rn):
     """In-dispatch divergence quarantine (containment on): a chain whose
     updated residual went non-finite or beyond the divergence threshold is
@@ -271,11 +295,12 @@ def _contain_guard(s, aux, active, z_prev, r_prev, rn_prev, z, r, rn):
 
 
 @partial(jax.jit, static_argnames=("k", "use_carry", "pad_given",
-                                   "contain", "tele_cap", "tele_delta"))
+                                   "contain", "tele_cap", "tele_delta",
+                                   "stall_break"))
 def _entry(ops, b, z0, c0, u0, uc, cok, pad_in, tol, lim, div,
            *, k: int, use_carry: bool, pad_given: bool,
            contain: bool = False, tele_cap: int = 0,
-           tele_delta: bool = False):
+           tele_delta: bool = False, stall_break: bool = False):
     """Norms, padding mask and the warm start (Alg. 2 l.2-7) as one fused
     dispatch. The warm-start rank gate is the batched masked triangular
     inverse (devlinalg.tri_inv_stacked) — no per-chain host loop.
@@ -285,7 +310,9 @@ def _entry(ops, b, z0, c0, u0, uc, cok, pad_in, tol, lim, div,
     fetched, bitwise-identical numerics (the tele_cap=0 pattern). With
     contain=True the state gains a per-chain `quar` bool and aux gains the
     absolute divergence threshold `div * ||b||`; a chain whose RHS is
-    already non-finite is quarantined at entry (its row never solves)."""
+    already non-finite is quarantined at entry (its row never solves).
+    stall_break=True adds each chain's best residual so far (`best`), which
+    the cycles' stall test compares against."""
     with jax.named_scope("skr/entry"):
         bsz = b.shape[0]
         dt = b.dtype
@@ -318,6 +345,8 @@ def _entry(ops, b, z0, c0, u0, uc, cok, pad_in, tol, lim, div,
             s["u"] = _mask(ok, u_new, s["u"])
             s["est"] = ok
             s["matvecs"] = jnp.where(want, k, 0).astype(jnp.int32)
+        if stall_break:
+            s["best"] = s["rnorm"]
         if tele_cap > 0:
             _tele_init(s, bsz, dt, tele_cap=tele_cap, tele_delta=tele_delta)
         f = _flags(s, aux, jnp.zeros(bsz, bool), jnp.zeros(bsz, bool),
@@ -367,8 +396,7 @@ def _fresh_cycle(ops, s, aux, *, m: int, k: int, orthog: str,
                  matvecs=s["matvecs"] + jnp.where(step, j + 1, 0),
                  cycles=s["cycles"] + step.astype(jnp.int32))
         if stall_break:
-            s["no_prog"] = jnp.where(step & (s["rnorm"] > 0.99 * rprev),
-                                     s["no_prog"] + 1, 0)
+            s = _no_progress(s, step)
         any_grew = jnp.zeros((), bool)
     if k > 0:
         # establish / re-establish recycle spaces per chain: the pencil
@@ -470,8 +498,7 @@ def _deflated_cycle(ops, s, aux, *, mi: int, k: int, orthog: str,
                  matvecs=s["matvecs"] + jnp.where(step, j + 1, 0),
                  cycles=s["cycles"] + step.astype(jnp.int32))
         if stall_break:
-            s["no_prog"] = jnp.where(step & (s["rnorm"] > 0.99 * rprev),
-                                     s["no_prog"] + 1, 0)
+            s = _no_progress(s, step)
             s["stalled"] = s["stalled"] | (s["no_prog"] >= 3)
 
     # the stacked generalized harmonic-Ritz pencil of the next spaces
@@ -498,7 +525,8 @@ def _deflated_refresh(s, g, ut, v, step, p, ritz_ok, *, k: int,
     with jax.named_scope("skr/ritz"):
         if contain:   # a quarantined chain must not refresh from garbage
             ritz_ok = ritz_ok & ~s["quar"]
-        q, inv_rr, ref_ok = dl.refresh_factors(g @ p, ritz_ok & step)
+        q, inv_rr, ref_ok = dl.refresh_factors(precision.matmul(g, p),
+                                                ritz_ok & step)
         c_new, yk = _next_cu_b(ut, v, s["c"], p[:, :k], p[:, k:],
                                q[:, :k], q[:, k:])
         u_new = _mat_post_b(yk, inv_rr)
@@ -535,7 +563,8 @@ class BatchedGCRODRSolver:
     """
 
     def __init__(self, cfg: KrylovConfig, use_kernel: bool = False,
-                 stall_break: bool = False, sharding=None, policy=None):
+                 stall_break: bool = False, sharding=None, policy=None,
+                 device_carry: bool = False):
         if cfg.k > 0 and cfg.ritz_refresh != "cycle":
             raise NotImplementedError(
                 "BatchedGCRODRSolver implements the paper-faithful "
@@ -562,7 +591,13 @@ class BatchedGCRODRSolver:
         # — set by the mixed-precision outer loop on its inner fp32 solver,
         # where the fp32 round-off floor is an expected exit
         self.stall_break = stall_break
-        self.u_carry: np.ndarray | None = None   # (B, n, k)
+        # device_carry: keep `u_carry` on the device between solves (the
+        # finalize fetch leaves it out) — set by the mixed-precision outer
+        # loop on its inner fp32 solver, so the carry does not cross the
+        # host link at every refinement pass; the outer loop fetches it
+        # once per call, with its iterate
+        self.device_carry = device_carry
+        self.u_carry: np.ndarray | jax.Array | None = None   # (B, n, k)
         self.carry_ok: np.ndarray | None = None  # (B,) bool
         self.systems_solved = 0
         # x_device: the DEVICE-resident (B, n) solution of the most recent
@@ -573,9 +608,13 @@ class BatchedGCRODRSolver:
         self.x_device = None
         self._inner: BatchedGCRODRSolver | None = None    # fp32 correction
         self._inner64: BatchedGCRODRSolver | None = None  # fp64 fallback
+        # the public carry object the fp32 inner solver holds a device copy
+        # of (the mixed path skips the upload while `u_carry` is still it)
+        self._carry_mirror: np.ndarray | None = None
 
     def reset(self):
         self.u_carry = None
+        self._carry_mirror = None
         self.carry_ok = None
         self.systems_solved = 0
         self.x_device = None
@@ -596,10 +635,18 @@ class BatchedGCRODRSolver:
         mixed-precision inner/fallback mirrors so a later downcast cannot
         resurrect the retired chain's subspace. Pure host numpy — zero
         device syncs, so the `host_syncs <= 2 + cycles` budget is
-        untouched (pinned by tests/test_serve.py under transfer_guard)."""
+        untouched (pinned by tests/test_serve.py under transfer_guard);
+        the fp32 inner solver's device carry takes the public carry it
+        mirrors, and is fetched only if the public one was replaced."""
         for s in (self, self._inner, self._inner64):
             if s is None or s.u_carry is None:
                 continue
+            if isinstance(s.u_carry, jax.Array):
+                # the mixed path's device-resident inner carry: continue
+                # from the public carry it mirrors (swapped just above)
+                s.u_carry = (self.u_carry
+                             if self.u_carry is self._carry_mirror
+                             else np.array(jax.device_get(s.u_carry)))
             if carry is None:
                 s.u_carry[w] = 0.0
                 ok = False
@@ -676,7 +723,9 @@ class BatchedGCRODRSolver:
             scalars = (np.asarray(cfg.tol, dt),
                        np.asarray(cfg.maxiter, np.int32),
                        np.asarray(div, dt))
-            obs.hostlink("h2d", carry, cok_np, pad_np, scalars)
+            obs.hostlink("h2d",
+                         None if isinstance(carry, jax.Array) else carry,
+                         cok_np, pad_np, scalars)
             uc = self._dev(jnp.asarray(carry)) if use_carry else u0
             cok = jnp.asarray(cok_np)
             pad_in = jnp.asarray(pad_np)
@@ -689,7 +738,7 @@ class BatchedGCRODRSolver:
                            tol_d, lim_d, div_d,
                            k=k, use_carry=use_carry, pad_given=pad_given,
                            contain=contain, tele_cap=tele_cap,
-                           tele_delta=tele_delta)
+                           tele_delta=tele_delta, stall_break=self.stall_break)
         with obs.span("host_sync", cat="solver", what="entry_flags"):
             fl = jax.device_get(f)
         obs.hostlink("d2h", fl)
@@ -749,7 +798,8 @@ class BatchedGCRODRSolver:
         x_dev = _from_z_b(ops, s["z"])
         self.x_device = x_dev
         fetch = (x_dev, s["rnorm"], s["iters"], s["matvecs"], s["cycles"],
-                 s["stalled"], s["est"], s["u"], aux["bnorm"],
+                 s["stalled"], s["est"],
+                 None if self.device_carry else s["u"], aux["bnorm"],
                  aux["zerob"], aux["pad"])
         if contain:
             # the quarantine verdicts ride the EXISTING finalize fetch
@@ -822,14 +872,22 @@ class BatchedGCRODRSolver:
                     # restarts cold
                     established = established & ~quar
                 if self.u_carry is None:
-                    self.u_carry = np.zeros((bsz, n, k), dtype=u_np.dtype)
                     self.carry_ok = np.zeros(bsz, dtype=bool)
-                keep = established[:, None, None]
-                self.u_carry = np.where(keep, u_np,
-                                        self.u_carry.astype(u_np.dtype))
+                if self.device_carry:
+                    # uc: the carry this solve started from (zeros if none)
+                    self.u_carry = _keep_carry(s["est"], s["u"], uc,
+                                               s["quar"] if contain else None)
+                else:
+                    if self.u_carry is None:
+                        self.u_carry = np.zeros((bsz, n, k),
+                                                dtype=u_np.dtype)
+                    keep = established[:, None, None]
+                    self.u_carry = np.where(keep, u_np,
+                                            self.u_carry.astype(u_np.dtype))
                 self.carry_ok = self.carry_ok | established
                 if contain and quar.any():
-                    self.u_carry[quar] = 0.0
+                    if not self.device_carry:
+                        self.u_carry[quar] = 0.0
                     self.carry_ok = self.carry_ok & ~quar
                     obs.counter_add("health.quarantined_chains",
                                     int(quar.sum()))
@@ -878,11 +936,19 @@ class BatchedGCRODRSolver:
         engine's own padding no-op, so their recycle carries are untouched)
         and ONE inner lockstep solve reduces each by `cfg.inner_tol`; the
         fp64 accumulate + true-residual recompute is one batched dispatch.
-        When any chain stagnates in fp32 the WHOLE batch falls back to fp64
-        correction passes (lockstep latency is the max over chains anyway).
+        A chain whose fp32 pass did not halve its residual while it started
+        from a recycled space gets one more fp32 pass without it (its carry
+        dropped: a space recycled from the previous system can stall the
+        deflated cycles far above the fp32 floor, where fresh cycles do
+        not). When a chain stagnates in fp32 otherwise, the WHOLE batch
+        falls back to fp64 correction passes (lockstep latency is the max
+        over chains anyway).
+        Counters (repro.obs): `mixed.dispatches` (this call), and per outer
+        pass `mixed.passes_fp32` or `mixed.passes_fp64`.
         """
         cfg = self.cfg
         t0 = time.perf_counter()
+        obs.counter_add("mixed.dispatches")
         if not isinstance(b, jax.Array):
             b = np.asarray(b, np.float64)
             obs.hostlink("h2d", b)
@@ -909,6 +975,8 @@ class BatchedGCRODRSolver:
         outer = np.zeros(bsz, dtype=int)
         fb64 = np.zeros(bsz, dtype=bool)
         stuck = np.zeros(bsz, dtype=bool)  # no-progress even in fp64
+        dropped = np.zeros(bsz, dtype=bool)  # carry dropped for a retry
+        warm = np.zeros(bsz, dtype=bool)     # pass started from a carry
         ops32 = cast_operator(ops, jnp.float32)
         # outer-loop telemetry is host-side and free: the fp64 residual
         # norms are already fetched every pass (kind="outer"; the inner
@@ -918,13 +986,18 @@ class BatchedGCRODRSolver:
         if self._inner is None:
             self._inner = BatchedGCRODRSolver(cfg, use_kernel=self.use_kernel,
                                               stall_break=True,
+                                              device_carry=True,
                                               sharding=self.sharding)
         inner = self._inner
         # push the public carry (possibly from a checkpoint or an earlier
-        # precision) down into the inner solver, stored fp32
+        # precision) down into the inner solver, stored fp32 — unless it is
+        # the carry this solver stored last, which the inner solver still
+        # holds on the device
         if self.u_carry is not None:
             with obs.span("carry_upload", cat="solver"):
-                inner.u_carry = np.asarray(self.u_carry, np.float32)
+                if (self.u_carry is not self._carry_mirror
+                        or inner.u_carry is None):
+                    inner.u_carry = np.asarray(self.u_carry, np.float32)
                 inner.carry_ok = (self.carry_ok.copy()
                                   if self.carry_ok is not None else None)
         fallback = False
@@ -949,8 +1022,12 @@ class BatchedGCRODRSolver:
                 inner.cfg = dataclasses.replace(cfg, inner_dtype="float64",
                                                 tol=tol_i, maxiter=budget)
                 obs.hostlink("h2d", need)
+                warm = (inner.carry_ok.copy() if inner.u_carry is not None
+                        and inner.carry_ok is not None
+                        else np.zeros(bsz, dtype=bool))
                 d, st_in = inner.solve_batch(ops32, _downcast_masked(r, need))
                 outer += need
+                obs.counter_add("mixed.passes_fp32")
             else:
                 # ---- fp64 fallback lockstep pass -----------------------
                 if self._inner64 is None:
@@ -966,7 +1043,7 @@ class BatchedGCRODRSolver:
                 self._inner64.cfg = dataclasses.replace(
                     cfg, inner_dtype="float64", tol=tol_i, maxiter=budget)
                 self._inner64.u_carry = (
-                    np.asarray(inner.u_carry, np.float64)
+                    np.asarray(jax.device_get(inner.u_carry), np.float64)
                     if inner.u_carry is not None else None)
                 self._inner64.carry_ok = (inner.carry_ok.copy()
                                           if inner.carry_ok is not None
@@ -979,6 +1056,7 @@ class BatchedGCRODRSolver:
                                                np.float32)
                     inner.carry_ok = self._inner64.carry_ok.copy()
                 fb64 |= need
+                obs.counter_add("mixed.passes_fp64")
             passes += 1
             host_syncs += max(st.host_syncs for st in st_in)
             dispatches += max(st.dispatches for st in st_in) + 1
@@ -1007,12 +1085,19 @@ class BatchedGCRODRSolver:
                 if fallback:
                     stuck |= no_prog  # a true stall, not budget exhaustion
                     break             # fp64 lockstep is stuck too — stop
-                fallback = True      # fp32 stagnated somewhere → fp64 batch
+                if (no_prog & ~(warm & ~dropped)).any():
+                    fallback = True  # fp32 stagnated somewhere → fp64 batch
+                else:                # retry those chains cold, in fp32
+                    dropped |= no_prog
+                    inner.carry_ok = inner.carry_ok & ~no_prog
 
         # ---- finalize ----------------------------------------------------
         self.x_device = x   # fp64 accumulated iterate, device-resident
-        x_np = np.asarray(x)
-        obs.hostlink("d2h", x_np)
+        # the inner solver's device carry rides the iterate's fetch
+        u_dev = (inner.u_carry if cfg.k > 0
+                 and isinstance(inner.u_carry, jax.Array) else None)
+        x_np, u_np = jax.device_get((x, u_dev))
+        obs.hostlink("d2h", x_np, u_np)
         host_syncs += 1
         wall = time.perf_counter() - t0
         converged = zerob | (rnorm <= tol_abs)
@@ -1055,15 +1140,18 @@ class BatchedGCRODRSolver:
             ))
         if cfg.k > 0 and inner.u_carry is not None:
             with obs.span("carry_store", cat="solver"):
-                self.u_carry = np.asarray(inner.u_carry, np.float32)
+                self.u_carry = np.require(
+                    inner.u_carry if u_np is None else u_np, np.float32,
+                    ["W"])
                 self.carry_ok = (inner.carry_ok.copy()
                                  if inner.carry_ok is not None else None)
                 if quar.any():   # carry quarantine, as in the fp64 path
                     self.u_carry[quar] = 0.0
                     if self.carry_ok is not None:
                         self.carry_ok = self.carry_ok & ~quar
-                    inner.u_carry[quar] = 0.0
+                    inner.u_carry = self.u_carry
                     if inner.carry_ok is not None:
                         inner.carry_ok = inner.carry_ok & ~quar
+                self._carry_mirror = self.u_carry
         self.systems_solved += int((~zerob & ~pad).sum())
         return x_np, stats

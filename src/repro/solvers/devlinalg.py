@@ -33,6 +33,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import precision
+from repro.kernels.precision import matmul as mm
+
 # conditioning gate shared with hostlinalg._stack_well_conditioned
 _RTOL = 1e-12
 
@@ -107,7 +110,7 @@ def _qr_solve(a, b):
     non-finite entries, like an LU solve; callers gate on that."""
     q, r = jnp.linalg.qr(a)
     return jax.lax.linalg.triangular_solve(
-        r, q.swapaxes(-1, -2) @ b, left_side=True, lower=False)
+        r, mm(q.swapaxes(-1, -2), b), left_side=True, lower=False)
 
 
 def _svd_lstsq(a, rhs):
@@ -137,7 +140,7 @@ def lstsq_stacked(a, rhs):
     """
     q, r = jnp.linalg.qr(a)
     ok = _diag_ok(r)
-    qtb = jnp.einsum("bij,bi->bj", q, rhs)
+    qtb = precision.einsum("bij,bi->bj", q, rhs)
     eye = jnp.eye(r.shape[-1], dtype=r.dtype)
     safe = jnp.where(ok[:, None, None], r, eye[None])
     y_qr = jax.lax.linalg.triangular_solve(
@@ -231,8 +234,8 @@ def deflated_pencil_stacked(g, whv):
     """M = (ĜᴴĜ)⁻¹ ĜᴴŴᴴV̂ (B, k+mi, k+mi) from the padded blocks of
     `assemble_*_stacked`: block diagonal with a ZERO dead block. A chain
     with a singular ĜᴴĜ gets non-finite entries."""
-    a1 = g.swapaxes(1, 2) @ g                    # SPD (+ identity dead block)
-    a2 = g.swapaxes(1, 2) @ whv
+    a1 = mm(g.swapaxes(1, 2), g)                 # SPD (+ identity dead block)
+    a2 = mm(g.swapaxes(1, 2), whv)
     return _qr_solve(a1, a2)
 
 

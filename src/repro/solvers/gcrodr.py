@@ -37,6 +37,7 @@ import numpy as np
 
 from repro import obs
 from repro.kernels import ops as kops
+from repro.kernels.precision import matmul as mm
 from repro.obs.telemetry import KrylovTelemetry
 from repro.solvers.arnoldi import arnoldi_cycle
 from repro.solvers.gmres import (_downcast32, _ir_refine, _residual_norms,
@@ -59,16 +60,16 @@ _apply_cols = jax.jit(jax.vmap(apply_op, in_axes=(None, 1), out_axes=1))
 def _warm_start(u, au_q, z, r):
     """Alg. 2 lines 6-7 given Q from qr(A·U_old): project the initial
     residual onto range(C)ᶜ and absorb the correction into z."""
-    ctr = au_q.T @ r
-    z = z + u @ ctr
-    r = r - au_q @ ctr
+    ctr = mm(au_q.T, r)
+    z = z + mm(u, ctr)
+    r = r - mm(au_q, ctr)
     return z, r, jnp.linalg.norm(r)
 
 
 @jax.jit
 def _fresh_update(op, b, z, v, y):
     """z += Vᵀy (y zero-padded to m); recompute the true residual."""
-    z = z + v[:-1].T @ y
+    z = z + mm(v[:-1].T, y)
     r = b - apply_op(op, z)
     return z, r, jnp.linalg.norm(r)
 
@@ -76,21 +77,21 @@ def _fresh_update(op, b, z, v, y):
 @jax.jit
 def _fresh_cu(v, h, p, q):
     """First recycle space: Ỹ = V P, C = V_{m+1} Q (P, Q zero-padded)."""
-    yk = v[:-1].T @ p
-    c = v.T @ q
+    yk = mm(v[:-1].T, p)
+    c = mm(v.T, q)
     return c, yk
 
 
 @jax.jit
 def _rhs_and_dnorm(c, u, v, r):
     """Ŵᴴr pieces + ‖U columns‖ for the host-side LS solve."""
-    return c.T @ r, v @ r, jnp.linalg.norm(u, axis=0)
+    return mm(c.T, r), mm(v, r), jnp.linalg.norm(u, axis=0)
 
 
 @jax.jit
 def _deflated_update(op, b, z, ut, v, y_k, y_m):
     """z += Û y_k + V y_m (zero-padded); true residual + Ŵᴴ V̂ pencil."""
-    z = z + ut @ y_k + v[:-1].T @ y_m
+    z = z + mm(ut, y_k) + mm(v[:-1].T, y_m)
     r = b - apply_op(op, z)
     # Ŵ = [C V_{m+1}] is produced by the caller as (c, v); the pencil
     # Ŵᴴ V̂ is assembled on host from these small blocks.
@@ -100,18 +101,18 @@ def _deflated_update(op, b, z, ut, v, y_k, y_m):
 @jax.jit
 def _whv_blocks(c, ut, v):
     """Small blocks of Ŵᴴ V̂: Ŵ = [c, Vrows], V̂ = [ut, Vrows[:-1]]."""
-    cu = c.T @ ut                      # (k, k)
-    cv = c.T @ v[:-1].T                # (k, m)
-    vu = v @ ut                        # (m+1, k)
-    vv = v @ v[:-1].T                  # (m+1, m)
+    cu = mm(c.T, ut)                   # (k, k)
+    cv = mm(c.T, v[:-1].T)             # (k, m)
+    vu = mm(v, ut)                     # (m+1, k)
+    vv = mm(v, v[:-1].T)               # (m+1, m)
     return cu, cv, vu, vv
 
 
 @jax.jit
 def _next_cu(ut, v, c, p_k, p_m, q_c, q_v):
     """C' = Ŵ Q, Ỹ = V̂ P from padded host factors."""
-    yk = ut @ p_k + v[:-1].T @ p_m
-    c_new = c @ q_c + v.T @ q_v
+    yk = mm(ut, p_k) + mm(v[:-1].T, p_m)
+    c_new = mm(c, q_c) + mm(v.T, q_v)
     return c_new, yk
 
 
@@ -184,7 +185,7 @@ class GCRODRSolver:
         c_new, yk = _next_cu(ut, cyc.v, c_dev,
                              jnp.asarray(p[:k], dt), jnp.asarray(p_m, dt),
                              jnp.asarray(q[:k], dt), jnp.asarray(q_v, dt))
-        return c_new, yk @ jnp.asarray(np.linalg.inv(rr), dt)
+        return c_new, mm(yk, jnp.asarray(np.linalg.inv(rr), dt))
 
     def _solve_mixed(self, op: PreconditionedOp, b, x0=None):
         """fp64 iterative refinement over fp32 GCRO-DR correction solves
@@ -281,8 +282,8 @@ class GCRODRSolver:
             diag = np.abs(np.diag(rr_np))
             if diag.min() > 1e-12 * max(diag.max(), 1e-300):
                 c_dev = q
-                u_dev = u_old @ jnp.asarray(
-                    np.linalg.inv(rr_np))                    # U R⁻¹
+                u_dev = mm(u_old, jnp.asarray(
+                    np.linalg.inv(rr_np)))                   # U R⁻¹
                 z, r, rn = _warm_start(u_dev, c_dev, z, r)
                 rnorm = float(rn)
                 stats.host_syncs += 1
@@ -345,7 +346,7 @@ class GCRODRSolver:
                             c_dev, yk = _fresh_cu(cyc.v, cyc.h,
                                                   jnp.asarray(p_pad),
                                                   jnp.asarray(q_pad))
-                            u_dev = yk @ jnp.asarray(np.linalg.inv(rr), dt)
+                            u_dev = mm(yk, jnp.asarray(np.linalg.inv(rr), dt))
                 if hist is not None:
                     hist.append(rnorm)
                     dims.append(k if c_dev is not None else 0)

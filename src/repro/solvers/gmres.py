@@ -19,6 +19,7 @@ import numpy as np
 
 from repro import obs
 from repro.kernels import ops as kops
+from repro.kernels.precision import matmul as mm
 from repro.obs.telemetry import KrylovTelemetry
 from repro.solvers.arnoldi import arnoldi_cycle
 from repro.solvers.hostlinalg import hessenberg_lstsq
@@ -40,7 +41,7 @@ def _residual_norms(op, b, z):
 def _fused_update(op, b, z, v, y):
     """z += Vᵀy (y zero-padded to the cycle width) + true residual — one
     dispatch instead of a host V copy + host matmul + residual dispatch."""
-    z = z + v[:-1].T @ y
+    z = z + mm(v[:-1].T, y)
     r = b - apply_op(op, z)
     return z, r, jnp.linalg.norm(r)
 

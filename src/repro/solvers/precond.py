@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import precision
 from repro.pde.dia import DIA, Stencil5
 
 # ---------------------------------------------------------------- pytrees
@@ -56,7 +57,8 @@ class BlockJacobiPrecond:
 
     def apply(self, v):
         nb, bs, _ = self.inv_blocks.shape
-        return jnp.einsum("bij,bj->bi", self.inv_blocks, v.reshape(nb, bs)).reshape(-1)
+        return precision.einsum("bij,bj->bi", self.inv_blocks,
+                                v.reshape(nb, bs)).reshape(-1)
 
 
 @jax.tree_util.register_pytree_node_class

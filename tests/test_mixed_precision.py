@@ -144,6 +144,60 @@ def test_batched_fp32_zero_rhs_padding_noop():
     assert sts[0].converged and sts[0].iterations > 0
 
 
+def test_batched_fp32_carry_stays_on_device_between_passes():
+    """The fp32 inner solver keeps its recycle carry on the device: a call
+    uploads it only when the public carry is not the one it stored last,
+    and fetches it once, with the iterate. Forcing the upload changes no
+    bit of the labels or of the carry."""
+    from repro import obs
+
+    fam = get_family("poisson", nx=12, ny=12)
+    batch = fam.sample_batch(jax.random.PRNGKey(6), 6)
+    coeffs = jnp.asarray(batch.op.coeffs)
+    b_all = np.asarray(batch.b).reshape(6, -1)
+    rows = []
+    for t in range(3):
+        idx = np.array([3 * w + t for w in range(2)])
+        st5 = Stencil5(coeffs).take(jnp.asarray(idx))
+        pre = make_preconditioner_batched("jacobi", st5)
+        rows.append((PreconditionedOp(StencilOp(st5.coeffs), pre),
+                     jnp.asarray(b_all[idx])))
+    carry_bytes = 2 * 144 * CFG.k * 4           # (B, n, k) fp32
+    outs = {}
+    for forced in (False, True):
+        solver = BatchedGCRODRSolver(CFG32)
+        xs, h2d, d2h = [], [], []
+        for t, (opsb, b) in enumerate(rows):
+            if forced:
+                solver._carry_mirror = None
+            obs.enable(krylov_capacity=0)
+            try:
+                x, sts = solver.solve_batch(opsb, b)
+                c = obs.summary()["counters"]
+            finally:
+                obs.disable()
+            assert all(s.converged for s in sts), (forced, t)
+            h2d.append(c["hostlink.h2d_bytes"])
+            d2h.append(c["hostlink.d2h_bytes"])
+            if t:
+                assert max(s.outer_refinements for s in sts) >= 2
+            assert isinstance(solver._inner.u_carry, jax.Array)
+            assert solver.u_carry.dtype == np.float32
+            assert solver.u_carry.flags.writeable
+            xs.append(x)
+        outs[forced] = (np.concatenate(xs), solver.u_carry.copy())
+        if forced:
+            assert h2d[2] - h2d_kept[2] == carry_bytes
+            assert d2h == d2h_kept
+        else:
+            h2d_kept, d2h_kept = h2d, d2h
+            # the first call has no carry to send; each later call fetches
+            # it once and never sends it back
+            assert min(d2h) >= carry_bytes
+    np.testing.assert_array_equal(outs[False][0], outs[True][0])
+    np.testing.assert_array_equal(outs[False][1], outs[True][1])
+
+
 # ------------------------------------------------------ stagnation fallback
 
 def _near_resonant_helmholtz(nx=12, kappa=1e8):
@@ -179,6 +233,42 @@ def test_fp32_stagnation_falls_back_to_fp64():
 
 
 # ------------------------------------------------- fp64-default regression
+
+def test_batched_fp32_stall_on_a_recycled_space_retries_cold():
+    """Lockstep mixed engine: a chain whose fp32 pass fails to halve its
+    residual while it started from a recycled space (here one whose columns
+    are nearly parallel) gets one more fp32 pass with its carry dropped,
+    instead of sending the whole batch to fp64; every chain still meets
+    tol by its true fp64 residual and the chain owns a fresh space after."""
+    cfg = KrylovConfig(m=30, k=10, tol=1e-8, maxiter=20_000,
+                       inner_dtype="float32")
+    fam = get_family("poisson", nx=12, ny=12)
+    batch = fam.sample_batch(jax.random.PRNGKey(2), 4)
+    coeffs = jnp.asarray(batch.op.coeffs)
+    b_all = np.asarray(batch.b).reshape(4, -1)
+
+    def row(idx):
+        st5 = Stencil5(coeffs).take(jnp.asarray(idx))
+        pre = make_preconditioner_batched("jacobi", st5)
+        return (PreconditionedOp(StencilOp(st5.coeffs), pre),
+                jnp.asarray(b_all[idx]))
+
+    solver = BatchedGCRODRSolver(cfg)
+    solver.solve_batch(*row([0, 1]))
+    assert solver.carry_ok.all()
+    u = np.array(solver.u_carry)
+    noise = np.random.default_rng(0).standard_normal((u.shape[1], cfg.k - 1))
+    u[0][:, 1:] = u[0][:, :1] + 1e-6 * noise
+    solver.u_carry = u
+    x, sts = solver.solve_batch(*row([2, 3]))
+    assert not any(s.fp64_fallback for s in sts)
+    assert sts[0].outer_refinements == sts[1].outer_refinements + 1
+    assert solver.carry_ok.all()
+    for w, i in enumerate([2, 3]):
+        a = Stencil5(coeffs[i]).to_dense()
+        res = np.linalg.norm(b_all[i] - a @ x[w]) / np.linalg.norm(b_all[i])
+        assert sts[w].converged and res <= cfg.tol * 1.01, (w, res)
+
 
 def test_fp64_default_path_bitwise_identical():
     """inner_dtype="float64" (and the default) must take the historical
@@ -334,3 +424,170 @@ def test_chunked_datagen_fp32_inner_labels_match():
             rel = (np.linalg.norm(cm.solutions[pos] - cb.solutions[pos])
                    / max(np.linalg.norm(cb.solutions[pos]), 1e-300))
             assert rel <= 1e-6, (pos, rel)
+
+
+# ----------------------------------------------- fp32 products on a TPU
+
+def _lockstep_programs(dtype, bsz=4, nx=16, m=8, k=3):
+    """The jaxprs of the lockstep solver's programs (entry, fresh and
+    deflated cycles, both refreshes) in `dtype`: the kernel path for fp32,
+    as a kernel solver's fp32 passes run; jnp for fp64."""
+    from repro.solvers import batched as bt
+    from repro.solvers.precond import JacobiPrecond
+
+    n, fp32 = nx * nx, dtype == jnp.float32
+
+    def sds(*shape, dt=dtype):
+        return jax.ShapeDtypeStruct(shape, dt)
+
+    ops_ = PreconditionedOp(StencilOp(sds(bsz, 5, nx, nx), use_kernel=fp32),
+                            JacobiPrecond(sds(bsz, n)))
+    mask = sds(bsz, dt=bool)
+    eargs = (ops_, sds(bsz, n), sds(bsz, n), sds(bsz, n, k), sds(bsz, n, k),
+             sds(bsz, n, k), mask, mask, sds(), sds(dt=jnp.int32), sds())
+    ekw = dict(k=k, use_carry=True, pad_given=True, stall_break=fp32)
+    kw = dict(k=k, orthog="cgs2", use_kernel=fp32, h_acc="native",
+              stall_break=fp32)
+    fkw, dkw = dict(kw, m=m, can_grow=False), dict(kw, mi=m - k)
+    s, aux, _ = jax.eval_shape(lambda *a: bt._entry(*a, **ekw), *eargs)
+    sf, _, pf = jax.eval_shape(lambda *a: bt._fresh_cycle(*a, **fkw),
+                               ops_, s, aux)
+    sd, _, pd = jax.eval_shape(lambda *a: bt._deflated_cycle(*a, **dkw),
+                               ops_, s, aux)
+    jx = jax.make_jaxpr
+    return {
+        "entry": jx(lambda *a: bt._entry(*a, **ekw))(*eargs),
+        "fresh": jx(lambda *a: bt._fresh_cycle(*a, **fkw))(ops_, s, aux),
+        "deflated": jx(lambda *a: bt._deflated_cycle(*a, **dkw))(ops_, s,
+                                                                  aux),
+        "fresh_refresh": jx(lambda *a: bt._fresh_refresh(*a, k=k))(
+            sf, pf["v"], pf["h"], sds(bsz, m, k), sds(bsz, m + 1, k),
+            sds(bsz, k, k), mask),
+        "deflated_refresh": jx(lambda *a: bt._deflated_refresh(*a, k=k))(
+            sd, pd["g"], pd["ut"], pd["v"], pd["step"], sds(bsz, m, k),
+            mask),
+    }
+
+
+def _dots(jaxpr):
+    """(operand dtype, precision) of every dot_general in a jaxpr and the
+    jaxprs nested in it."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            out.append((eqn.invars[0].aval.dtype, eqn.params["precision"]))
+        for p in eqn.params.values():
+            for q in (p if isinstance(p, (tuple, list)) else (p,)):
+                if isinstance(q, ClosedJaxpr):
+                    out += _dots(q.jaxpr)
+                elif isinstance(q, Jaxpr):
+                    out += _dots(q)
+    return out
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64],
+                         ids=["fp32", "fp64"])
+def test_lockstep_products_run_at_their_precision(dtype):
+    """Every fp32 product of the fp32 lockstep programs asks for HIGHEST:
+    at the TPU's default an fp32 dot is one bf16 pass, and the fp32
+    correction passes then fail to halve the fp64 residual on the chip.
+    The fp64 programs keep the default (no precision) on every product."""
+    highest = jax.lax.Precision.HIGHEST
+    for name, jaxpr in _lockstep_programs(dtype).items():
+        dots = [p for dt, p in _dots(jaxpr.jaxpr) if dt == dtype]
+        assert dots, name
+        for prec in dots:
+            if dtype == jnp.float32:
+                assert prec is not None and all(
+                    q == highest for q in (prec if isinstance(prec, tuple)
+                                           else (prec,))), (name, prec)
+            else:
+                assert prec is None, (name, prec)
+
+
+def test_fp32_lockstep_pass_stops_three_cycles_after_its_best():
+    """A stall-breaking fp32 lockstep solve asked for a tolerance below
+    fp32's round-off floor stops each chain at its first run of 3 cycles
+    that leave its best residual within 1 %: a cycle that only wins back a
+    rise at the floor does not restart the count (per-cycle residuals from
+    the device telemetry)."""
+    from repro import obs
+    from repro.pde.dia import Stencil5
+
+    bsz = 4
+    batch = get_family("darcy", nx=32, ny=32).sample_batch(
+        jax.random.PRNGKey(3), bsz)
+    st5 = Stencil5(jnp.asarray(batch.op.coeffs))
+    op = cast_operator(PreconditionedOp(
+        StencilOp(st5.coeffs), make_preconditioner_batched("jacobi", st5)),
+        jnp.float32)
+    b = jnp.asarray(np.asarray(batch.b).reshape(bsz, -1), jnp.float32)
+    cfg = KrylovConfig(m=20, k=5, tol=1e-9, maxiter=5000)
+    obs.enable(krylov_capacity=256)
+    try:
+        _, stats = BatchedGCRODRSolver(cfg, stall_break=True).solve_batch(
+            op, b)
+    finally:
+        obs.disable()
+    for st, best in zip(stats, np.linalg.norm(np.asarray(b), axis=1)):
+        assert st.breakdown
+        run = 0
+        for cycle, res in enumerate(st.telemetry.res_hist, start=1):
+            run = run + 1 if res > 0.99 * best else 0
+            best = min(best, res)
+            if run == 3:
+                break
+        assert st.cycles == cycle, (st.cycles, cycle)
+
+
+def test_darcy128_f32k_settings_match_the_plain_reference():
+    """The benchmark's darcy-128-f32k settings at 32 x 32 on 8 chains
+    (kernels interpreted), through the pipeline: every label meets tol by
+    the plain reference's true residual and agrees with its fp64 direct
+    solve; every refinement pass ran in fp32, as the counters say."""
+    import json
+    import os
+
+    from bench import reference
+    from repro import obs
+    from repro.core import pipeline
+    from repro.core.skr import SKRConfig, SteadyWork
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "bench", "configs",
+                           "darcy-128-f32k.json")) as f:
+        conf = json.load(f)
+    params = dict(conf["family_params"], nx=32, ny=32)
+    fam = get_family(conf["family"], **params)
+    cfg = SKRConfig(krylov=KrylovConfig(**conf["krylov"]),
+                    sort_method=conf["sort_method"], precond=conf["precond"],
+                    use_kernel=conf["use_kernel"], strict_labels="flag")
+    obs.enable(krylov_capacity=0)
+    try:
+        chunks = pipeline.run_chunked(SteadyWork(fam, cfg),
+                                      jax.random.PRNGKey(15), 16, 8,
+                                      "batched")
+        counters = obs.registry().snapshot()["counters"]
+    finally:
+        obs.disable()
+    k_field = np.concatenate([c.inputs for c in chunks])
+    labels = np.concatenate([c.solutions for c in chunks])
+    a, b = reference.darcy_system(k_field, params["source"])
+    res = reference.stencil_residual(a, labels, b)
+    assert res.max() <= conf["label_residual_limit"], res.max()
+    direct = reference.solve(a, b, np.float64)
+    rel = (np.linalg.norm((labels - direct).reshape(len(labels), -1), axis=1)
+           / np.linalg.norm(direct.reshape(len(direct), -1), axis=1))
+    assert rel.max() <= 1e-6, rel.max()
+
+    solved = [c.stats.solved for c in chunks]
+    assert not any(s.fp64_fallback for st in solved for s in st)
+    assert counters.get("mixed.passes_fp64", 0.0) == 0.0
+    rows = max(len(st) for st in solved)
+    assert counters["mixed.dispatches"] == rows
+    # a pass runs while any chain of the row still needs one
+    assert counters["mixed.passes_fp32"] == sum(
+        max(st[t].outer_refinements for st in solved if t < len(st))
+        for t in range(rows))

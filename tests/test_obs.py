@@ -260,7 +260,8 @@ def _programs(k=4):
     args = (ops, b, z0, c0, u0, u0, jnp.ones(bsz, bool),
             jnp.zeros(bsz, bool), jnp.asarray(1e-8),
             jnp.asarray(np.int32(100)), jnp.asarray(0.0))
-    entry_kw = dict(k=k, use_carry=True, pad_given=True, contain=True)
+    entry_kw = dict(k=k, use_carry=True, pad_given=True, contain=True,
+                    stall_break=True)
     s, aux, _ = bt._entry(*args, **entry_kw)
     cyc_kw = dict(k=k, orthog="cgs2", use_kernel=False, h_acc="native",
                   stall_break=True, contain=True)

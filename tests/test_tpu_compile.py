@@ -141,31 +141,43 @@ def test_fp64_solver_request_on_tpu_raises(field, monkeypatch):
         solve_gmres(None, jnp.ones((4, 4)), cfg, use_kernel=True)
 
 
-def _fp64_cycle_shapes(bsz, nx=64, m=40, k=15):
-    """Abstract operators, state and aux of the fp64 lockstep solver at
-    darcy-64-f64's shapes (n = nx², GCRO-DR m 40, k 15) for `bsz` chains."""
-    from repro.solvers import batched as bt
+def _entry_args(bsz, nx=64, k=15, dt=jnp.float64, use_kernel=False):
+    """Abstract arguments of the lockstep solver's `_entry` for `bsz`
+    chains at n = nx² in `dt` (a kernel solver's operator when
+    `use_kernel`)."""
     from repro.solvers.operator import PreconditionedOp, StencilOp
     from repro.solvers.precond import JacobiPrecond
 
-    f8, n = jnp.float64, nx * nx
+    n = nx * nx
 
-    def sds(*shape, dt=f8):
-        return jax.ShapeDtypeStruct(shape, dt)
+    def sds(*shape, d=dt):
+        return jax.ShapeDtypeStruct(shape, d)
 
-    ops = PreconditionedOp(StencilOp(sds(bsz, 5, nx, nx)),
+    ops = PreconditionedOp(StencilOp(sds(bsz, 5, nx, nx),
+                                     use_kernel=use_kernel),
                            JacobiPrecond(sds(bsz, n)))
-    vec, basis, mask = sds(bsz, n), sds(bsz, n, k), sds(bsz, dt=bool)
-    args = (ops, vec, vec, basis, basis, basis, mask, mask, sds(),
-            sds(dt=jnp.int32), sds())
+    vec, basis, mask = sds(bsz, n), sds(bsz, n, k), sds(bsz, d=bool)
+    return (ops, vec, vec, basis, basis, basis, mask, mask, sds(),
+            sds(d=jnp.int32), sds())
+
+
+def _cycle_shapes(bsz, nx=64, m=40, k=15, dt=jnp.float64, use_kernel=False,
+                  stall_break=False):
+    """Abstract operators, state and aux of the lockstep solver at n = nx²
+    (GCRO-DR m 40, k 15; darcy-64-f64's shapes by default) for `bsz`
+    chains, fp64 on jnp unless told otherwise."""
+    from repro.solvers import batched as bt
+
+    args = _entry_args(bsz, nx, k, dt, use_kernel)
     s, aux, _ = jax.eval_shape(lambda *a: bt._entry(
-        *a, k=k, use_carry=True, pad_given=True), *args)
-    return ops, s, aux
+        *a, k=k, use_carry=True, pad_given=True, stall_break=stall_break),
+        *args)
+    return args[0], s, aux
 
 
-def _cycle_kw(name, m=40, k=15):
-    kw = dict(k=k, orthog="cgs2", use_kernel=False, h_acc="native",
-              stall_break=False)
+def _cycle_kw(name, m=40, k=15, use_kernel=False, stall_break=False):
+    kw = dict(k=k, orthog="cgs2", use_kernel=use_kernel, h_acc="native",
+              stall_break=stall_break)
     return (dict(kw, m=m, can_grow=False) if name == "fresh"
             else dict(kw, mi=m - k))
 
@@ -183,36 +195,105 @@ def test_fp64_host_eig_programs_compile_for_v5e(program, one_chip):
     the refresh programs that rebuild (C, U) from the host's basis, compile
     for one v5e chip at darcy-64-f64's shapes and 64 chains, and fit its
     memory."""
+    assert 0 < _bytes(_compiled(program, 64, one_chip)) < 16e9   # 16 GB
+
+
+def _compiled(program, bsz, one_chip, nx=64, dt=jnp.float64,
+              use_kernel=False):
+    """One lockstep program compiled for a described v5e at `bsz`
+    chains."""
     from repro.solvers import batched as bt
 
-    bsz = 64
     def place(tree):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, x.dtype, sharding=one_chip), tree)
 
+    if program == "entry":
+        lowered = bt._entry.lower(
+            *place(_entry_args(bsz, nx, dt=dt, use_kernel=use_kernel)),
+            k=15, use_carry=True, pad_given=True,
+            stall_break=dt == jnp.float32)
+        return lowered.compile()
     name = program.split("_")[0]
-    fn, kw = _cycle_fn(name), _cycle_kw(name)
-    ops, s, aux = _fp64_cycle_shapes(bsz)
+    # the refinement driver's fp32 solver breaks off a stalled pass; its
+    # fp64 fallback solver does not
+    brk = dt == jnp.float32
+    fn, kw = _cycle_fn(name), _cycle_kw(name, use_kernel=use_kernel,
+                                        stall_break=brk)
+    ops, s, aux = _cycle_shapes(bsz, nx, dt=dt, use_kernel=use_kernel,
+                                stall_break=brk)
     if program == name:
-        lowered = fn.lower(place(ops), place(s), place(aux), **kw)
+        return fn.lower(place(ops), place(s), place(aux), **kw).compile()
+    s, _, pend = jax.eval_shape(lambda *a: fn(*a, **kw), ops, s, aux)
+    k, width = kw["k"], kw.get("m", kw.get("mi", 0) + kw["k"])
+    p = jax.ShapeDtypeStruct((bsz, width, k), dt)
+    mask = jax.ShapeDtypeStruct((bsz,), bool)
+    if name == "fresh":
+        q = jax.ShapeDtypeStruct((bsz, width + 1, k), dt)
+        inv = jax.ShapeDtypeStruct((bsz, k, k), dt)
+        lowered = bt._fresh_refresh.lower(
+            *place((s, pend["v"], pend["h"], p, q, inv, mask)), k=k)
     else:
-        s, _, pend = jax.eval_shape(lambda *a: fn(*a, **kw), ops, s, aux)
-        k, width = kw["k"], kw.get("m", kw.get("mi", 0) + kw["k"])
-        p = jax.ShapeDtypeStruct((bsz, width, k), jnp.float64)
-        mask = jax.ShapeDtypeStruct((bsz,), bool)
-        if name == "fresh":
-            q = jax.ShapeDtypeStruct((bsz, width + 1, k), jnp.float64)
-            inv = jax.ShapeDtypeStruct((bsz, k, k), jnp.float64)
-            lowered = bt._fresh_refresh.lower(
-                *place((s, pend["v"], pend["h"], p, q, inv, mask)), k=k)
-        else:
-            lowered = bt._deflated_refresh.lower(
-                *place((s, pend["g"], pend["ut"], pend["v"], pend["step"],
-                        p, mask)), k=k)
-    mem = lowered.compile().memory_analysis()
-    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+        lowered = bt._deflated_refresh.lower(
+            *place((s, pend["g"], pend["ut"], pend["v"], pend["step"],
+                    p, mask)), k=k)
+    return lowered.compile()
+
+
+def _bytes(compiled):
+    """A compiled program's argument, output and temporary bytes."""
+    mem = compiled.memory_analysis()
+    return (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
-    assert 0 < used < 16e9          # a v5e chip holds 16 GB
+
+
+@pytest.mark.parametrize("program", [
+    "f32-entry", "f32-fresh", "f32-deflated", "f32-fresh_refresh",
+    "f32-deflated_refresh", "f64-entry", "f64-deflated",
+    "f64-fresh_refresh", "f64-deflated_refresh"])
+def test_128_programs_compile_for_v5e_at_64_chains(program, one_chip,
+                                                   monkeypatch):
+    """darcy-128-f32k's programs at 128² and 64 chains compile for one v5e
+    chip and fit its memory: the fp32 passes' programs on the kernels, and
+    the fp64 programs of the refinement driver's fallback passes (a kernel
+    solver's fp64 cycles run on jnp). The Arnoldi sweep writes its new
+    basis row by a select: a write at each chain's own step is a scatter
+    over chains, which the fp64 cycles cannot fit in the 16 MiB of scoped
+    VMEM at this size. (The fp64 fresh cycle compiles too, but needs about
+    19.7 GB at 64 chains: PERF.md, open questions.) In the cycles, the
+    fused kernel's operands sit where the benchmark's cost model
+    (bench/cost.py) counts them: the basis and the recycle rows in HBM,
+    the rest in VMEM."""
+    dtype, name = program.split("-")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    f32 = dtype == "f32"
+    compiled = _compiled(name, 64, one_chip, nx=128,
+                         dt=jnp.float32 if f32 else jnp.float64,
+                         use_kernel=True)
+    assert 0 < _bytes(compiled) < 16e9
+    if f32 and name in ("fresh", "deflated"):
+        from bench import cost
+
+        k = 15 if name == "deflated" else 0
+        want = [where for _, _, _, where
+                in cost.blocks(41 - k, k, 128, 128, cost.row_block(128))]
+        got = _kernel_operands_in_hbm(compiled.as_text())
+        assert got == want[:len(got)] and len(got) == 10, got
+
+
+def _kernel_operands_in_hbm(hlo):
+    """Per operand of the fused arnoldi_step call in compiled HLO text:
+    True where its layout lacks the VMEM memory space S(1)."""
+    import re
+
+    call = re.search(r"%arnoldi_step[.\d]* = .*? custom-call\((.*?)\), "
+                     r"custom_call_target", hlo)
+    out = []
+    for operand in call.group(1).split(","):
+        name = operand.split("*/")[-1].strip()
+        shape = re.search(re.escape(name) + r" = (\S+) ", hlo).group(1)
+        out.append("S(1)" not in shape)
+    return out
 
 
 @pytest.mark.parametrize("name", ["fresh", "deflated"])
@@ -226,7 +307,7 @@ def test_fp64_host_eig_cycles_drop_the_sweep_loop(name):
     from repro.solvers.arnoldi import _arnoldi_cycle_impl
 
     bsz = 8
-    ops, s, aux = _fp64_cycle_shapes(bsz)
+    ops, s, aux = _cycle_shapes(bsz)
     fn, kw = _cycle_fn(name), _cycle_kw(name)
 
     def loops(f, *args):
