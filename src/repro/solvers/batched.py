@@ -905,14 +905,20 @@ class BatchedGCRODRSolver:
         t0 = time.perf_counter()
         if fresh:
             p, ok = hl.ritz_first_cycle_padded(small["a"], small["j"], k)
+            blocks = hl.last_blocks()
             q, inv_rr, ok = hl.refresh_factors_stacked(small["h"] @ p, ok)
             est_new = ok & small["can"]
             ups = (p.astype(dt), q.astype(dt), inv_rr.astype(dt), est_new)
         else:
             p, ok = hl.ritz_deflated_padded(small["mm"], small["j"], k)
+            blocks = hl.last_blocks()
             ups = (p.astype(dt), ok)
         live = small["j"] > 0
         obs.counter_add("ritz.host_s", time.perf_counter() - t0)
+        # blocks: the chain blocks the eigensolve ran in (1 inline)
+        obs.counter_add("ritz.host_calls")
+        obs.counter_add("ritz.host_blocks", blocks)
+        obs.counter_add("ritz.host_parallel_calls", float(blocks > 1))
         obs.counter_add("ritz.host_chains", int(live.sum()))
         obs.counter_add("ritz.host_gated", int((live & ~ok).sum()))
         obs.hostlink("h2d", ups)

@@ -329,3 +329,69 @@ def test_host_refresh_factors_match_the_device():
     np.testing.assert_array_equal(q[1:], 0.0)
     np.testing.assert_array_equal(inv[1:], np.broadcast_to(np.eye(4),
                                                            (2, 4, 4)))
+
+
+# ------------------------------------------- blocked host eigensolves
+
+_MARK = 7.25          # a pencil holding it at [0, 0] makes LAPACK "fail"
+
+
+def _host_solves(a, j_first, mm, j_defl, gp, k):
+    p1, ok1 = hl.ritz_first_cycle_padded(a, j_first, k)
+    p2, ok2 = hl.ritz_deflated_padded(mm, j_defl, k)
+    q, inv, ok3 = hl.refresh_factors_stacked(gp, ok1)
+    return p1, ok1, p2, ok2, q, inv, ok3
+
+
+@pytest.mark.parametrize("eig_fails", [False, True],
+                         ids=["eig-ok", "eig-raises"])
+@pytest.mark.parametrize("bsz", [1, 8, 17, 64])
+def test_blocked_host_solves_match_the_inline_call(bsz, eig_fails,
+                                                    monkeypatch):
+    """The padded Ritz bases and the refresh factors, split into chain
+    blocks on the pool, give every chain the bits of one inline stacked
+    call: at ragged widths, with a j = 0 chain, a non-finite pencil, a
+    rank-deficient product and a pencil whose eig raises (the one-by-one
+    fallback then runs inside its block only)."""
+    rng = np.random.default_rng(53 + bsz)
+    k, m = 3, 10
+    j_first = rng.integers(k + 1, m + 1, bsz)
+    j_defl = rng.integers(1, m - k + 1, bsz)
+    a = np.zeros((bsz, m, m))
+    mm = np.zeros((bsz, m, m))
+    for i in range(bsz):
+        jf, s = int(j_first[i]), k + int(j_defl[i])
+        a[i, :jf, :jf] = rng.standard_normal((jf, jf))
+        a[i, range(jf, m), range(jf, m)] = 1e8      # the dead diagonal
+        mm[i, :s, :s] = rng.standard_normal((s, s))
+    gp = rng.standard_normal((bsz, m + 1, k))
+    if bsz > 1:
+        j_first[1] = j_defl[1] = 0                 # chain 1 took no step
+        a[-1, 0, 1] = mm[-1, 1, 0] = np.nan        # last: non-finite pencil
+        gp[0, :, 2] = gp[0, :, 1]                  # chain 0: rank deficient
+    if eig_fails:
+        bad = bsz // 2
+        j_first[bad] = m
+        a[bad, 0, 0] = mm[bad, 0, 0] = _MARK
+        real_eig = np.linalg.eig
+
+        def eig(x):
+            if (np.asarray(x)[..., 0, 0] == _MARK).any():
+                raise np.linalg.LinAlgError("no convergence")
+            return real_eig(x)
+
+        monkeypatch.setattr(np.linalg, "eig", eig)
+    monkeypatch.setattr(hl, "_cpus", lambda: 4)
+    monkeypatch.setattr(hl, "MIN_BLOCK_ROWS", 10**9)
+    inline = _host_solves(a, j_first, mm, j_defl, gp, k)
+    assert hl.last_blocks() == 1
+    monkeypatch.setattr(hl, "MIN_BLOCK_ROWS", 2)
+    blocked = _host_solves(a, j_first, mm, j_defl, gp, k)
+    assert hl.last_blocks() == (4 if bsz >= 8 else 1)
+    for got, want in zip(blocked, inline):
+        assert np.array_equal(got, want)
+    live = np.ones(bsz, bool)
+    live[[1, -1] if bsz > 1 else []] = False
+    if eig_fails:
+        live[bad] = False
+    assert np.array_equal(inline[1], live) and np.array_equal(inline[3], live)

@@ -44,8 +44,8 @@ def _obs_off():
     obs.disable()
 
 
-def _batched_ops(nx=10, chains=3, seed=11):
-    fam = get_family("poisson", nx=nx, ny=nx)
+def _batched_ops(nx=10, chains=3, seed=11, family="poisson"):
+    fam = get_family(family, nx=nx, ny=nx)
     batch = fam.sample_batch(jax.random.PRNGKey(seed), chains)
     st5 = Stencil5(jnp.asarray(batch.op.coeffs))
     pre = make_preconditioner_batched("jacobi", st5)
@@ -492,3 +492,51 @@ def test_host_eig_counters(monkeypatch):
     assert c["ritz.host_chains"] == sum(s.cycles for s in stats)
     assert c["ritz.host_gated"] == sum(gated) > 0
     assert c["ritz.host_s"] > 0
+
+
+def test_blocked_host_ritz_is_the_inline_solve(monkeypatch):
+    """A Darcy row whose host eigensolve runs in chain blocks on the pool
+    gives the iterates, carries, stats and syncs of the inline stacked
+    call; `ritz.host_calls` counts the `_host_ritz` calls and
+    `ritz.host_blocks` the blocks they used (one each inline)."""
+    import dataclasses
+
+    from repro.solvers import hostlinalg as hl
+
+    real, calls = BatchedGCRODRSolver._host_ritz, []
+
+    def spy(self, *a, **kw):
+        calls.append(1)
+        return real(self, *a, **kw)
+
+    monkeypatch.setattr(BatchedGCRODRSolver, "_host_ritz", spy)
+    monkeypatch.setattr(hl, "_cpus", lambda: 4)
+    ops, b = _batched_ops(nx=8, chains=8, family="darcy")
+    cfg = KrylovConfig(m=18, k=6, tol=1e-8, maxiter=2000)
+    runs = {}
+    for name, min_rows in (("inline", 10**9), ("blocked", 2)):
+        monkeypatch.setattr(hl, "MIN_BLOCK_ROWS", min_rows)
+        calls.clear()
+        obs.enable(krylov_capacity=0)
+        solver = BatchedGCRODRSolver(cfg)
+        xs, stats = _two_solves(solver, ops, b)
+        runs[name] = dict(
+            xs=xs, carry=np.asarray(solver.u_carry), ok=solver.carry_ok,
+            stats=[dataclasses.replace(st, wall_time_s=0.0) for st in stats],
+            c=obs.summary()["counters"], calls=len(calls))
+        obs.disable()
+    inline, blocked = runs["inline"], runs["blocked"]
+    for x_in, x_bl in zip(inline["xs"], blocked["xs"]):
+        np.testing.assert_array_equal(x_bl, x_in)
+    np.testing.assert_array_equal(blocked["carry"], inline["carry"])
+    np.testing.assert_array_equal(blocked["ok"], inline["ok"])
+    assert blocked["stats"] == inline["stats"]
+    assert all(st.converged for st in inline["stats"])
+    for run in (inline, blocked):
+        c = run["c"]
+        assert c["ritz.host_calls"] == run["calls"] > 0
+    ci, cb = inline["c"], blocked["c"]
+    assert ci["ritz.host_blocks"] == ci["ritz.host_calls"]
+    assert ci["ritz.host_parallel_calls"] == 0
+    assert cb["ritz.host_blocks"] > cb["ritz.host_calls"]
+    assert 0 < cb["ritz.host_parallel_calls"] <= cb["ritz.host_calls"]
