@@ -20,7 +20,6 @@
   streaming_datagen    online streaming scheduler: mid-flight slot refill
                        vs wave-padding baseline on Poisson traces
                        (utilization, p50/p99 latency, label parity)
-  roofline_report      §Roofline (aggregates dry-run artifacts)
 
 Each run also writes a machine-readable ``results/BENCH_<name>.json``
 artifact (name, wall time, headline metrics = whatever the bench's ``run``
@@ -48,8 +47,8 @@ import tempfile
 import time
 
 from benchmarks import (batched_solver, convergence_fig11, label_expansion,
-                        mixed_precision, parallel_e22, roofline_report,
-                        sharded_datagen, stability_fig13, streaming_datagen,
+                        mixed_precision, parallel_e22, sharded_datagen,
+                        stability_fig13, streaming_datagen,
                         table1_speedup, table2_sort_ablation,
                         table33_no_training, trajectory_recycle)
 
@@ -66,7 +65,6 @@ BENCHES = [
     ("table33_no_training", table33_no_training.run),
     ("label_expansion", label_expansion.run),
     ("streaming_datagen", streaming_datagen.run),
-    ("roofline_report", roofline_report.run),
 ]
 
 RESULTS_DIR = os.path.join(os.path.dirname(os.path.dirname(

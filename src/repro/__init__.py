@@ -1,12 +1,14 @@
-# repro — production-grade JAX framework implementing
-# "Accelerating Data Generation for Neural Operators via Krylov Subspace
-# Recycling" (SKR, ICLR 2024) as a first-class data-generation subsystem,
-# plus the full training/serving substrate (model zoo, distributed runtime,
-# fault tolerance, dry-run + roofline harness).
+# repro — a JAX implementation of "Accelerating Data Generation for Neural
+# Operators via Krylov Subspace Recycling" (SKR, ICLR 2024): a datagen system
+# (pde, core, solvers, kernels, obs) that sorts, chains and recycle-solves
+# families of PDE systems into labelled datasets, plus the neural-operator
+# consumer that trains on them (operators, train).
 #
-# Solvers require f64 on CPU for paper-parity tolerances (down to 1e-11).
-# The LM stack always passes explicit (bf16/f32) dtypes, so enabling x64
-# globally is safe and matches PETSc semantics.
+# The solvers hold residuals of record, refinement and the Krylov cycles in
+# fp64 to reach the paper's tolerances (down to 1e-11) with PETSc's
+# semantics; JAX's default would silently demote those arrays to fp32, so
+# x64 is enabled for the process. Code that wants fp32 (the fp32 inner
+# cycles, the Pallas kernels, the FNO) passes its dtype explicitly.
 import jax
 
 jax.config.update("jax_enable_x64", True)

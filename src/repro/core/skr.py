@@ -52,14 +52,17 @@ frontends. Engines (`generate_dataset_chunked(engine=...)`):
     single device it degenerates to "batched").
 
 Device-resident cycle (the lockstep engines' dispatch shape): each GCRO-DR
-cycle of the batched engine is ONE fused device program — Arnoldi sweep,
-stacked Hessenberg LS, stacked harmonic-Ritz refresh (solvers/devlinalg.py)
-and the masked per-chain control flow all run on-device; the ONLY blocking
-host sync in the loop is a 4-bool flag fetch per cycle that decides
-continuation (plus one at entry and one bulk fetch at finalize —
-`SolveStats.host_syncs` tracks the budget, asserted ≤ 2 + cycles by
-tests/test_transfer_guard.py). The sequential engine keeps the historical
-host-mediated cleanup (hostlinalg.py) as the bitwise reference.
+cycle of the batched engine is one fused device program — Arnoldi sweep,
+stacked Hessenberg LS (solvers/devlinalg.py) and the masked per-chain
+control flow — that ends at the small (k+m)² harmonic-Ritz pencils. The
+cycle's one blocking host sync fetches the continuation flags and, in the
+same `device_get`, those pencils; the host takes their invariant subspaces
+with LAPACK (hostlinalg.ritz_*_padded, on chain blocks) and a refresh
+program rebuilds the recycle space on the device. No array of length n
+crosses the host link between cycles. With one sync at entry and one bulk
+fetch at finalize the sync budget is 2 + cycles (`SolveStats.host_syncs`,
+asserted by tests/test_transfer_guard.py). The sequential engine runs the
+same eigensolve through hostlinalg.py per system.
 
 Observability (repro.obs, opt-in via `obs.enable()`): the pipeline stages
 above are telemetry tap points — `core/pipeline.py` records spans for

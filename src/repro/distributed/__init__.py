@@ -1,8 +1,5 @@
-"""Distributed runtime: mesh-aware sharding rules, logical-axis helpers,
-datagen chunk-chain sharding and gradient compression."""
-from repro.distributed.sharding import (ChainSharding, batch_axes,
-                                        datagen_mesh, logical_to_spec,
-                                        param_specs, shard_act)
+"""Sharding: datagen chunk-chain sharding and the batch-axis constraint of
+the neural-operator consumer."""
+from repro.distributed.sharding import ChainSharding, datagen_mesh, shard_act
 
-__all__ = ["ChainSharding", "batch_axes", "datagen_mesh", "logical_to_spec",
-           "param_specs", "shard_act"]
+__all__ = ["ChainSharding", "datagen_mesh", "shard_act"]

@@ -1,34 +1,18 @@
-"""Sharding rules: logical activation/parameter axes → mesh PartitionSpecs.
+"""Sharding for the datagen system and its neural-operator consumer.
 
-Two consumers live here:
+Two users live here:
 
-  * the TRAINING/SERVING stack (launch/mesh.py meshes) — logical
-    activation/parameter axes resolved against the ACTIVE abstract mesh
-    (`logical_to_spec`, `shard_act`, `param_specs`);
   * the DATAGEN pipeline (core/pipeline.py) — solver-array specs for the
     lockstep batched GCRO-DR engine, resolved against an EXPLICIT 1-D
     `data` mesh (`datagen_mesh`, `ChainSharding`): arrays with a leading
     chain axis (right-hand sides, residuals, per-chain recycle carries
-    U_k/C_k, batched operator/preconditioner leaves) shard on "dp"; the
-    small stacked eigen/LS factors are computed ON-DEVICE per cycle
-    (solvers/devlinalg.py) — they are (B, m, m)-small, chain-leading like
-    everything else, and never gathered to host between cycles.
+    U_k/C_k, batched operator/preconditioner leaves) shard on "dp";
+  * the FNO consumer (operators/fno.py) — `shard_act(x, ("dp", ...))`
+    shards the batch axis over the active mesh's "pod"/"data" axes at trace
+    time, and is a no-op without a mesh (single-device CPU runs).
 
-Mesh layout (launch/mesh.py):
-    single-pod : (data=16, model=16)
-    multi-pod  : (pod=2, data=16, model=16)
-
-Logical axes used by the model code:
-    "dp"    batch                 → ("pod","data") when a pod axis exists
-    "tp"    heads / ffn / experts / vocab → "model"
-    "fsdp"  weight-shard axis     → "data" (ZeRO-style parameter sharding)
-    "sp"    sequence (long-context KV) → "model" where chosen per-arch
-    None    replicated
-
-The model code never names raw mesh axes — it calls shard_act(x, spec) with
-logical names, resolved against the active (abstract) mesh at trace time, so
-the same model lowers on any mesh (including single-device CPU smoke tests,
-where the constraint is a no-op).
+Logical axes: "dp" (batch → ("pod", "data") where those axes exist) and
+None (replicated).
 """
 from __future__ import annotations
 
@@ -69,26 +53,9 @@ def logical_to_spec(logical: Sequence) -> Optional[P]:
             out.append(None)
         elif ax == "dp":
             out.append(batch_axes())
-        elif ax == "tp":
-            out.append("model" if "model" in names else None)
-        elif ax in ("fsdp", "sp"):
-            out.append("data" if "data" in names else None)
-        elif ax == "sq":   # sequence-parallel attention (heads don't divide
-            out.append("model" if "model" in names else None)  # the TP axis)
         else:
             raise ValueError(f"unknown logical axis {ax!r}")
     return P(*out)
-
-
-def axis_size(name: str) -> int:
-    """Size of a mesh axis under the active (abstract) mesh; 1 if absent."""
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return 1
-    if mesh is None or getattr(mesh, "empty", False):
-        return 1
-    return dict(mesh.shape).get(name, 1)
 
 
 def shard_act(x: jax.Array, logical: Sequence):
@@ -100,103 +67,6 @@ def shard_act(x: jax.Array, logical: Sequence):
         return jax.lax.with_sharding_constraint(x, spec)
     except (ValueError, RuntimeError):
         return x  # shapes not compatible with mesh (tiny smoke configs)
-
-
-# --------------------------------------------------------------------------
-# Parameter sharding rules: match on parameter-path suffixes.
-# Conventions (models/*): weights are dicts; names below are leaf key names.
-# Megatron TP + ZeRO/FSDP hybrid:
-#   column-parallel (output dim sharded over model):  wq wk wv w_up w_gate
-#   row-parallel    (input dim sharded over model):   wo w_down
-#   experts:        leading expert dim over model (EP), ffn dim over fsdp
-#   embeddings/head: vocab over model
-# --------------------------------------------------------------------------
-
-_RULES = [
-    # (suffix, spec builder: takes ndim -> logical tuple)
-    ("embed", lambda nd: ("tp", None)),
-    ("lm_head", lambda nd: (None, "tp")),
-    ("w_experts_up", lambda nd: ("tp", None, "fsdp")),
-    ("w_experts_gate", lambda nd: ("tp", None, "fsdp")),
-    ("w_experts_down", lambda nd: ("tp", "fsdp", None)),
-    ("w_router", lambda nd: (None, None)),
-    ("wq", lambda nd: ("fsdp", "tp")),
-    ("wk", lambda nd: ("fsdp", "tp")),
-    ("wv", lambda nd: ("fsdp", "tp")),
-    ("wo", lambda nd: ("tp", "fsdp")),
-    ("w_gate", lambda nd: ("fsdp", "tp")),
-    ("w_up", lambda nd: ("fsdp", "tp")),
-    ("w_down", lambda nd: ("tp", "fsdp")),
-    # MLA low-rank factors
-    ("wq_a", lambda nd: ("fsdp", None)),
-    ("wq_b", lambda nd: (None, "tp")),
-    ("wkv_a", lambda nd: ("fsdp", None)),
-    ("wkv_b", lambda nd: (None, "tp")),
-    # recurrent / conv blocks: shard the channel dim over model
-    ("w_rec_in", lambda nd: ("fsdp", "tp")),
-    ("w_rec_out", lambda nd: ("tp", "fsdp")),
-]
-
-
-def _spec_for_path(path: str, ndim: int) -> Tuple:
-    for suffix, fn in _RULES:
-        if path.endswith(suffix):
-            logical = fn(ndim)
-            if len(logical) > ndim:  # stacked-per-layer leading dim
-                logical = logical[:ndim]
-            if len(logical) < ndim:  # leading scan/stack dims replicate
-                logical = (None,) * (ndim - len(logical)) + tuple(logical)
-            return tuple(logical)
-    return (None,) * ndim  # biases, norms, small tables: replicated
-
-
-def _axis_sizes() -> dict:
-    try:
-        mesh = jax.sharding.get_abstract_mesh()
-    except Exception:
-        return {}
-    if mesh is None or getattr(mesh, "empty", False):
-        return {}
-    return dict(mesh.shape)
-
-
-def _validate_divisibility(spec: P, shape) -> P:
-    """Drop mesh-axis assignments that don't divide the dim size (e.g.
-    Whisper's 51865 vocab cannot shard over a 16-wide model axis — such
-    tables replicate; Megatron would pad, we keep configs exact)."""
-    sizes = _axis_sizes()
-    out = []
-    for i, entry in enumerate(spec):
-        if entry is None:
-            out.append(None)
-            continue
-        axes = entry if isinstance(entry, tuple) else (entry,)
-        total = 1
-        for a in axes:
-            total *= sizes.get(a, 1)
-        out.append(entry if (i < len(shape) and shape[i] % total == 0)
-                   else None)
-    return P(*out)
-
-
-def param_specs(params_shape_tree) -> "jax.tree_util.PyTreeDef":
-    """PartitionSpec pytree for a parameter (shape) tree, by path suffix.
-
-    Works on trees of ShapeDtypeStruct (jax.eval_shape output) or arrays.
-    Dims whose size doesn't divide the assigned mesh axes fall back to
-    replicated (validated against the active abstract mesh).
-    """
-
-    def visit(path, leaf):
-        keys = [getattr(p, "key", getattr(p, "name", None)) for p in path]
-        name = "/".join(str(k) for k in keys if k is not None)
-        logical = _spec_for_path(name, len(leaf.shape))
-        spec = logical_to_spec(logical)
-        if spec is None:
-            return P()
-        return _validate_divisibility(spec, tuple(leaf.shape))
-
-    return jax.tree_util.tree_map_with_path(visit, params_shape_tree)
 
 
 # --------------------------------------------------------------------------
@@ -230,9 +100,9 @@ class ChainSharding:
     (B, n), running solutions/residuals (B, n), Krylov bases (B, m+1, n),
     per-chain recycle carries U_k/C_k (B, n, k), batched operator and
     preconditioner leaves (B, ...) — shards that axis over the `data` mesh
-    axis. The stacked O(m³) eigen/LS cleanup also carries the chain axis
-    (solvers/devlinalg.py) and runs inside the same sharded dispatch; only
-    the per-cycle continuation flags cross to host.
+    axis. The stacked least-squares work also carries the chain axis and
+    runs inside the same sharded dispatch; only the per-cycle flags and the
+    small harmonic-Ritz pencils cross to the host (solvers/hostlinalg.py).
 
     Arrays whose leading dim does not divide the shard count fall back to
     replicated (the pipeline pads the chain count so the hot arrays always

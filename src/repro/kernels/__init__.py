@@ -4,7 +4,6 @@
   dia_spmv       — banded/diagonal-format SpMV (general flattened operators)
   fused_orthog   — fused CGS2 Gram-Schmidt (Arnoldi orthogonalization)
   arnoldi_step   — one fused (deflated) Arnoldi inner iteration
-  flash_attention— tiled online-softmax attention (LM prefill; beyond-paper)
 
 Each kernel: pl.pallas_call + explicit BlockSpec VMEM tiling, a jit'd
 dispatch wrapper in ops.py, and a pure-jnp oracle in ref.py. `ops.py`

@@ -1,7 +1,7 @@
 """jit'd dispatch wrappers for the Pallas kernels.
 
 Every op has a pure-jnp reference path (ref.py, the default) and a Pallas
-path (`use_kernel=True`). The solver/model layers call THESE wrappers so
+path (`use_kernel=True`). The solver layers call THESE wrappers so
 the kernel routing is a config flag, not a code change. The backend decides
 how a kernel runs, here and nowhere else (`interpret_mode`): compiled by
 Mosaic on a TPU, in the Pallas interpreter on the CPU. A caller may still
@@ -176,19 +176,3 @@ def arnoldi_step(coeffs: jax.Array, inv_diag: jax.Array, c_rows: jax.Array,
     return ref.arnoldi_step(coeffs, inv_diag, c_rows, v_basis, vin, mask,
                             acc_dtype=acc_dtype)
 
-
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, window: int | None = None,
-                    use_kernel: bool = False,
-                    interpret: bool | None = None) -> jax.Array:
-    """Chunked-softmax attention (beyond-paper LM hot spot).
-
-    q: (B, Hq, Tq, D), k/v: (B, Hkv, Tk, D) — GQA broadcast when Hq > Hkv.
-    """
-    if use_kernel:
-        from repro.kernels.flash_attention import flash_attention_pallas
-
-        return flash_attention_pallas(
-            q, k, v, causal=causal, window=window,
-            interpret=_resolve(interpret, q.dtype, k.dtype, v.dtype))
-    return ref.flash_attention(q, k, v, causal=causal, window=window)

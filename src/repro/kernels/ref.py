@@ -82,28 +82,3 @@ def arnoldi_step(coeffs: jax.Array, inv_diag: jax.Array, c_rows: jax.Array,
     w, h = fused_orthog(v_basis, w, mask, acc_dtype=acc_dtype)
     return w, h, bj
 
-
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                    causal: bool = True, window: int | None = None) -> jax.Array:
-    """Naive full-materialization attention oracle with GQA broadcast.
-
-    q: (B, Hq, Tq, D); k, v: (B, Hkv, Tk, D). Tq may be < Tk (decode), in
-    which case query position i is at absolute position Tk - Tq + i.
-    """
-    b, hq, tq, d = q.shape
-    hkv = k.shape[1]
-    group = hq // hkv
-    kq = jnp.repeat(k, group, axis=1)
-    vq = jnp.repeat(v, group, axis=1)
-    scores = jnp.einsum("bhqd,bhkd->bhqk", q, kq) / jnp.sqrt(d).astype(q.dtype)
-    tk = k.shape[2]
-    qpos = jnp.arange(tq) + (tk - tq)
-    kpos = jnp.arange(tk)
-    m = jnp.ones((tq, tk), bool)
-    if causal:
-        m &= kpos[None, :] <= qpos[:, None]
-    if window is not None:
-        m &= kpos[None, :] > qpos[:, None] - window
-    scores = jnp.where(m[None, None], scores, jnp.finfo(scores.dtype).min)
-    p = jax.nn.softmax(scores.astype(jnp.float32), axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, vq)

@@ -58,10 +58,11 @@ no Krylov information — so the leading chain axis of every large device
 array shards cleanly over a 1-D `data` mesh. Construct the solver with a
 `distributed.sharding.ChainSharding` and every lockstep dispatch runs as
 ONE SPMD program across the mesh: right-hand sides, residuals, bases,
-per-chain recycle carries AND the small per-chain eigen/LS factors live
-chain-sharded on device — nothing is gathered to host between cycles. The
-caller owns making the chain count divide the shard count
-(core/pipeline.py pads with zero-RHS chains).
+per-chain recycle carries and the small per-chain LS factors live
+chain-sharded on device; only the flags and the harmonic-Ritz pencils are
+fetched to the host between cycles, as on one device. The caller owns
+making the chain count divide the shard count (core/pipeline.py pads with
+zero-RHS chains).
 
 Precision policy: `cfg.inner_dtype="float32"` routes `solve_batch` through
 `_solve_batch_mixed` — the fp64 outer iterative-refinement loop of the

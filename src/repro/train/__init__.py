@@ -1,1 +1,2 @@
-"""Training substrate: optimizers, fault-tolerant loop, data pipeline."""
+"""Training substrate of the neural-operator consumer: optimizers, a
+fault-tolerant loop, checkpoints."""

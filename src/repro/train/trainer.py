@@ -1,18 +1,16 @@
-"""Fault-tolerant training loop (DESIGN §5).
+"""Fault-tolerant training loop for the neural operators that consume the
+datagen system's labels (operators/: FNO, DeepONet).
 
-One Trainer drives both model families (LM archs via models/api loss_fn,
-neural operators via a user loss_fn). Production behaviors:
+The caller supplies `loss_fn(params, batch)`. Behaviors:
 
   * periodic atomic checkpoints (CheckpointManager) + warm resume — a
     preempted job restarts at the last step with optimizer state intact;
   * fault injection (`fail_at`) for the restart tests;
-  * microbatch gradient accumulation with a straggler-drop threshold
-    (optim.GradAccumulator): a slow host's microbatch is dropped instead of
-    stalling the step once `threshold` of them arrived;
-  * optional error-feedback gradient compression on the (slow, cross-pod)
-    gradient reduction path (distributed/compression.py);
-  * mesh-aware: pass a mesh + donate-able shardings and the jitted step is
-    pjit-partitioned; pass mesh=None for single-device CPU runs.
+  * microbatch gradient accumulation: the batch's leading dim splits into
+    `micro_batches` equal slices whose gradients are averaged;
+  * mesh-aware: pass a mesh and the jitted step traces under it (the FNO
+    shards its batch axis with `shard_act`); pass mesh=None for
+    single-device CPU runs.
 """
 from __future__ import annotations
 
@@ -23,7 +21,6 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compression import compress_tree, init_error_tree
 from repro.train.checkpoint import CheckpointManager
 from repro.train.optim import Optimizer, adamw
 
@@ -34,16 +31,12 @@ class TrainerConfig:
     ckpt_every: int = 50
     keep: int = 3
     log_every: int = 10
-    compression: str = "none"      # none | int8 | topk
-    topk_frac: float = 0.1
     micro_batches: int = 1
-    straggler_threshold: float = 1.0
 
 
 class Trainer:
     def __init__(self, loss_fn: Callable, params, optimizer: Optimizer = None,
-                 cfg: TrainerConfig = TrainerConfig(), mesh=None,
-                 state_shardings=None):
+                 cfg: TrainerConfig = TrainerConfig(), mesh=None):
         self.loss_fn = loss_fn
         self.optimizer = optimizer or adamw(3e-4)
         self.cfg = cfg
@@ -52,12 +45,8 @@ class Trainer:
                      if cfg.ckpt_dir else None)
 
         opt_state = self.optimizer.init(params)
-        err = (init_error_tree(params)
-               if cfg.compression != "none" else None)
         self.state = {"params": params, "opt": opt_state,
                       "step": jnp.zeros((), jnp.int32)}
-        if err is not None:
-            self.state["err"] = err
         self.history: list = []
         self._step_fn = self._build_step()
 
@@ -93,10 +82,6 @@ class Trainer:
                 grads = jax.tree_util.tree_map(lambda g: g / nmicro, grads)
 
             new_state = dict(state)
-            if "err" in state:
-                grads, new_err = compress_tree(
-                    grads, state["err"], cfg.compression, cfg.topk_frac)
-                new_state["err"] = new_err
             updates, new_opt = opt.update(grads, state["opt"], params)
             new_state["params"] = jax.tree_util.tree_map(
                 lambda p, u: p + u, params, updates)

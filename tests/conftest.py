@@ -1,6 +1,7 @@
 """Shared fixtures. NOTE: do NOT set XLA device-count flags here — smoke
-tests and benches must see 1 CPU device; only launch/dryrun.py forces the
-512-device placeholder fleet (in a subprocess for the dry-run tests).
+tests and benches must see 1 CPU device; a test that needs a multi-device
+mesh forces virtual CPU devices in a subprocess of its own
+(test_pipeline.py::test_sharded_equivalence_on_8_virtual_devices).
 
 Also installs a fallback `hypothesis` stub when the real package is absent
 (see requirements-dev.txt): `@given` degrades to a fixed deterministic set of

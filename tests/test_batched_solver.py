@@ -11,7 +11,7 @@ import pytest
 from repro.core.skr import (SKRConfig, SKRGenerator, generate_dataset,
                             generate_dataset_chunked)
 from repro.pde.dia import DIA, Stencil5
-from repro.pde.registry import get_family
+from repro.pde.registry import get_family, list_families
 from repro.solvers.batched import BatchedGCRODRSolver
 from repro.solvers.gcrodr import GCRODRSolver
 from repro.solvers.gmres import gmres_solve
@@ -64,7 +64,7 @@ def _solve_batched(coeffs, b_all, subs, cfg, precond="jacobi"):
     return out
 
 
-@pytest.mark.parametrize("family", ["poisson", "darcy"])
+@pytest.mark.parametrize("family", list_families())
 def test_batched_matches_sequential_per_chain(family):
     """Acceptance: per-chain solutions agree with the existing GCRODRSolver
     to <= 1e-8 relative error, chains keep independent recycle carries."""
@@ -144,11 +144,12 @@ def test_batched_zero_rhs_is_padding_noop():
     assert stats[0].converged and stats[0].iterations > 0
 
 
-def test_chunked_engines_agree_with_padding():
+@pytest.mark.parametrize("family", list_families())
+def test_chunked_engines_agree_with_padding(family):
     """batched == sequential engine through the full datagen path, with a
     worker count that does NOT divide num (uneven chunks exercise the
     zero-RHS padding)."""
-    fam = get_family("poisson", nx=12, ny=12)
+    fam = get_family(family, nx=12, ny=12)
     cfg = SKRConfig(krylov=KC, precond="jacobi")
     key = jax.random.PRNGKey(5)
     seq = generate_dataset_chunked(fam, key, 8, cfg, workers=3,

@@ -266,61 +266,3 @@ def test_arnoldi_step_kernel_vmaps():
             np.testing.assert_allclose(np.asarray(g[i]), np.asarray(w),
                                        rtol=1e-10, atol=1e-10)
 
-
-# ----------------------------------------------------- flash attention
-
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2), (8, 1)])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_kernel_matches_ref(hq, hkv, causal):
-    key = jax.random.PRNGKey(hq * 10 + hkv)
-    b, tq, tk, d = 2, 64, 64, 32
-    q = _rand(key, (b, hq, tq, d), jnp.float32)
-    k = _rand(jax.random.fold_in(key, 1), (b, hkv, tk, d), jnp.float32)
-    v = _rand(jax.random.fold_in(key, 2), (b, hkv, tk, d), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=causal, use_kernel=True,
-                              interpret=True)
-    want = ref.flash_attention(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3,
-                               atol=2e-3)
-
-
-def test_flash_attention_window():
-    key = jax.random.PRNGKey(11)
-    b, h, t, d = 1, 2, 128, 16
-    q = _rand(key, (b, h, t, d), jnp.float32)
-    k = _rand(jax.random.fold_in(key, 1), (b, h, t, d), jnp.float32)
-    v = _rand(jax.random.fold_in(key, 2), (b, h, t, d), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=True, window=32,
-                              use_kernel=True, interpret=True)
-    want = ref.flash_attention(q, k, v, causal=True, window=32)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3,
-                               atol=2e-3)
-
-
-def test_flash_attention_decode_offset():
-    """Tq < Tk: query positions sit at the cache tail (decode semantics)."""
-    key = jax.random.PRNGKey(13)
-    b, h, d = 2, 2, 16
-    q = _rand(key, (b, h, 1, d), jnp.float32)
-    k = _rand(jax.random.fold_in(key, 1), (b, h, 96, d), jnp.float32)
-    v = _rand(jax.random.fold_in(key, 2), (b, h, 96, d), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=True, use_kernel=True,
-                              interpret=True)
-    want = ref.flash_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3,
-                               atol=2e-3)
-
-
-def test_chunked_jnp_flash_matches_ref_ragged():
-    """models/attention.flash_jnp with a non-multiple chunk (Whisper 1500)."""
-    from repro.models.attention import flash_jnp
-
-    key = jax.random.PRNGKey(17)
-    b, h, t, d = 1, 4, 300, 32
-    q = _rand(key, (b, h, t, d), jnp.float32)
-    k = _rand(jax.random.fold_in(key, 1), (b, h, t, d), jnp.float32)
-    v = _rand(jax.random.fold_in(key, 2), (b, h, t, d), jnp.float32)
-    got = flash_jnp(q, k, v, causal=False, window=None, chunk=128)
-    want = ref.flash_attention(q, k, v, causal=False)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-3,
-                               atol=2e-3)

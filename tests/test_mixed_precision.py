@@ -56,7 +56,7 @@ def test_gmres_fp32_inner_reaches_fp64_tolerance(family):
                                atol=1e-7)
 
 
-@pytest.mark.parametrize("family", ["poisson", "helmholtz"])
+@pytest.mark.parametrize("family", list_families())
 def test_gcrodr_fp32_inner_sequence_parity(family):
     """Recycling chain under the mixed policy: every system of a sequence
     converges to the fp64 tolerance and the carry is STORED fp32."""
@@ -94,10 +94,11 @@ def test_timedep_fp32_inner_trajectory_parity(family):
     np.testing.assert_allclose(t32, t64, atol=1e-6 * scale)
 
 
-def test_batched_fp32_inner_matches_fp64_lockstep():
+@pytest.mark.parametrize("family", list_families())
+def test_batched_fp32_inner_matches_fp64_lockstep(family):
     """Lockstep mixed engine: per-chain solutions agree with the fp64
     lockstep engine to solver tolerance; per-chain carries stored fp32."""
-    fam = get_family("poisson", nx=12, ny=12)
+    fam = get_family(family, nx=12, ny=12)
     batch = fam.sample_batch(jax.random.PRNGKey(3), 4)
     coeffs = jnp.asarray(batch.op.coeffs)
     b_all = np.asarray(batch.b).reshape(4, -1)

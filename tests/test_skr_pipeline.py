@@ -9,38 +9,53 @@ import pytest
 from repro.core.skr import (SKRConfig, SKRGenerator, generate_dataset,
                             generate_dataset_baseline,
                             generate_dataset_chunked)
-from repro.pde.registry import get_family
+from repro.pde.registry import get_family, list_families
 from repro.solvers.types import KrylovConfig
 
 KC = KrylovConfig(m=30, k=10, tol=1e-8, maxiter=6000)
 CFG = SKRConfig(krylov=KC, precond="jacobi")
 
 
-def test_datagen_produces_valid_dataset():
-    fam = get_family("poisson", nx=16, ny=16)
-    res = generate_dataset(fam, jax.random.PRNGKey(0), 6, CFG)
-    assert res.inputs.shape == (6, 16, 16)
-    assert res.solutions.shape == (6, 16, 16)
-    assert np.isfinite(res.solutions).all()
-    assert sorted(res.order.tolist()) == list(range(6))
-    assert all(s.converged for s in res.stats.per_system)
+@pytest.mark.parametrize("inner_dtype", ["float64", "float32"])
+@pytest.mark.parametrize("engine", ["sequential", "batched"])
+@pytest.mark.parametrize("family", list_families())
+def test_datagen_produces_valid_dataset(family, engine, inner_dtype):
+    """Every family through the chunked pipeline, on both engines and both
+    inner precisions: every label solves its system to tol in fp64."""
+    fam = get_family(family, nx=16, ny=16)
+    cfg = dataclasses.replace(
+        CFG, krylov=dataclasses.replace(KC, inner_dtype=inner_dtype))
+    chunks = generate_dataset_chunked(fam, jax.random.PRNGKey(0), 6, cfg,
+                                      workers=2, engine=engine)
+    assert len(chunks) == 2
+    order = np.concatenate([ch.order for ch in chunks])
+    assert sorted(order.tolist()) == list(range(6))
     # every solution actually solves its system
     batch = fam.sample_batch(jax.random.PRNGKey(0), 6)
     from repro.core.skr import _problem_op_of
 
-    for i in range(6):
-        a = _problem_op_of(batch, i).to_dense()
-        b = np.asarray(batch.b[i], dtype=np.float64).reshape(-1)
-        r = np.linalg.norm(b - a @ res.solutions[i].reshape(-1))
-        assert r <= KC.tol * np.linalg.norm(b) * 1.1
+    for ch in chunks:
+        n = len(ch.order)
+        assert ch.inputs.shape == (n, 16, 16)
+        assert ch.solutions.shape == (n, 16, 16)
+        assert np.isfinite(ch.solutions).all()
+        assert all(s.converged for s in ch.stats.per_system)
+        for pos, i in enumerate(ch.order.tolist()):
+            a = _problem_op_of(batch, i).to_dense()
+            b = np.asarray(batch.b[i], dtype=np.float64).reshape(-1)
+            r = np.linalg.norm(b - a @ ch.solutions[pos].reshape(-1))
+            assert r <= KC.tol * np.linalg.norm(b) * 1.1, (i, r)
 
 
-def test_solutions_independent_of_solve_order():
+@pytest.mark.parametrize("sort_method",
+                         ["greedy", "grouped", "hilbert", "random", "none"])
+def test_solutions_independent_of_solve_order(sort_method):
     """SKR (sorted) and GMRES (unsorted) datasets agree: sorting only
     reorders the WORK, never the (input → solution) pairing (App. E.3)."""
     fam = get_family("darcy", nx=12, ny=12)
     key = jax.random.PRNGKey(2)
-    skr = generate_dataset(fam, key, 8, CFG)
+    skr = generate_dataset(fam, key, 8,
+                           dataclasses.replace(CFG, sort_method=sort_method))
     gm = generate_dataset_baseline(fam, key, 8, KC, precond="jacobi")
     np.testing.assert_allclose(skr.solutions, gm.solutions, rtol=1e-5,
                                atol=1e-7)

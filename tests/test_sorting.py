@@ -54,12 +54,20 @@ def test_greedy_beats_random_ordering():
 @given(st.integers(1, 60), st.integers(1, 8), st.integers(0, 10_000))
 @settings(max_examples=25, deadline=None)
 def test_property_sort_permutation_and_improvement(n, f, seed):
+    """What Algorithm 1 guarantees: a permutation that starts at `start`,
+    each step to a nearest unvisited point. (The chain need not be shorter
+    than the identity order: n=4 f=5 seed=2826 is a counterexample.)"""
     feats = np.random.default_rng(seed).standard_normal((n, f))
-    order = greedy_sort(feats)
+    start = seed % n
+    order = greedy_sort(feats, start=start)
     assert sorted(order.tolist()) == list(range(n))
-    if n > 2:
-        assert (chain_length(feats, order)
-                <= chain_length(feats, np.arange(n)) + 1e-9)
+    assert order[0] == start
+    # squared distances by differences; the sort's expanded form rounds at
+    # ~1e-16 of the squared norms, far inside the 1e-9 relative slack
+    slack = 1e-9 * (1.0 + np.max(np.sum(feats**2, axis=1)))
+    for i in range(n - 1):
+        d2 = np.sum((feats[order[i + 1:]] - feats[order[i]])**2, axis=1)
+        assert d2[0] <= d2.min() + slack, (i, d2[0], d2.min())
 
 
 @given(st.integers(2, 8))

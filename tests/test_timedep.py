@@ -95,34 +95,44 @@ def test_theta_scheme_order_of_accuracy(theta, expected_order):
 
 # ----------------------------------------------------- dataset + step validity
 
+@pytest.mark.parametrize("inner_dtype", ["float64", "float32"])
+@pytest.mark.parametrize("engine", ["sequential", "batched"])
 @pytest.mark.parametrize("name", ["heat", "convdiff-t"])
-def test_trajectories_solve_their_step_systems(name):
+def test_trajectories_solve_their_step_systems(name, engine, inner_dtype):
     """Every emitted field actually satisfies its implicit-step linear
     system to solver tolerance (the trajectory analogue of the SKR
-    dataset-validity test)."""
+    dataset-validity test), on both engines and both inner precisions."""
     fam = get_timedep_family(name, nx=10, ny=10, nt=3)
-    res = generate_trajectories(fam, jax.random.PRNGKey(0), 3, CFG)
-    assert res.trajectories.shape == (3, 4, 10, 10)
-    assert np.isfinite(res.trajectories).all()
-    assert res.stats.num_converged == res.stats.num == 9
-    assert sorted(res.order.tolist()) == [0, 1, 2]
+    cfg = dataclasses.replace(
+        CFG, krylov=dataclasses.replace(KC, inner_dtype=inner_dtype))
+    chunks = generate_trajectories_chunked(fam, jax.random.PRNGKey(0), 3,
+                                           cfg, workers=2, engine=engine)
+    assert len(chunks) == 2
+    order = np.concatenate([ch.order for ch in chunks])
+    assert sorted(order.tolist()) == [0, 1, 2]
+    assert sum(ch.stats.num_converged for ch in chunks) == 9
+    assert sum(ch.stats.num for ch in chunks) == 9
 
     specs = fam.sample_specs(jax.random.PRNGKey(0), 3)
     step1 = fam.step_fn()
-    for i in range(3):
-        lat = jax.tree_util.tree_map(lambda a: a[i], specs.latent)
-        np.testing.assert_array_equal(res.trajectories[i, 0],
-                                      np.asarray(specs.u0[i]))
-        for s in range(fam.nt):
-            u_prev = jnp.asarray(res.trajectories[i, s])
-            a, b = step1(lat, u_prev, s * fam.dt, (s + 1) * fam.dt)
-            r = np.asarray(b) - np.asarray(
-                stencil5_matvec(a, jnp.asarray(res.trajectories[i, s + 1])))
-            assert (np.linalg.norm(r)
-                    <= KC.tol * np.linalg.norm(np.asarray(b)) * 1.1), (i, s)
+    for ch in chunks:
+        assert ch.trajectories.shape == (len(ch.order), 4, 10, 10)
+        assert np.isfinite(ch.trajectories).all()
+        for pos, i in enumerate(ch.order.tolist()):
+            traj = ch.trajectories[pos]
+            lat = jax.tree_util.tree_map(lambda a: a[i], specs.latent)
+            np.testing.assert_array_equal(traj[0], np.asarray(specs.u0[i]))
+            for s in range(fam.nt):
+                a, b = step1(lat, jnp.asarray(traj[s]), s * fam.dt,
+                             (s + 1) * fam.dt)
+                r = np.asarray(b) - np.asarray(
+                    stencil5_matvec(a, jnp.asarray(traj[s + 1])))
+                assert (np.linalg.norm(r)
+                        <= KC.tol * np.linalg.norm(np.asarray(b)) * 1.1), \
+                    (i, s)
 
 
-@pytest.mark.parametrize("name", ["heat", "convdiff-t"])
+@pytest.mark.parametrize("name", ["heat", "convdiff-t", "wave"])
 def test_recycled_matches_cold_start_per_step(name):
     """Recycling changes the WORK, never the solutions: per-step fields from
     the GCRO-DR carry chain match the cold-start GMRES baseline to solver
