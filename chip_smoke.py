@@ -262,9 +262,10 @@ def phase_kernels(sizes, seed):
     # preconditioned stencil operators with the kernel path on
     st = StencilOp(jnp.abs(coeffs), use_kernel=True)
     opb = PreconditionedOp(st, JacobiPrecond(inv_diag))
-    hlo = arnoldi_cycle_batched.lower(
-        opb, c_rows, w, jnp.full((bsz,), 1e-4, f32), m=kc.m - k,
-        use_kernel=True).compile().as_text()
+    cycle = jax.jit(lambda *a: arnoldi_cycle_batched(*a, m=kc.m - k,
+                                                     use_kernel=True))
+    hlo = cycle.lower(opb, c_rows, w,
+                      jnp.full((bsz,), 1e-4, f32)).compile().as_text()
     fused = "tpu_custom_call" in hlo and "arnoldi_step" in hlo
     out["cycle_program_fused_kernel"] = fused
     ok &= fused or jax.default_backend() != "tpu"

@@ -112,6 +112,16 @@ def _kernel(c5_ref, idg_ref, idg_up_ref, idg_dn_ref, vin_ref, vin_up_ref,
             b_ref[...] = cacc_s[...].astype(b_ref.dtype)
 
 
+# The kernel's scoped VMEM. XLA keeps a kernel operand in VMEM across a
+# loop when it fits beside that scope: the lockstep sweep's basis, written
+# in place (104 MiB at 64 chains and 128²), or its recycle rows C (60 MiB)
+# would then be read over VMEM, not HBM where bench/cost.py counts them.
+# 80 MiB of v5e's 128 leaves 48 MiB beside the kernel: room for the stencil
+# fields, the Jacobi diagonal and v_j, which it counts as VMEM, and for
+# neither of those two.
+VMEM_LIMIT_BYTES = 80 * 2**20
+
+
 @functools.partial(jax.jit, static_argnames=("interpret", "block_rows",
                                              "acc_dtype"))
 def arnoldi_step_pallas(coeffs: jax.Array, inv_diag: jax.Array,
@@ -122,16 +132,20 @@ def arnoldi_step_pallas(coeffs: jax.Array, inv_diag: jax.Array,
     """One fused Arnoldi inner iteration.
 
     coeffs  : (5, nx, ny) stencil fields
-    inv_diag: (n,) Jacobi inverse diagonal (pass ones for precond=None)
-    c_rows  : (k, n) deflation rows Cᴴ (k = 0 → padded to one zero row)
-    v_basis : (m+1, n) Krylov basis rows (inactive rows masked)
-    vin     : (n,) current basis vector v_j
+    inv_diag: (n,) or (nx, ny) Jacobi inverse diagonal (pass ones for
+              precond=None)
+    c_rows  : (k, n) or (k, nx, ny) deflation rows Cᴴ (k = 0 → padded to
+              one zero row)
+    v_basis : (m+1, n) or (m+1, nx, ny) Krylov basis rows (inactive rows
+              masked); in the (nx, ny) layout the kernel reads them as they
+              are, flat rows are relaid out per call
+    vin     : (n,) or (nx, ny) current basis vector v_j
     mask    : (m+1,) float {0,1} — rows 0..j active
     acc_dtype: widen ONLY the CGS2 coefficient scratch (fp32 storage / fp64
     accumulate — KrylovConfig.cgs2_acc); w, b stay in storage dtype.
 
-    Returns (w_orth (n,), hcol (m+1,), bj (k,)) — exactly the unfused
-    `precond → matvec → C-projection → fused_orthog` composition.
+    Returns (w_orth (vin's shape), hcol (m+1,), bj (k,)) — exactly the
+    unfused `precond → matvec → C-projection → fused_orthog` composition.
     """
     nx, ny = coeffs.shape[-2:]
     m1 = v_basis.shape[0]
@@ -178,6 +192,8 @@ def arnoldi_step_pallas(coeffs: jax.Array, inv_diag: jax.Array,
             pltpu.VMEM((m1, 1, 1), acc),
         ],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         name="arnoldi_step",
     )(coeffs,
       inv_diag.reshape(nx, ny), inv_diag.reshape(nx, ny),
@@ -185,4 +201,4 @@ def arnoldi_step_pallas(coeffs: jax.Array, inv_diag: jax.Array,
       vin.reshape(nx, ny), vin.reshape(nx, ny), vin.reshape(nx, ny),
       c_rows.reshape(k1, nx, ny), v_basis.reshape(m1, nx, ny),
       mask.reshape(m1, 1, 1))
-    return wout.reshape(-1), h.reshape(m1), bj.reshape(k1)[:k]
+    return wout.reshape(vin.shape), h.reshape(m1), bj.reshape(k1)[:k]

@@ -164,8 +164,10 @@ def arnoldi_step(coeffs: jax.Array, inv_diag: jax.Array, c_rows: jax.Array,
     stencil matvec + C-projection + CGS2 as ONE launch (the lockstep hot
     loop's whole inner body — see kernels/arnoldi_step.py).
 
-    Returns (w_orth (n,), hcol (m+1,), bj (k,)). k = 0 (plain GMRES) is
-    handled by zero-row padding inside the kernel wrapper."""
+    Returns (w_orth (n,), hcol (m+1,), bj (k,)); the kernel path also
+    takes its vectors as (nx, ny) fields, and then returns w_orth as one.
+    k = 0 (plain GMRES) is handled by zero-row padding inside the kernel
+    wrapper."""
     if use_kernel:
         from repro.kernels.arnoldi_step import arnoldi_step_pallas
 

@@ -23,9 +23,11 @@ Krylov information, exactly the App. E.2.2 task decomposition.
 
 Lockstep semantics (who iterates when):
 
-* Per cycle, every chain runs ≤ m Arnoldi steps under ONE vmapped
-  `lax.while_loop`; a chain that hits its own tolerance mid-cycle is frozen
-  by the batching rule, so per-chain iterates match the sequential solver.
+* Per cycle, every chain runs ≤ m Arnoldi steps in ONE lockstep
+  `lax.while_loop` with one step counter (`arnoldi_cycle_batched`, which
+  writes each step's new basis rows in place); a chain that hits its own
+  tolerance mid-cycle stops there, so per-chain iterates match the
+  sequential solver.
 * Whole cycles are phase-uniform: a "fresh" (establishing) cycle or a
   "deflated" cycle runs for ALL chains at once. Converged / stalled /
   maxiter chains are masked by passing tol_abs = +inf (their cycle takes 0
@@ -68,7 +70,7 @@ Precision policy: `cfg.inner_dtype="float32"` routes `solve_batch` through
 `_solve_batch_mixed` — the fp64 outer iterative-refinement loop of the
 sequential solver lifted to lockstep granularity. All B chains share each
 outer pass (converged chains ride along as zero-RHS padding rows); the
-bandwidth-bound inner machinery — vmapped Arnoldi cycles, preconditioner
+bandwidth-bound inner machinery — lockstep Arnoldi cycles, preconditioner
 applies, recycle-space updates, and now also the stacked eigen/LS work —
 runs in fp32 at half the HBM traffic, while b, the accumulated x and every
 residual of record stay fp64. The per-chain recycle carries are stored
@@ -91,7 +93,7 @@ from repro.obs.telemetry import KrylovTelemetry, drain_chain
 from repro.solvers import devlinalg as dl
 from repro.solvers import hostlinalg as hl
 from repro.solvers import gcrodr as _seq
-from repro.solvers.arnoldi import _arnoldi_cycle_impl
+from repro.solvers.arnoldi import arnoldi_cycle_batched
 from repro.solvers.gmres import _ir_accum
 from repro.solvers.operator import apply_op, cast_operator
 from repro.solvers.types import KrylovConfig, SolveStats
@@ -377,9 +379,9 @@ def _fresh_cycle(ops, s, aux, *, m: int, k: int, orthog: str,
         eff_tol = jnp.where(active, aux["tol_abs"], jnp.inf)
     with jax.named_scope("skr/arnoldi"):
         empty_c = jnp.zeros((bsz, 0, n), dt)
-    cyc = jax.vmap(partial(_arnoldi_cycle_impl, m=m, orthog=orthog,
-                           use_kernel=use_kernel, h_acc=h_acc))(
-        ops, empty_c, s["r"], eff_tol)
+    cyc = arnoldi_cycle_batched(ops, empty_c, s["r"], eff_tol, m=m,
+                                orthog=orthog, use_kernel=use_kernel,
+                                h_acc=h_acc)
     with jax.named_scope("skr/lstsq"):
         j = cyc.j_used.astype(jnp.int32)
         step = j > 0
@@ -469,9 +471,9 @@ def _deflated_cycle(ops, s, aux, *, mi: int, k: int, orthog: str,
         eff_tol = jnp.where(active, aux["tol_abs"], jnp.inf)
     with jax.named_scope("skr/arnoldi"):
         c_rows = jnp.swapaxes(s["c"], 1, 2)
-    cyc = jax.vmap(partial(_arnoldi_cycle_impl, m=mi, orthog=orthog,
-                           use_kernel=use_kernel, h_acc=h_acc))(
-        ops, c_rows, s["r"], eff_tol)
+    cyc = arnoldi_cycle_batched(ops, c_rows, s["r"], eff_tol, m=mi,
+                                orthog=orthog, use_kernel=use_kernel,
+                                h_acc=h_acc)
     with jax.named_scope("skr/lstsq"):
         j = cyc.j_used.astype(jnp.int32)
         step = j > 0

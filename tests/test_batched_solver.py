@@ -16,7 +16,7 @@ from repro.solvers.batched import BatchedGCRODRSolver
 from repro.solvers.gcrodr import GCRODRSolver
 from repro.solvers.gmres import gmres_solve
 from repro.solvers.operator import PreconditionedOp, StencilOp
-from repro.solvers.precond import (make_preconditioner,
+from repro.solvers.precond import (JacobiPrecond, make_preconditioner,
                                    make_preconditioner_batched)
 from repro.solvers.types import KrylovConfig
 
@@ -269,3 +269,70 @@ def test_rows_converge_with_one_sync_per_cycle():
         it_h = sum(s.iterations for s in stats)
         it_d = _DEVICE_EIG_ROW_ITERS[t]
         assert abs(it_h - it_d) <= 0.05 * it_d, (t, it_h, it_d)
+
+
+def _sweep_chains(dt, k, use_kernel, nx=8):
+    """Four chains for the lockstep Arnoldi sweep that stop at different
+    steps: tol_abs = +inf (0 steps), a loose tolerance (an early stop), 0
+    (all m steps), and an operator that Jacobi makes the identity with r0
+    on one grid point, where C vanishes (a lucky breakdown at step 1)."""
+    rng = np.random.default_rng(7)
+    n = nx * nx
+    c = np.zeros((4, 5, nx, nx))
+    c[:3, 0] = 4.0 + rng.random((3, nx, nx))
+    c[:3, 1:] = -(0.5 + 0.5 * rng.random((3, 4, nx, nx)))
+    c[3, 0] = 2.0
+    c_rows = rng.standard_normal((4, k, n))
+    c_rows[:, :, 5] = 0.0
+    if k:
+        c_rows = np.stack([np.linalg.qr(x.T)[0].T for x in c_rows])
+    r0 = rng.standard_normal((4, n))
+    r0 -= np.einsum("bkn,bk->bn", c_rows,
+                    np.einsum("bkn,bn->bk", c_rows, r0))
+    r0[3] = 0.0
+    r0[3, 5] = 3.0
+    tol = np.array([np.inf, 0.05 * np.linalg.norm(r0[1]), 0.0, 0.0])
+    ops = PreconditionedOp(StencilOp(jnp.asarray(c, dt),
+                                     use_kernel=use_kernel),
+                           JacobiPrecond(jnp.asarray(1.0 / c[:, 0], dt)
+                                         .reshape(4, n)))
+    return ops, jnp.asarray(c_rows, dt), jnp.asarray(r0, dt), \
+        jnp.asarray(tol, dt)
+
+
+@pytest.mark.parametrize("k", [0, 2], ids=["k0", "k2"])
+@pytest.mark.parametrize("path", ["fp64-jnp", "fp32-fused"])
+def test_lockstep_sweep_matches_one_chain_at_a_time(path, k):
+    """The lockstep Arnoldi sweep (one step counter for all chains, rows
+    written in place) gives each chain what the same sweep gives it alone:
+    the same steps and breakdown flag, the same basis, Hessenberg and
+    C-block, and rows past the chain's last step still zero. On the fp64
+    jnp path and on the fp32 fused kernel (interpreted)."""
+    from repro.solvers.arnoldi import arnoldi_cycle, arnoldi_cycle_batched
+
+    fused = path == "fp32-fused"
+    dt, rtol = (jnp.float32, 1e-6) if fused else (jnp.float64, 1e-13)
+    m = 6
+    ops, c_rows, r0, tol = _sweep_chains(dt, k, fused)
+    got = arnoldi_cycle_batched(ops, c_rows, r0, tol, m=m,
+                                use_kernel=fused)
+    j = np.asarray(got.j_used)
+    assert j[0] == 0 and 0 < j[1] < m and j[2] == m and j[3] == 1, j
+    assert np.asarray(got.breakdown).tolist() == [False, False, False, True]
+
+    def rel(a, b):
+        a, b = np.float64(a), np.float64(b)
+        return np.linalg.norm(a - b) / max(np.linalg.norm(b), 1e-300)
+
+    for i in range(4):
+        one = arnoldi_cycle(jax.tree.map(lambda x: x[i], ops), c_rows[i],
+                            r0[i], tol[i], m=m, use_kernel=fused)
+        assert int(one.j_used) == j[i]
+        assert bool(one.breakdown) == bool(got.breakdown[i])
+        v, v1 = np.asarray(got.v[i]), np.asarray(one.v)
+        assert rel(v, v1) <= rtol
+        # h and b are the V- and C-blocks of one projection of A·V
+        hb = np.concatenate([np.asarray(got.h[i]), np.asarray(got.b[i])])
+        hb1 = np.concatenate([np.asarray(one.h), np.asarray(one.b)])
+        assert rel(hb, hb1) <= rtol
+        assert not v[j[i] + 1:].any() and not v1[j[i] + 1:].any()

@@ -198,10 +198,20 @@ def test_fp64_host_eig_programs_compile_for_v5e(program, one_chip):
     assert 0 < _bytes(_compiled(program, 64, one_chip)) < 16e9   # 16 GB
 
 
+_COMPILED = {}
+
+
 def _compiled(program, bsz, one_chip, nx=64, dt=jnp.float64,
               use_kernel=False):
-    """One lockstep program compiled for a described v5e at `bsz`
-    chains."""
+    """One lockstep program compiled for a described v5e at `bsz` chains
+    (compiled once per module: several tests read the same program)."""
+    key = (program, bsz, nx, jnp.dtype(dt).name, use_kernel)
+    if key not in _COMPILED:
+        _COMPILED[key] = _compile(program, bsz, one_chip, nx, dt, use_kernel)
+    return _COMPILED[key]
+
+
+def _compile(program, bsz, one_chip, nx, dt, use_kernel):
     from repro.solvers import batched as bt
 
     def place(tree):
@@ -257,11 +267,12 @@ def test_128_programs_compile_for_v5e_at_64_chains(program, one_chip,
     chip and fit its memory: the fp32 passes' programs on the kernels, and
     the fp64 programs of the refinement driver's fallback passes (a kernel
     solver's fp64 cycles run on jnp). The Arnoldi sweep writes its new
-    basis row by a select: a write at each chain's own step is a scatter
-    over chains, which the fp64 cycles cannot fit in the 16 MiB of scoped
-    VMEM at this size. (The fp64 fresh cycle compiles too, but needs about
-    19.7 GB at 64 chains: PERF.md, open questions.) In the cycles, the
-    fused kernel's operands sit where the benchmark's cost model
+    basis row in place at the step all chains share (a
+    dynamic-update-slice): a write at each chain's own step would be a
+    scatter over chains, which the fp64 cycles cannot fit in the 16 MiB of
+    scoped VMEM at this size. (The fp64 fresh cycle compiles too, but needs
+    about 19.7 GB at 64 chains: PERF.md, open questions.) In the cycles,
+    the fused kernel's operands sit where the benchmark's cost model
     (bench/cost.py) counts them: the basis and the recycle rows in HBM,
     the rest in VMEM."""
     dtype, name = program.split("-")
@@ -296,6 +307,76 @@ def _kernel_operands_in_hbm(hlo):
     return out
 
 
+def _sweep_body(hlo):
+    """The instructions of the Arnoldi sweep's `while` body in compiled HLO
+    text, as (opcode, elements of its largest output, op name, the text of
+    the computation a fusion calls)."""
+    import re
+    from math import prod
+
+    comps, cur = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    loop = [line for body in comps.values() for line in body
+            if " while(" in line and re.search(
+                r'op_name="[^"]*skr/arnoldi\)?/while"', line)]
+    assert len(loop) == 1, loop
+    out = []
+    for line in comps[re.search(r"body=%([^,\s]+)", loop[0]).group(1)]:
+        ins = re.match(r"\s*(?:ROOT )?%\S+ = (.*?) ([a-z][\w-]*)\(", line)
+        if not ins:
+            continue
+        shape, opcode = ins.groups()
+        size = max([prod(int(d) for d in dims.split(",") if d)
+                    for dims in re.findall(r"\w\[([\d,]*)\]", shape)]
+                   or [0])
+        name = re.search(r'op_name="([^"]*)"', line)
+        calls = re.search(r"calls=%([^,\s]+)", line)
+        out.append((opcode, size, name.group(1) if name else "",
+                    "\n".join(comps[calls.group(1)]) if calls else ""))
+    return out
+
+
+@pytest.mark.parametrize("case", ["f32-fresh", "f32-deflated",
+                                  "f64-deflated"])
+def test_sweep_writes_each_row_in_place(case, one_chip, monkeypatch):
+    """Each step of the lockstep Arnoldi sweep writes its new basis row in
+    place: at 64 chains, no instruction of the sweep's loop body is as
+    large as the basis or C but the row write, a dynamic-update-slice (the
+    fused kernel's output is one vector a chain). So no step selects over,
+    relays out, copies or slices the whole basis or C: the fp32 cycles at
+    128² on the fused kernel, and the fp64 deflated cycle at 64² on jnp,
+    where only the emulated-fp64 CGS2's own work (`skr/arnoldi/orthog`)
+    and what XLA inserts for it (no op name) may be that large."""
+    dtype, name = case.split("-")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    f32 = dtype == "f32"
+    nx = 128 if f32 else 64
+    k = 15 if name == "deflated" else 0
+    basis = 64 * (41 - k) * nx * nx
+    large = min(basis, 64 * k * nx * nx) if k else basis
+    compiled = _compiled(name, 64, one_chip, nx=nx,
+                         dt=jnp.float32 if f32 else jnp.float64,
+                         use_kernel=f32)
+    free = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    left = [(opcode, op, fused) for opcode, size, op, fused
+            in _sweep_body(compiled.as_text())
+            if size >= large and opcode not in free
+            and (f32 or (op and "skr/arnoldi/orthog" not in op))]
+    assert left, "no row write of the basis in the loop body"
+    for opcode, op, fused in left:
+        assert "skr/arnoldi" in op, op
+        assert (opcode == "dynamic-update-slice"
+                or opcode == "fusion" and " dynamic-update-slice(" in fused), \
+            (opcode, op)
+
+
 @pytest.mark.parametrize("name", ["fresh", "deflated"])
 def test_fp64_host_eig_cycles_drop_the_sweep_loop(name):
     """With the eigensolve on the host, a cycle program holds no loop of
@@ -304,7 +385,7 @@ def test_fp64_host_eig_cycles_drop_the_sweep_loop(name):
     one)."""
     from functools import partial
 
-    from repro.solvers.arnoldi import _arnoldi_cycle_impl
+    from repro.solvers.arnoldi import arnoldi_cycle_batched
 
     bsz = 8
     ops, s, aux = _cycle_shapes(bsz)
@@ -319,8 +400,8 @@ def test_fp64_host_eig_cycles_drop_the_sweep_loop(name):
               if name == "fresh" else
               jax.ShapeDtypeStruct((bsz, kw["k"], s["r"].shape[1]),
                                    jnp.float64))
-    sweep = jax.vmap(partial(_arnoldi_cycle_impl, m=width, orthog="cgs2",
-                             use_kernel=False, h_acc="native"))
+    sweep = partial(arnoldi_cycle_batched, m=width, orthog="cgs2",
+                    use_kernel=False, h_acc="native")
     arnoldi = loops(sweep, ops, c_rows, s["r"], aux["tol_abs"])
     assert arnoldi >= 1
     assert loops(lambda *a: fn.__wrapped__(*a, **kw), ops, s, aux) == arnoldi
@@ -332,7 +413,7 @@ def test_fp64_applies_leave_the_kernels_on_tpu(monkeypatch):
     the kernels take no fp64, instead of raising; fp32 keeps the kernels."""
     from repro.kernels import ref
     from repro.pde.dia import DIA
-    from repro.solvers.arnoldi import _arnoldi_cycle_impl
+    from repro.solvers.arnoldi import arnoldi_cycle_batched
     from repro.solvers.operator import DIAOp, PreconditionedOp, StencilOp
     from repro.solvers.precond import JacobiPrecond
 
@@ -347,9 +428,10 @@ def test_fp64_applies_leave_the_kernels_on_tpu(monkeypatch):
     assert jnp.allclose(DIAOp(dia, use_kernel=True).apply(v),
                         ref.dia_spmv(dia.offsets, dia.data, v))
     op = PreconditionedOp(st, JacobiPrecond(jnp.ones(16)))
-    cyc = _arnoldi_cycle_impl(op, jnp.zeros((0, 16)), v, 1e-12, m=4,
-                              use_kernel=True)
-    assert int(cyc.j_used) == 4 and jnp.isfinite(cyc.h).all()
+    ops1 = jax.tree.map(lambda x: x[None], op)
+    cyc = arnoldi_cycle_batched(ops1, jnp.zeros((1, 0, 16)), v[None],
+                                jnp.array([1e-12]), m=4, use_kernel=True)
+    assert int(cyc.j_used[0]) == 4 and jnp.isfinite(cyc.h).all()
 
 
 def test_gspmd_partitioner_switch_exists():
